@@ -1,4 +1,4 @@
-"""Benchmark CLI shim: every PERF.md table number in ONE parsed JSON line.
+"""Benchmark CLI shim: one run, ONE parsed JSON line naming its device.
 
 The harness itself lives in `symbiont_tpu/bench/` — a tier-isolated
 registry (tiers.py), a repetition engine (stats.py), a per-process resource
@@ -7,16 +7,17 @@ a typed archive schema + regression gate (archive.py); this file is the
 thin CLI the driver and docs invoke:
 
     python bench.py                 # full run; rc != 0 on ANY tier failure
-    python bench.py --quick         # primary embedding metric only (~1 min)
+    python bench.py --quick         # embed-policy tier only
     python bench.py --no-e2e        # skip the full-stack tier
-    python bench.py --render-doc BENCH_rNN.json > docs/PERF.md
     python bench.py --gate NEW.json BASELINE.json
     python bench.py --validate ARCHIVE.json [...]
 
 Prints ONE JSON line to stdout (extra detail goes to stderr); the line
 always carries `tier_failures`/`tier_skips`, and a thrown tier or a missing
 declared primary metric exits nonzero AFTER the line is printed — the
-archive carries the evidence (VERDICT r5 weak #1).
+archive carries the evidence. The run needs a TPU (symbiont_tpu/device.py):
+without one it exits 3 naming the platform it found, unless JAX_PLATFORMS=cpu
+was set explicitly — and then the line says `"platform": "cpu"`.
 
 The reference publishes no numbers (BASELINE.md: "none exist"), so
 vs_baseline is measured, not quoted: the same model on the same chip run the
@@ -36,11 +37,9 @@ from symbiont_tpu.bench.archive import (load_archive,  # noqa: F401
                                         regression_gate, validate_file,
                                         validate_line)
 from symbiont_tpu.bench.cli import main  # noqa: F401
-from symbiont_tpu.bench.doc import _fmt, render_doc  # noqa: F401
 from symbiont_tpu.bench.stats import med_min_max  # noqa: F401
 from symbiont_tpu.bench.workload import (bert_fwd_flops,  # noqa: F401
-                                         chip_peak_flops, log,
-                                         make_sentences)
+                                         chip_peaks, log, make_sentences)
 
 if __name__ == "__main__":
     sys.exit(main())

@@ -14,8 +14,8 @@
 //
 // PIPELINED FEED (VERDICT r4 next-1): the reference's model — and our first
 // three rounds' — was one synchronous embed hop per document, so each
-// replica held exactly one doc in flight and the engine round-trip (~110 ms
-// device RTT on a tunnel) was paid per document. This shell now:
+// replica held exactly one doc in flight and the engine round-trip was
+// paid per document. This shell now:
 //   - keeps up to SYMBIONT_PREPROC_MAX_INFLIGHT embed requests in flight at
 //     once (async inbox request-reply, single-threaded event loop), and
 //   - COALESCES the sentences of multiple pending documents into one

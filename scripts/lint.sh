@@ -2,8 +2,7 @@
 # Contract linter: the static-analysis pass over the repo's own invariants
 # (docs/LINTING.md) — subject wiring, event-loop blocking calls, lock
 # ordering, JAX recompile hygiene, C++ wire-contract parity, knob/doc
-# drift. Device-free and fast (~2s); run it pre-merge alongside
-# scripts/perf_gate.sh.
+# drift. Device-free and fast (~2s); run it pre-merge.
 #
 #   scripts/lint.sh                       # the whole pass (CI entrypoint)
 #   scripts/lint.sh --rules cpp-parity    # one rule family

@@ -1,7 +1,6 @@
 #!/usr/bin/env bash
 # Localize a host-overlap regression in ONE command (ROADMAP item 3, the
-# overlap-everything ingest rework — docs/PERF.md "Overlap-everything
-# ingest" section): where does e2e ingest time actually go?
+# overlap-everything ingest rework): where does e2e ingest time actually go?
 #
 #   scripts/profile_ingest.sh                  # run the bench e2e tier
 #       (full stack: native broker + C++ workers + engine plane), then

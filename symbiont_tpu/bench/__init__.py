@@ -2,16 +2,11 @@
 subsystem.
 
 The bench harness is the gate on every performance claim this project makes:
-docs/PERF.md is rendered mechanically from one archived JSON line, and the
-round-over-round archive (`BENCH_r*.json`) IS the published-numbers story
-(BASELINE.md: the reference publishes none). Round 5's verdict showed what a
-monolithic harness costs: an arms-length `python bench.py` silently lost the
-entire full-stack generation tier (two declared primary metrics vanished with
-rc=0 behind a swallowed `except`), a `parsed: null` driver wrapper crashed
-`load_archive` and reddened the fast tier, and the decode path graded its own
-exam by setting the very ceiling its utilization was measured against.
-
-This package replaces the monolith with five isolated components:
+one JSON line per run, naming the device it ran on (the root PERF.md says
+what has and has not been measured on the chip). A monolithic harness once
+lost an entire tier with rc=0 behind a swallowed `except`, crashed on a
+`parsed: null` driver wrapper, and let the decode path set the very ceiling
+its utilization was measured against — hence five isolated components:
 
 - `tiers`    — a registry where each benchmark tier runs in isolation; a tier
                that throws archives a structured `tier_failures` entry and the
@@ -23,7 +18,7 @@ This package replaces the monolith with five isolated components:
                so a cross-run spread claim is falsifiable from one archive.
 - `sampler`  — per-process resource accounting (CPU seconds per worker role,
                bus bytes/s) sampled during the e2e waves, archiving the
-               host-side decomposition docs/PERF.md previously only asserted.
+               host-side decomposition.
 - `roofline` — per-batch decode byte breakdowns (weights vs KV vs
                activations) and DUAL-ceiling utilization: every point is
                reported against the reference stream kernel and against the
@@ -34,8 +29,8 @@ This package replaces the monolith with five isolated components:
                gate against a previous archive.
 
 Tier implementations live beside them (`workload`, `compute`, `engine_plane`,
-`decode`, `e2e`), doc rendering in `doc`, and `cli.main` orchestrates;
-repo-root `bench.py` is a thin CLI shim over this package.
+`decode`, `e2e`) and `cli.main` orchestrates; repo-root `bench.py` is a
+thin CLI shim over this package.
 """
 
 from symbiont_tpu.bench.archive import load_archive, validate_line  # noqa: F401

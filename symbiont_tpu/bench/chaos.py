@@ -47,7 +47,8 @@ def tier_chaos(results: dict, ctx) -> None:
         raise TierSkip("pytest not installed")
 
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")  # the suite needs no device
+    # the suite needs no device — and this parent may be holding the chip
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "pytest", str(tests_dir), "-m", "chaos",
            "-q", "--no-header", "-p", "no:cacheprovider"]
     log(f"chaos: {' '.join(cmd[2:])}")

@@ -656,6 +656,9 @@ async def _drive_multiproc(results: dict, load_seed: int,
         # worker-process config, all via env (the config layer's canonical
         # spelling — SYMBIONT_<SECTION>_<FIELD>)
         common = {
+            # the bench parent holds the chip (one process per chip), so
+            # these runner children are pinned to the CPU: this tier's
+            # results are CPU results (docs/DEPLOYMENT.md)
             "JAX_PLATFORMS": "cpu",
             # fleet telemetry plane (obs/fleet.py): every role publishes
             # metric deltas + finished spans fast enough for the stitching
@@ -1254,6 +1257,9 @@ async def _drive_ramp(results: dict, load_seed: int,
         api_port = free_port()
         bus_url = f"symbus://127.0.0.1:{broker_port}"
         common = {
+            # the bench parent holds the chip (one process per chip), so
+            # these runner children are pinned to the CPU: this tier's
+            # results are CPU results (docs/DEPLOYMENT.md)
             "JAX_PLATFORMS": "cpu",
             "SYMBIONT_OBS_FLEET_PUBLISH_S": "0.3",
             "SYMBIONT_BUS_DURABLE": "1",
@@ -1742,6 +1748,9 @@ async def _drive_gen_chaos(results: dict, load_seed: int,
         bus_url = f"symbus://127.0.0.1:{broker_port}"
         genlog_dir = f"{td}/genlog"
         common = {
+            # the bench parent holds the chip (one process per chip), so
+            # these runner children are pinned to the CPU: this tier's
+            # results are CPU results (docs/DEPLOYMENT.md)
             "JAX_PLATFORMS": "cpu",
             "SYMBIONT_OBS_FLEET_PUBLISH_S": "0.3",
             "SYMBIONT_BUS_DURABLE": "1",

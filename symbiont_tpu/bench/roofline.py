@@ -136,7 +136,7 @@ def grade_executable(flops: Optional[float], bytes_accessed: Optional[float],
                      wall_s: float, dispatches: int,
                      ref_gbps: Optional[float] = None) -> dict:
     """Place one executable on the roofline from its XLA cost-model
-    estimate (obs/xprof.py cost_analysis_for) and its MEASURED host wall.
+    estimate (obs/xprof.py cost_analysis_of) and its MEASURED host wall.
 
     Achieved rates divide the cost model's per-dispatch work by the mean
     host wall per dispatch — an UNDERESTIMATE of device rates whenever the
@@ -164,14 +164,3 @@ def grade_executable(flops: Optional[float], bytes_accessed: Optional[float],
     return {"achieved_gflops_per_s": gflops, "achieved_gbps": gbps,
             "arithmetic_intensity": intensity,
             "hbm_util_vs_ref_pct": util}
-
-
-def annotated_for_render(r: dict) -> dict:
-    """Non-destructive annotate for doc rendering: legacy archives carry raw
-    `*_hbm_gbps*` + `hbm_stream_gbps_measured` but not the dual fields, so
-    the renderer derives them the same way a fresh run would. Fields already
-    present in the archive win (the archived value is authoritative)."""
-    derived = dict(r)
-    annotate(derived)
-    derived.update(r)  # archived values win over derived ones
-    return derived

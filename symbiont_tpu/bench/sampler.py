@@ -1,8 +1,8 @@
 """Per-process resource sampler for the full-stack tier.
 
-Round-5 verdict weak #4: docs/PERF.md claimed the e2e-ingest floor is "one
-shared host core runs every byte of 15 processes" with no measurement behind
-it — an unfalsifiable assertion. This sampler snapshots `/proc/<pid>/stat`
+A claim like "the e2e-ingest floor is one shared host core running every
+byte of 15 processes" is unfalsifiable without a measurement behind it.
+This sampler snapshots `/proc/<pid>/stat`
 (utime+stime) and `/proc/<pid>/io` (rchar+wchar — syscall-level bytes, which
 on socket-only workers like the broker is bus traffic) around a measured
 window, so the archive carries the decomposition: CPU seconds per worker

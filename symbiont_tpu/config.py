@@ -9,8 +9,10 @@ exported to the native C++ workers via environment variables.
 
 Env override convention: SYMBIONT_<SECTION>_<FIELD>, e.g.
 SYMBIONT_ENGINE_MODEL_NAME, SYMBIONT_BUS_URL. Reference-era env names
-(NATS_URL, QDRANT_URI, FORCE_CPU, API_SERVER_HOST/PORT) are honored as aliases
-for drop-in compatibility (reference: .env.example:1-12).
+(NATS_URL, QDRANT_URI, API_SERVER_HOST/PORT) are honored as aliases for
+drop-in compatibility (reference: .env.example:1-12). The reference's
+FORCE_CPU is NOT one of them: the device is chosen by JAX_PLATFORMS alone
+(symbiont_tpu/device.py).
 """
 
 from __future__ import annotations
@@ -62,7 +64,6 @@ class EngineConfig:
     model_name: str = "sentence-transformers/paraphrase-multilingual-mpnet-base-v2"
     model_dir: Optional[str] = None  # local checkpoint dir (safetensors + config)
     embedding_dim: int = 768
-    force_cpu: bool = False  # reference: FORCE_CPU env, preprocessing main.rs:307
     dtype: str = "bfloat16"
     # attention backend: "auto" → XLA fused attention (fastest at every
     # measured encoder bucket on v5e with the bf16 softmax path);
@@ -77,10 +78,10 @@ class EngineConfig:
     max_batch: int = 128
     # Interactive path: flush a partial batch after this deadline.
     flush_deadline_ms: float = 5.0
-    # Micro-batcher flushes dispatched concurrently: on a network-attached
-    # device each flush tail is ~an RTT of pure waiting, so overlapping
-    # flushes keeps the chip fed (engine/batcher.py _BatcherBase). 2 was
-    # measured as break-even locally; raise toward 4 on a high-RTT tunnel.
+    # Micro-batcher flushes dispatched concurrently: flush N+1 tokenizes,
+    # pads and dispatches while flush N's results are still materializing
+    # (engine/batcher.py _BatcherBase). Whether >1 pays on a locally
+    # attached chip is not measured.
     max_inflight_flushes: int = 2
     # Engine-plane tenant fairness (engine/batcher.TenantLanes): items queue
     # in per-tenant lanes drained stride-fair, so a hot tenant that bypasses
@@ -913,7 +914,6 @@ _ENV_ALIASES = {
     "NEO4J_PASSWORD": ("graph_store", "password"),
     "API_SERVER_HOST": ("api", "host"),
     "API_SERVER_PORT": ("api", "port"),
-    "FORCE_CPU": ("engine", "force_cpu"),
     "EMBEDDING_MODEL_NAME": ("engine", "model_name"),
 }
 

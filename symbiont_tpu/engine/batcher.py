@@ -236,10 +236,9 @@ class _BatcherBase:
     consumes) and `_flush(batch)` (resolve every item's future).
 
     `max_inflight_flushes` > 1 lets the loop start flush N+1 while flush N's
-    results are still materializing — on a network-attached device a flush
-    tail is ~an RTT of pure waiting, so overlapping flushes keeps the chip
-    fed (the engine's entry points are thread-safe by design; see
-    engine.py's concurrency contract): batch N+1 tokenizes/pads/dispatches
+    results are still materializing (the engine's entry points are
+    thread-safe by design; see engine.py's concurrency contract): batch
+    N+1 tokenizes/pads/dispatches
     on its own executor thread while batch N's forward runs. Generation
     keeps it at 1: decode sessions admit newcomers at chunk boundaries
     instead, and two sessions would only contend on the LM lock.

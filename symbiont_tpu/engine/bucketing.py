@@ -65,8 +65,7 @@ def pad_ids_rows(
 
     The attention mask is NOT materialized on host: the device executable
     rebuilds it as `arange(bucket) < lengths[:, None]`, halving the
-    host→device bytes vs shipping an explicit [n, bucket] mask — on a
-    network-attached chip h2d bandwidth is part of the ingest wall.
+    host→device bytes vs shipping an explicit [n, bucket] mask.
     `dtype` further narrows the wire: uint16 ids when the vocab fits."""
     n = len(seqs)
     ids = np.full((n, bucket), pad_id, dtype)

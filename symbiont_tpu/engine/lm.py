@@ -231,6 +231,8 @@ class LmEngine:
                     "LM tensor_parallel=auto: %s not divisible by tensor "
                     "axis (%d); falling back to single-device decode",
                     ", ".join(bad), tp)
+                metrics.inc("lm.degraded",
+                            labels={"reason": "tp_auto_undivisible"})
             else:
                 self.mesh = mesh
                 log.info("LM params sharded for TP decode over tensor=%d", tp)
@@ -316,6 +318,8 @@ class LmEngine:
                 log.warning(
                     "spec_draft_model %r not found — speculative decoding "
                     "disabled, plain decode unaffected", cfg.spec_draft_model)
+                metrics.inc("lm.degraded",
+                            labels={"reason": "spec_draft_missing"})
             else:
                 from symbiont_tpu.models.convert import load_gpt_model as _lg
 
@@ -1576,6 +1580,8 @@ class BatchSession:
             except Exception:
                 log.warning("draft prefill failed — session decodes plain",
                             exc_info=True)
+                metrics.inc("lm.degraded",
+                            labels={"reason": "draft_prefill_failed"})
                 self._spec_on = False
                 self._d_cache = None
         engine_timeline.note_admit(
@@ -2191,6 +2197,8 @@ class BatchSession:
                 # caller-visible error
                 log.warning("page alloc for spec window failed — session "
                             "falls back to plain decode", exc_info=True)
+                metrics.inc("lm.degraded",
+                            labels={"reason": "spec_page_pressure"})
                 self._spec_on = False
                 return self.step()
         draft_params, dcfg = lm._draft

@@ -106,6 +106,9 @@ JAX_HOST_SYNC_ALLOWED = {
     ("symbiont_tpu/engine/engine.py", "TpuEngine.warmup"):
         "warmup exists to FORCE the compile+execute to finish; the sync "
         "is the point, and the path never serves traffic",
+    ("symbiont_tpu/engine/engine.py", "TpuEngine.warm_rerank"):
+        "same as warmup: the boot warm-up of the rerank hop forces each "
+        "compile+execute to finish, off the serving path",
     ("symbiont_tpu/engine/lm.py", "LmEngine._generate_stream_impl"):
         "chunk-boundary sync is the streaming contract: each decoded "
         "chunk's tokens must reach the SSE reader before the next chunk "

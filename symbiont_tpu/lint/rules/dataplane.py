@@ -4,7 +4,7 @@ shim over these rules).
 
 - ``no-per-float-conversion``: a ``[float(x) for ...]`` list comprehension
   inside services/ is exactly the per-float serialization wall the binary
-  tensor-frame plane removed (docs/PERF.md "data plane") — bulk floats ride
+  tensor-frame plane removed (schema/frames.py) — bulk floats ride
   schema/frames or ``ndarray.tolist()``. Allowlisted: bounded latency-path
   payloads (top-k scores), FLOAT_LIST_ALLOWED.
 - ``no-asdict-on-ingest``: ``dataclasses.asdict`` recursively materializes
@@ -77,7 +77,7 @@ def check_float(ctx: LintContext) -> List[Finding]:
         ctx, _FLOAT_LIST, FLOAT_RULE,
         "per-float Python conversion on a services/ message path — the "
         "serialization wall the tensor-frame data plane removed "
-        "(docs/PERF.md 'data plane'). Use schema/frames or "
+        "(schema/frames.py). Use schema/frames or "
         "ndarray.tolist() instead")
 
 

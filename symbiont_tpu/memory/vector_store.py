@@ -322,19 +322,29 @@ class VectorStore:
         with self._lock:
             return self._hits_from(scores, idx, top_k)
 
-    def warm_fused(self, engine, word_counts: Sequence[int] = (3, 40, 150),
+    def warm_fused(self, engine,
+                   word_counts: Optional[Sequence[int]] = None,
                    top_ks: Optional[Sequence[int]] = None) -> None:
         """Pre-compile the fused embed+top-k executables for the store's
-        CURRENT capacity across the engine's query length buckets — including
-        an empty store (capacity is the first block, which the first
+        CURRENT capacity across EVERY query length bucket of the engine —
+        including an empty store (capacity is the first block, which the first
         shard_capacity upserts keep). Without this, the first fused query per
         (length-bucket, capacity) pays the full XLA compile inside the
-        gateway's short probe timeout. Warms every power-of-two k bucket up
+        gateway's short probe timeout — on the v5e that is ~16 s against a
+        5 s probe: the gateway negative-caches the fused subject, the 2-hop
+        path compiles its own cold executable, and the client gets a 503
+        (seen on the chip when only three of the five buckets were warmed).
+        `word_counts` defaults to one text per bucket: one word more than
+        the previous bucket holds. Warms every power-of-two k bucket up
         to config.warm_top_k (default 8 and 16) — the gateways route only
         top_k ≤ ApiConfig.fused_search_max_top_k to the fused path, and the
         two knobs must move together — and records the warmed capacity so
         callers can re-warm when upserts cross a capacity block
         (fused_warm_stale)."""
+        if word_counts is None:
+            buckets = [b for b in engine.config.length_buckets
+                       if b <= engine.model_cfg.max_position_embeddings]
+            word_counts = [prev + 1 for prev in [0] + buckets[:-1]]
         if top_ks is None:
             top_ks = [8]
             while top_ks[-1] < self.config.warm_top_k:

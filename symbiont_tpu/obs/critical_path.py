@@ -23,8 +23,8 @@ same tree ``TraceStore.trace_tree`` already builds:
 Served at ``GET /api/traces/<id>/critical_path`` (services/api.py), and
 aggregated fleet-wide by ``aggregate_stage_attribution`` into ``stage.*``
 series (fraction of e2e latency per hop, grouped by root span name) that
-the bench e2e tier archives and docs/PERF.md renders as the "where the
-time goes" table.
+the bench e2e tier archives (the input to the root PERF.md's "where the
+time goes" section).
 
 Like the trace store itself: no symbiont imports above the obs layer, no
 device, pure arithmetic over recorded spans.

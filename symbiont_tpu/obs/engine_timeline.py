@@ -25,8 +25,7 @@ Surfaces: ``GET /api/engine/timeline`` (JSON summary, or ``?fmt=chrome``
 for Perfetto counter tracks interleaved with the flight recorder's span
 lanes — ``obs/chrome_trace.export_timeline``), the ``lm.ttft_ms`` /
 ``lm.tpot_ms`` Prometheus histograms fed at step boundaries, and the
-``decode_*`` archive fields the bench ``decode_timeline`` tier renders
-into docs/PERF.md.
+``decode_*`` archive fields of the bench ``decode_timeline`` tier.
 
 Layering: imports only ``utils/telemetry`` (the registry); the engine and
 batcher record into the global ``engine_timeline`` the way every handler

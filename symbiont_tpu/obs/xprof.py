@@ -23,9 +23,9 @@ deltas. This module turns the claim into first-class series:
   the lint rule doesn't know about.
 
 * **XLA cost model** — at the engine's existing ``_time_first_call``
-  compile seam, ``cost_analysis_for(jitted, args)`` captures the
-  lowered computation's FLOPs / bytes-accessed estimate (graceful None
-  fallback when the backend doesn't implement it); combined with the
+  compile seam, ``compile_analysis_for(jitted, args)`` captures the
+  lowered computation's FLOPs / bytes-accessed estimate (None when the
+  backend doesn't implement it); combined with the
   measured dispatch wall this places each executable on the PR 1
   roofline (bench/roofline.py:grade_executable).
 
@@ -55,7 +55,7 @@ __all__ = [
     "DispatchLedger",
     "DeviceTraceCapture",
     "compile_analysis_for",
-    "cost_analysis_for",
+    "cost_analysis_of",
     "dispatch_ledger",
     "device_trace",
     "known_sync_sites",
@@ -74,21 +74,21 @@ def known_sync_sites() -> tuple:
     return tuple(sorted(scope for (_file, scope) in JAX_HOST_SYNC_ALLOWED))
 
 
-def cost_analysis_for(jitted, args) -> Optional[dict]:
-    """FLOPs / bytes-accessed estimate for a jitted fn at concrete args.
+def cost_analysis_of(lowered) -> Optional[dict]:
+    """FLOPs / bytes-accessed estimate off a ``Lowered`` (pre-compile, so
+    the one real XLA compile still happens exactly once afterwards).
 
-    Uses ``Lowered.cost_analysis()`` (pre-compile, so the subsequent
-    first call still performs the one real XLA compile — no double
-    compilation). Returns ``{"flops": float, "bytes_accessed": float}``
-    with absent estimates as 0.0, or None when the backend / jax version
-    doesn't expose a cost model (CPU backends may not) — callers must
-    treat None as "unknown", never as zero work.
+    Returns ``{"flops": float, "bytes_accessed": float}`` with absent
+    estimates as 0.0, or None when the backend exposes no cost model —
+    callers must treat None as "unknown", never as zero work. Only the
+    ANALYSIS is tolerant; lowering and compiling are not (see
+    ``compile_analysis_for``).
     """
     try:
-        ca = jitted.lower(*args).cost_analysis()
-    except Exception:
+        ca = lowered.cost_analysis()
+    except Exception:  # backend-specific: no cost model is a legal answer
         return None
-    # older jax returns a per-device list; newer returns one dict
+    # some backends return a per-device list, others one dict
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else None
     if not isinstance(ca, dict):
@@ -140,41 +140,19 @@ def compile_analysis_for(jitted, args) -> tuple:
 
     Returns ``(cost, memory, compiled)``: the cost model off the Lowered,
     the memory footprint off the Compiled, and the AOT Compiled object
-    itself so the caller can dispatch through it — the first call then
-    costs exactly one trace and one XLA compile, same as calling the
-    jitted fn directly, but the static analyses come along for free.
-    Any stage may come back None (backend support varies); a None
-    ``compiled`` means the caller must fall back to ``jitted(*args)``
-    (which re-uses jit's own cache — at worst one duplicate compile on
-    this rare path).
+    itself, which the caller dispatches through from then on — the first
+    call costs exactly one trace and one XLA compile, same as calling the
+    jitted fn directly. The analyses may be None (backend support varies).
+    A failed ``lower()`` or ``compile()`` PROPAGATES: there is no second
+    attempt under ``jit`` — a compile error is paid for once and reported
+    where it happened (``lowered.compile()`` goes through the same
+    persistent compilation cache as ``jit``, so nothing is lost by
+    compiling here).
     """
-    cost = mem = compiled = None
-    try:
-        lowered = jitted.lower(*args)
-    except Exception:
-        return None, None, None
-    try:
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else None
-        if isinstance(ca, dict):
-            def _num(key: str) -> float:
-                try:
-                    v = float(ca.get(key, 0.0))
-                except (TypeError, ValueError):
-                    return 0.0
-                return v if v == v and v >= 0.0 else 0.0
-
-            cost = {"flops": _num("flops"),
-                    "bytes_accessed": _num("bytes accessed")}
-    except Exception:
-        cost = None
-    try:
-        compiled = lowered.compile()
-    except Exception:
-        return cost, None, None
-    mem = memory_analysis_of(compiled)
-    return cost, mem, compiled
+    lowered = jitted.lower(*args)
+    cost = cost_analysis_of(lowered)
+    compiled = lowered.compile()
+    return cost, memory_analysis_of(compiled), compiled
 
 
 class _ExeStats:

@@ -33,6 +33,11 @@ Design:
 - fallback: shapes the kernel can't tile (non-divisible or tiny S) route to
   the same dense reference implementation, so callers never need shape
   special-cases.
+- every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
+  line and one `flash.fallback{path}` bump per traced shape — the Pallas interpreter on a CPU backend, the dense route for
+  untileable shapes, the dense-recompute GQA backward. `chip_smoke.py`
+  asserts all three read zero on the chip. The interpreter on a `tpu`
+  backend is an error, as is any backend that is neither `tpu` nor `cpu`.
 
 Replaces, at the bottom of the stack, the reference's candle
 `BertModel::forward` attention (reference:
@@ -43,12 +48,27 @@ materializes full score matrices per layer — with the TPU-native fused form.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from symbiont_tpu.utils.telemetry import metrics
+
+log = logging.getLogger(__name__)
+
+
+def _announce(path: str, q, k) -> None:
+    """A way off the compiled kernel was taken for this shape: count it
+    (`flash.fallback{path}`) and say so. Runs at trace time, so under jit
+    that is one bump and one log line per compiled shape."""
+    metrics.inc("flash.fallback", labels={"path": path})
+    log.warning("flash_attention: %s for q%s k%s %s", path, tuple(q.shape),
+                tuple(k.shape), q.dtype)
+
 
 # Large-negative finite stand-ins for -inf: m is initialized to _ACC_NEG and
 # masked scores are set to _MASK_NEG; keeping both finite (and _ACC_NEG well
@@ -392,6 +412,7 @@ def _flash_bwd_fused(q, k, v, bias, out, lse, g, causal, scale, bq, bk,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q, k, v, bias, causal, scale, block_q, block_k, interpret):
     if block_q == 0 or block_k == 0:
+        _announce("dense_untileable", q, k)
         out, _ = _dense_reference(q, k, v, bias, causal, scale)
         return out.astype(q.dtype)
     return _flash_call(q, k, v, bias, causal, scale, block_q, block_k,
@@ -400,6 +421,7 @@ def _flash(q, k, v, bias, causal, scale, block_q, block_k, interpret):
 
 def _flash_fwd(q, k, v, bias, causal, scale, block_q, block_k, interpret):
     if block_q == 0 or block_k == 0:
+        _announce("dense_untileable", q, k)
         out, _ = _dense_reference(q, k, v, bias, causal, scale)
         return out.astype(q.dtype), (q, k, v, bias, None, None)
     out, lse = _flash_call(q, k, v, bias, causal, scale, block_q, block_k,
@@ -418,8 +440,11 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     if lse is not None and group == 1:
         return _flash_bwd_fused(q, k, v, bias, out, lse, g, causal, scale,
                                 block_q, block_k, interpret)
-    # dense f32 recompute: the fallback-shape path and GQA (prefill-only in
-    # this system; long-context LM training rides parallel/context.py)
+    # dense f32 recompute: the fallback-shape path (announced in the
+    # forward) and GQA (prefill-only in this system; long-context LM
+    # training rides parallel/context.py)
+    if block_q and block_k:
+        _announce("dense_gqa_backward", q, k)
     _, (p, qf, kf, vf) = _dense_reference(q, k, v, bias, causal, scale)
     gf = g.astype(jnp.float32)
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
@@ -452,8 +477,11 @@ def flash_attention(
 ) -> jax.Array:
     """Fused attention → [B, NH, Sq, D] in q.dtype.
 
-    `interpret=None` auto-selects: compiled kernel on TPU, pallas interpreter
-    elsewhere (CPU tests run the same kernel code path bit-for-bit).
+    `interpret=None` auto-selects: compiled kernel on a `tpu` backend,
+    pallas interpreter on a `cpu` backend (CPU tests run the same kernel
+    code path bit-for-bit; announced, see `_announce`). Any other backend
+    name is an error — a TPU reached under another platform name must not
+    silently run the interpreter — and so is `interpret=True` on `tpu`.
     """
     B, NH, Sq, D = q.shape
     NKV, Sk = k.shape[1], k.shape[2]
@@ -461,8 +489,20 @@ def flash_attention(
         raise ValueError(f"q heads {NH} not a multiple of kv heads {NKV}")
     if v.shape != k.shape:
         raise ValueError(f"k/v shape mismatch: {k.shape} vs {v.shape}")
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"flash_attention: backend {backend!r} is neither 'tpu' "
+            "(compiled kernel) nor 'cpu' (interpreter)")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = backend == "cpu"
+    if interpret and backend == "tpu":
+        raise ValueError(
+            "flash_attention(interpret=True) on a tpu backend: the Pallas "
+            "interpreter must never stand in for the compiled kernel on "
+            "the chip")
+    if interpret:
+        _announce("interpreter", q, k)
     if kv_bias is None:
         kv_bias = jnp.zeros((B, Sk), jnp.float32)
     kv_bias = kv_bias.astype(jnp.float32)

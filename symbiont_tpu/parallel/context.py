@@ -22,7 +22,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from symbiont_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from symbiont_tpu.models.gpt import (

@@ -62,16 +62,21 @@ def build_mesh(
 
     shape=None → all devices on the first axis, 1 on the rest (pure DP, the
     right default for the embedding models: MiniLM..e5-large all fit a single
-    v5e chip's HBM; TP is for LMs).
+    v5e chip's HBM; TP is for LMs). An explicit shape smaller than the host
+    takes the FIRST prod(shape) devices — a one-chip stack (`[1, 1]`) can be
+    asked for on a four-chip host without hiding chips; a shape larger than
+    the host is an error.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if shape is None:
         shape = [n] + [1] * (len(axis_names) - 1)
     shape = list(shape)
-    if int(np.prod(shape)) != n:
-        raise ValueError(f"mesh shape {shape} does not cover {n} devices")
-    dev_array = np.asarray(devices).reshape(shape)
+    want = int(np.prod(shape))
+    if want > n:
+        raise ValueError(f"mesh shape {shape} needs {want} devices, "
+                         f"only {n} present")
+    dev_array = np.asarray(devices[:want]).reshape(shape)
     return Mesh(dev_array, tuple(axis_names))
 
 

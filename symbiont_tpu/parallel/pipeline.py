@@ -31,7 +31,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from symbiont_tpu.parallel.compat import pcast, shard_map
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from symbiont_tpu.models.gpt import (

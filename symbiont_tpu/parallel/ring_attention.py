@@ -22,8 +22,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size, pcast
 from jax.sharding import Mesh, PartitionSpec as P
-from symbiont_tpu.parallel.compat import axis_size, pcast, shard_map
 
 
 def ring_attention(

@@ -140,7 +140,7 @@ def corpus_topk(mesh: Mesh, corpus, query, n_valid, k: int,
     Trace-time only (call inside jit with the mesh's sharded operands)."""
     import jax.numpy as jnp
 
-    from symbiont_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     nd = mesh.shape[axis]
     cap = corpus.shape[0]

@@ -31,8 +31,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-from symbiont_tpu.parallel.compat import axis_size, shard_map
 
 
 def _full_attention(q, k, v, causal: bool) -> jax.Array:

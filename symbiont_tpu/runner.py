@@ -223,9 +223,17 @@ class SymbiontStack:
             and (on("vector_memory") or on("engine"))
             and not cfg.vector_store.uri
             and cfg.vector_store.device_resident)
-        if (cfg.parallel.enabled and self._mesh is None
-                and (builds_real_engine or builds_real_lm
-                     or builds_embedded_store)):
+        touches_device = (builds_real_engine or builds_real_lm
+                          or builds_embedded_store)
+        if touches_device:
+            # one device policy (symbiont_tpu/device.py): a TPU, or a CPU
+            # that JAX_PLATFORMS=cpu asked for — never a CPU this process
+            # fell back to because the chip was absent or held elsewhere.
+            # Also places the persistent compile cache.
+            from symbiont_tpu.device import require_device
+
+            require_device()
+        if cfg.parallel.enabled and self._mesh is None and touches_device:
             from symbiont_tpu.parallel.mesh import mesh_from_config
 
             self._mesh = mesh_from_config(cfg.parallel)
@@ -597,7 +605,13 @@ async def main() -> None:
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     stack = SymbiontStack()
-    await stack.start()
+    try:
+        await stack.start()
+    except BaseException:
+        # e.g. DeviceUnavailable: release the gateway socket and the bus
+        # before the process exits non-zero
+        await stack.stop()
+        raise
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
     for sig in (signal.SIGINT, signal.SIGTERM):

@@ -1,8 +1,8 @@
 """Binary tensor frames — the zero-copy bulk-float data plane on the bus.
 
-docs/PERF.md attributes the 5.5× gap between full-stack ingest and the
-engine-plane bulk number largely to host-side (de)serialization: every
-embedding hop used to JSON-encode 384 floats per sentence, and each f32
+Much of the gap between full-stack ingest and the engine-plane bulk rate
+is host-side (de)serialization: every embedding hop used to JSON-encode
+384 floats per sentence, and each f32
 that rode through Python `float()` serialized as the ~17-digit shortest
 round-trip of its DOUBLE widening (~19-20 bytes per float on the wire).
 The accelerator-feeding literature makes the same point (Demystifying

@@ -114,8 +114,9 @@ class EngineService(Service):
     def _spawn_fused_warm(self) -> None:
         """Background-compile the fused query executables for the store's
         current capacity across the query length buckets (works for an empty
-        store too — capacity is the first block), so interactive queries
-        don't eat the 20-40s TPU compile inside the gateway's probe timeout.
+        store too — capacity is the first block) and, once, the rerank
+        executables, so interactive queries don't eat a cold XLA compile
+        inside the gateway's probe and rerank-hop timeouts.
         Queries arriving mid-warmup fall back to the 2-hop path; the store
         lock is never held across a compile. Re-invoked when upserts cross a
         capacity block (the executables are capacity-keyed)."""
@@ -127,14 +128,27 @@ class EngineService(Service):
 
         async def warm() -> None:
             loop = asyncio.get_running_loop()
+            t0 = loop.time()
             try:
                 await loop.run_in_executor(
                     None, self.vector_store.warm_fused, self.engine)
-                log.info("fused query executables warmed")
+                # capacity-independent: on a re-warm after a capacity
+                # block this is ten already-compiled dummy dispatches
+                await loop.run_in_executor(None, self.engine.warm_rerank)
             except Exception:
-                log.exception("fused warmup failed (non-fatal)")
-                self._warm_failed = True  # next upsert retries
+                # the process keeps serving (queries ride the 2-hop path and
+                # the next upsert retries), but the failure is COUNTED — a
+                # stack whose fused path never warmed must not look healthy
+                # to anything that reads /metrics (chip_smoke.py asserts 0)
+                log.exception("fused warmup failed; next upsert retries")
+                metrics.inc("engine.fused_warmups",
+                            labels={"result": "failed"})
+                self._warm_failed = True
                 return
+            dt = loop.time() - t0
+            metrics.inc("engine.fused_warmups", labels={"result": "ok"})
+            metrics.gauge_set("engine.fused_warmup_s", round(dt, 3))
+            log.info("fused query + rerank executables warmed in %.1fs", dt)
             # an upsert may have crossed a capacity block while this warm
             # was compiling (spawn attempts during a live warm are no-ops) —
             # re-check so the stale window closes without waiting for the

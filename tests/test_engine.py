@@ -398,9 +398,9 @@ def test_concat_fetch_groups_match(monkeypatch):
 
 
 def test_micro_batcher_overlapping_flushes():
-    """max_inflight_flushes=2: a flush stuck materializing (on a remote
-    device that tail is ~an RTT of waiting) must not block the next flush
-    from dispatching — and the stuck flush still resolves correctly."""
+    """max_inflight_flushes=2: a flush stuck materializing must not block
+    the next flush from dispatching — and the stuck flush still resolves
+    correctly."""
     import asyncio
     import threading
 

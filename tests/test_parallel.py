@@ -45,8 +45,11 @@ def test_mesh_build_and_shape_error():
     assert mesh.shape == {"data": 8, "tensor": 1}
     mesh2 = build_mesh([2, 4])
     assert mesh2.shape == {"data": 2, "tensor": 4}
-    with pytest.raises(ValueError):
-        build_mesh([3, 2])
+    # a shape smaller than the host takes the first devices ...
+    mesh3 = build_mesh([3, 2])
+    assert list(mesh3.devices.flat) == jax.devices()[:6]
+    with pytest.raises(ValueError):  # ... a larger one cannot be built
+        build_mesh([3, 3])
 
 
 @requires_8

@@ -7,7 +7,7 @@ Covers the PR-17 profiling plane end to end:
 - live host-sync audit: counters per allowlisted site, and TWO-direction
   parity with the lint allowlist (every allowlisted site has a runtime
   counter call; no counter call names a site the lint rule doesn't know);
-- cost_analysis_for: real-jit happy path, and the graceful None fallback
+- cost_analysis_of: real-jit happy path, and the graceful None fallback
   when the backend exposes no cost model (None is "unknown", never zero);
 - host-gap attribution: the engine-timeline summary's
   decode_dispatches_per_token / decode_host_gap_pct fields and the new
@@ -34,7 +34,8 @@ from symbiont_tpu.bench.roofline import grade_executable
 from symbiont_tpu.obs.xprof import (
     DeviceTraceCapture,
     DispatchLedger,
-    cost_analysis_for,
+    compile_analysis_for,
+    cost_analysis_of,
     known_sync_sites,
 )
 from symbiont_tpu.utils.telemetry import Metrics
@@ -150,38 +151,66 @@ def test_sync_site_parity_both_directions():
 
 # ----------------------------------------------------------- cost analysis
 
-class _FakeJitted:
-    """Stands in for jax.jit(fn): .lower(*args).cost_analysis() -> shape."""
-
-    def __init__(self, ca):
-        self._ca = ca
-
-    def lower(self, *args):
-        if isinstance(self._ca, Exception):
-            raise self._ca
-        return types.SimpleNamespace(cost_analysis=lambda: self._ca)
+def _fake_lowered(ca):
+    """Stands in for jax.jit(fn).lower(*args): .cost_analysis() -> shape."""
+    def cost_analysis():
+        if isinstance(ca, Exception):
+            raise ca
+        return ca
+    return types.SimpleNamespace(cost_analysis=cost_analysis)
 
 
 def test_cost_analysis_fallback_when_unavailable():
-    # backend raises anywhere in lower/cost_analysis -> None (unknown)
-    assert cost_analysis_for(_FakeJitted(RuntimeError("no cost model")),
-                             ()) is None
+    # backend has no cost model -> None (unknown)
+    assert cost_analysis_of(
+        _fake_lowered(RuntimeError("no cost model"))) is None
     # non-dict shapes -> None
-    assert cost_analysis_for(_FakeJitted("nope"), ()) is None
-    assert cost_analysis_for(_FakeJitted([]), ()) is None
+    assert cost_analysis_of(_fake_lowered("nope")) is None
+    assert cost_analysis_of(_fake_lowered([])) is None
 
 
 def test_cost_analysis_normalizes_shapes_and_guards_values():
-    out = cost_analysis_for(
-        _FakeJitted({"flops": 10.0, "bytes accessed": 5.0}), ())
+    out = cost_analysis_of(
+        _fake_lowered({"flops": 10.0, "bytes accessed": 5.0}))
     assert out == {"flops": 10.0, "bytes_accessed": 5.0}
-    # older jax: per-device LIST of dicts
-    out = cost_analysis_for(_FakeJitted([{"flops": 7.0}]), ())
+    # per-device LIST of dicts
+    out = cost_analysis_of(_fake_lowered([{"flops": 7.0}]))
     assert out == {"flops": 7.0, "bytes_accessed": 0.0}
     # NaN / negative / non-numeric estimates -> 0.0, never poison
-    out = cost_analysis_for(
-        _FakeJitted({"flops": float("nan"), "bytes accessed": -3.0}), ())
+    out = cost_analysis_of(
+        _fake_lowered({"flops": float("nan"), "bytes accessed": -3.0}))
     assert out == {"flops": 0.0, "bytes_accessed": 0.0}
+
+
+def test_compile_errors_propagate_and_are_paid_once():
+    """Only the ANALYSES are tolerant. A failed lower() or compile() raises
+    out of compile_analysis_for — it is never swallowed into a second
+    attempt under jit (a compile error paid for twice and reported never)."""
+    calls = {"lower": 0, "compile": 0}
+
+    class _Lowered:
+        def cost_analysis(self):
+            return {"flops": 1.0}
+
+        def compile(self):
+            calls["compile"] += 1
+            raise RuntimeError("mosaic says no")
+
+    class _Jitted:
+        def __init__(self, lower_exc=None):
+            self._exc = lower_exc
+
+        def lower(self, *args):
+            calls["lower"] += 1
+            if self._exc is not None:
+                raise self._exc
+            return _Lowered()
+
+    with pytest.raises(TypeError, match="bad trace"):
+        compile_analysis_for(_Jitted(TypeError("bad trace")), ())
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        compile_analysis_for(_Jitted(), ())
+    assert calls == {"lower": 2, "compile": 1}
 
 
 def test_cost_analysis_real_jit_does_not_crash():
@@ -189,8 +218,7 @@ def test_cost_analysis_real_jit_does_not_crash():
     import jax.numpy as jnp
 
     jitted = jax.jit(lambda x: jnp.dot(x, x))
-    out = cost_analysis_for(jitted,
-                            (np.ones((8, 8), dtype=np.float32),))
+    out = cost_analysis_of(jitted.lower(np.ones((8, 8), dtype=np.float32)))
     # CPU backends may or may not expose a cost model — both are legal,
     # but a present one must carry the normalized keys
     if out is not None:
