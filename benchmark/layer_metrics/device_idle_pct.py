@@ -1,0 +1,9 @@
+"""1 - union of device-op intervals / traced window (every family of cells:
+`device_idle_pct.ingest`, `.search`, ...)."""
+
+
+def read(ctx):
+    t = ctx.get("trace")
+    if not t or t["window_s"] <= 0 or t["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
