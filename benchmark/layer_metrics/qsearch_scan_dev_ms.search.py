@@ -1,0 +1,8 @@
+"""Device ms per query of the fused search's scan (corpus cast, matmul,
+mask): ops traced under `scan` inside `symbiont.qsearch`, per `jit_fn`
+program of the traced sub-window."""
+from _scopes import ms_per_program
+
+
+def read(ctx):
+    return ms_per_program(ctx, "symbiont.qsearch", ("scan",))

@@ -42,7 +42,12 @@ from symbiont_tpu.resilience.admission import (
     AdmissionReject,
     StrideClock,
 )
-from symbiont_tpu.utils.telemetry import metrics
+from symbiont_tpu.utils.telemetry import (
+    carry_context,
+    current_headers,
+    metrics,
+    span,
+)
 
 log = logging.getLogger(__name__)
 
@@ -352,6 +357,8 @@ class _BatcherBase:
         if self._closed:
             raise RuntimeError("batcher closed")
         item._t_submit = time.monotonic()  # queue-age gauge reads this
+        # the submitter's open span: a flush rides its first item's trace
+        item._trace_ctx = current_headers()
         self._queue.append(item)
         self._queued += self._size(item)
         self._wake.set()
@@ -381,6 +388,13 @@ class _BatcherBase:
         self._queued -= size
         if taken:
             labels = {"service": "engine", "batcher": self.kind}
+            now = time.monotonic()
+            for item in taken:
+                # time work waited for the batcher, per item taken: its
+                # submit -> this chunk (an item a session put back is seen
+                # again, still from its submit)
+                metrics.observe("batcher.queue_wait_ms",
+                                (now - item._t_submit) * 1e3, labels=labels)
             fill = size / self.max_batch if self.max_batch else 0.0
             metrics.observe("batcher.flush_fill_ratio", fill, labels=labels)
             metrics.gauge_set("batcher.last_flush_fill_ratio", round(fill, 4),
@@ -423,7 +437,12 @@ class _BatcherBase:
         if self._inflight_n == 1:
             self._busy_since = t0
         try:
-            await self._flush(batch)
+            # busy time of the batcher: one flush, whatever the subclass
+            # does in it (tokenize, pad, dispatch, fetch; a whole decode
+            # session), on the trace of the first item it carries
+            with span("batcher.flush", batch[0]._trace_ctx, batcher=self.kind,
+                      rows=sum(self._size(item) for item in batch)):
+                await self._flush(batch)
         finally:
             self._inflight.release()
             t1 = time.monotonic()
@@ -524,7 +543,7 @@ class MicroBatcher(_BatcherBase):
         try:
             # off the event loop: the forward is CPU/TPU-bound
             vecs = await asyncio.get_running_loop().run_in_executor(
-                None, self.engine.embed_texts, texts)
+                None, carry_context(self.engine.embed_texts), texts)
             offset = 0
             for p in batch:
                 n = len(p.texts)

@@ -53,7 +53,7 @@ from symbiont_tpu.models import bert as bert_mod
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.obs.hbm import guard_oom, hbm_ledger
 from symbiont_tpu.obs.xprof import compile_analysis_for, dispatch_ledger
-from symbiont_tpu.utils.telemetry import maybe_profile, metrics
+from symbiont_tpu.utils.telemetry import metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -279,6 +279,12 @@ class TpuEngine:
         return cfg
 
     def _get_executable(self, kind: str, L: int, B: int) -> Callable:
+        """The compiled program of one (kind, length, batch) shape. Every
+        jitted function keeps the Python name `fn` (the benchmark's
+        rooflines find the XLA module `jit_fn`, and the decorator below
+        keeps it); a program and its phases are named by `jax.named_scope`
+        instead, which lands in each device op's metadata (`tf_op` in a
+        profiler trace) and changes nothing that is compiled."""
         import jax
 
         key = (kind, L, B)
@@ -294,6 +300,7 @@ class TpuEngine:
                                        self.pooling, self.normalize)
             d2h_bf16 = self.config.dtype == "bfloat16"
 
+            @jax.named_scope("symbiont.embed")
             def fn(params, ids, lengths):
                 # mask rebuilt on device from lengths (half the h2d bytes);
                 # ids may arrive uint16 (another halving — see _ids_dtype);
@@ -322,6 +329,7 @@ class TpuEngine:
             cap, k = B  # for qsearch the batch slot carries (capacity, top_k)
             mesh = self.mesh if self._corpus_sharded(cap) else None
 
+            @jax.named_scope("symbiont.qsearch")
             def fn(params, ids, mask, corpus, n_valid):
                 ids = ids.astype(jnp.int32)
                 emb = bert_mod.embed_sentences(params, ids, mask, cfg,
@@ -331,15 +339,19 @@ class TpuEngine:
                     from symbiont_tpu.parallel.sharding import corpus_topk
 
                     return corpus_topk(mesh, corpus, q, n_valid, k)
-                scores = (corpus.astype(jnp.bfloat16) @ q).astype(jnp.float32)
-                valid = jnp.arange(cap) < n_valid
-                scores = jnp.where(valid, scores, -jnp.inf)
-                return jax.lax.top_k(scores, k)
+                with jax.named_scope("scan"):
+                    scores = (corpus.astype(jnp.bfloat16) @ q
+                              ).astype(jnp.float32)
+                    valid = jnp.arange(cap) < n_valid
+                    scores = jnp.where(valid, scores, -jnp.inf)
+                with jax.named_scope("topk"):
+                    return jax.lax.top_k(scores, k)
         elif kind == "rerank":
             import jax.numpy as jnp
 
             ccfg = self._attn_cfg(self.cross_cfg, L)
 
+            @jax.named_scope("symbiont.rerank")
             def fn(params, ids, lengths, len_a):
                 # mask and token-type ids rebuilt on device from two [B]
                 # length vectors (vs two [B, L] matrices over the wire)
@@ -515,6 +527,16 @@ class TpuEngine:
                 and self.mesh.shape.get("data", 1) > 1
                 and cap % self.mesh.shape["data"] == 0)
 
+    @staticmethod
+    def _note_stages(name: str, t0: float, t_dispatched: float) -> None:
+        """The two stages of one engine call, stamped at a call and a fetch
+        that exist: `<name>.host_ms` (entry -> the last dispatch returned:
+        tokenize, pad, h2d, dispatch) and `<name>.device_wait_ms` (from
+        there -> every result on the host)."""
+        metrics.observe(f"{name}.host_ms", (t_dispatched - t0) * 1e3)
+        metrics.observe(f"{name}.device_wait_ms",
+                        (time.perf_counter() - t_dispatched) * 1e3)
+
     # ---------------------------------------------------------------- embed
 
     def _prep_executor(self):
@@ -559,14 +581,16 @@ class TpuEngine:
         full device round-trip per batch)."""
         if len(texts) == 0:
             return np.zeros((0, self.model_cfg.hidden_size), np.float32)
-        max_len = min(self.config.length_buckets[-1],
-                      self.model_cfg.max_position_embeddings)
-        buckets = [b for b in self.config.length_buckets
-                   if b <= self.model_cfg.max_position_embeddings]
-        out = np.zeros((len(texts), self.model_cfg.hidden_size), np.float32)
-        chunk = self.config.host_prep_chunk
-        pending = []
-        with maybe_profile("engine.embed"):
+        with span("engine.embed", rows=len(texts)):
+            t0 = time.perf_counter()
+            max_len = min(self.config.length_buckets[-1],
+                          self.model_cfg.max_position_embeddings)
+            buckets = [b for b in self.config.length_buckets
+                       if b <= self.model_cfg.max_position_embeddings]
+            out = np.zeros((len(texts), self.model_cfg.hidden_size),
+                           np.float32)
+            chunk = self.config.host_prep_chunk
+            pending = []
             if 0 < chunk < len(texts):
                 texts = list(texts)
                 pool = self._prep_executor()
@@ -585,6 +609,9 @@ class TpuEngine:
                 self._dispatch_embed(
                     self.tokenizer.encode_batch(list(texts), max_len),
                     0, buckets, pending)
+            # every batch is dispatched: what is left is waiting for the
+            # device and fetching (no stamp adds a sync of its own)
+            t_dispatched = time.perf_counter()
             if len(pending) > 1 and self._batch_sharding is None:
                 # grouped single-copy fetch (see _concat in __init__); the
                 # DP-sharded path keeps per-batch fetches — its outputs live
@@ -612,6 +639,7 @@ class TpuEngine:
                     out[rows] = np.asarray(res_dev)[:n_real]
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
                                                len(pending))
+            self._note_stages("engine.embed", t0, t_dispatched)
         self._bump(embed_calls=1, sentences_embedded=len(texts))
         return out
 
@@ -628,22 +656,26 @@ class TpuEngine:
         numpy. corpus_dev rows must be L2-normalized ([cap, D] on device)."""
         import jax.numpy as jnp
 
-        max_len = min(self.config.length_buckets[-1],
-                      self.model_cfg.max_position_embeddings)
-        encoded = self.tokenizer.encode(text, max_len)
-        buckets = [b for b in self.config.length_buckets
-                   if b <= self.model_cfg.max_position_embeddings]
-        bucket = choose_bucket(len(encoded), buckets)
-        ids, mask = pad_to_bucket([encoded], bucket, self.tokenizer.pad_id,
-                                  dtype=self._ids_dtype)
-        cap = corpus_dev.shape[0]
-        with maybe_profile("engine.qsearch"):
+        with span("engine.qsearch", top_k=top_k):
+            t0 = time.perf_counter()
+            max_len = min(self.config.length_buckets[-1],
+                          self.model_cfg.max_position_embeddings)
+            encoded = self.tokenizer.encode(text, max_len)
+            buckets = [b for b in self.config.length_buckets
+                       if b <= self.model_cfg.max_position_embeddings]
+            bucket = choose_bucket(len(encoded), buckets)
+            ids, mask = pad_to_bucket([encoded], bucket, self.tokenizer.pad_id,
+                                      dtype=self._ids_dtype)
+            cap = corpus_dev.shape[0]
             fn = self._get_executable("qsearch", bucket, (cap, top_k))
             scores, idx = fn(self.params, jnp.asarray(ids), jnp.asarray(mask),
                              corpus_dev, n_valid)
+            t_dispatched = time.perf_counter()
             _start_host_copies((scores, idx))  # both d2h copies in flight
             self._bump(qsearch_calls=1)
-            return np.asarray(scores), np.asarray(idx)
+            out = np.asarray(scores), np.asarray(idx)
+            self._note_stages("engine.qsearch", t0, t_dispatched)
+            return out
 
     # --------------------------------------------------------------- rerank
 
@@ -666,7 +698,9 @@ class TpuEngine:
         out = np.zeros((len(passages),), np.float32)
 
         pending = []
-        with maybe_profile("engine.rerank"):
+        # not "engine.rerank": EngineService's handler span of the rerank op
+        # has that name, and two things in one histogram are neither
+        with span("engine.rerank.forward", rows=len(passages)):
             for bucket, indices in plan_batches(lengths, buckets,
                                                 self._plan_cap):
                 ids, lens = pad_ids_rows([pairs[i][0] for i in indices],

@@ -38,7 +38,7 @@ from symbiont_tpu.obs.hbm import guard_oom, hbm_ledger
 from symbiont_tpu.obs.usage import usage
 from symbiont_tpu.obs.xprof import dispatch_ledger
 from symbiont_tpu.resilience.admission import DEFAULT_TENANT
-from symbiont_tpu.utils.telemetry import maybe_profile, metrics
+from symbiont_tpu.utils.telemetry import metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -720,7 +720,10 @@ class LmEngine:
         with self._lock:
             self._key, sub = jax.random.split(self._key)
             t0 = time.perf_counter()
-            with maybe_profile("engine.generate"):
+            # not "engine.generate": EngineService's handler span of the
+            # generate op has that name, and two things in one histogram
+            # are neither
+            with span("lm.generate", rows=n):
                 tokens, lengths = gpt_mod.generate(
                     self.params, jnp.asarray(prompt_ids),
                     jnp.asarray(prompt_mask),

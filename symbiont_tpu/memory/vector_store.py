@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from symbiont_tpu.config import VectorStoreConfig
+from symbiont_tpu.utils.telemetry import span
 
 log = logging.getLogger(__name__)
 
@@ -309,18 +310,21 @@ class VectorStore:
         engine's fused embed+top-k executable (one device round-trip instead
         of embed then search). Same results as search(embed_query(text)) —
         asserted in tests — with the same static-k bucketing."""
-        with self._lock:
-            n = len(self._ids)
-            if n == 0 or top_k <= 0:
-                return []
-            self._sync_device()
-            corpus = self._device_corpus
-            k = self._k_static(top_k, n, corpus.shape[0])
-        # device call (and any first-shape compile) outside the lock — see
-        # search() for why the snapshot stays valid
-        scores, idx = engine.embed_and_search(text, corpus, n, k)
-        with self._lock:
-            return self._hits_from(scores, idx, top_k)
+        # the thread-side whole of one fused query: lock, device sync, the
+        # engine's call (its own span inside), hits assembly
+        with span("store.search_fused", top_k=top_k):
+            with self._lock:
+                n = len(self._ids)
+                if n == 0 or top_k <= 0:
+                    return []
+                self._sync_device()
+                corpus = self._device_corpus
+                k = self._k_static(top_k, n, corpus.shape[0])
+            # device call (and any first-shape compile) outside the lock —
+            # see search() for why the snapshot stays valid
+            scores, idx = engine.embed_and_search(text, corpus, n, k)
+            with self._lock:
+                return self._hits_from(scores, idx, top_k)
 
     def warm_fused(self, engine,
                    word_counts: Optional[Sequence[int]] = None,

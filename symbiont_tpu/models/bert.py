@@ -208,14 +208,18 @@ def bert_encode(
     """Full encoder forward → last hidden state [B, S, H] in cfg.dtype."""
     dtype = jnp.dtype(cfg.dtype)
     # shared leaf-aware cast: floating params → compute dtype, QuantTensor
-    # leaves untouched (their f32 scales must not be downcast)
-    params = quant.cast_params(params, dtype)
-    x = embeddings(params["embeddings"], input_ids, attention_mask, cfg, token_type_ids)
-    x = x.astype(dtype)
-    # additive mask bias: 0 for real tokens, large negative for padding
-    mask_bias = (1.0 - attention_mask[:, None, None, :].astype(jnp.float32)) * -1e9
-    for layer_params in params["layers"]:
-        x = encoder_layer(layer_params, x, mask_bias, cfg)
+    # leaves untouched (their f32 scales must not be downcast). Cast per
+    # subtree inside the scope that uses it, so a device trace charges the
+    # table's cast to `embeddings` and the layers' to `encoder`.
+    with jax.named_scope("embeddings"):
+        x = embeddings(quant.cast_params(params["embeddings"], dtype),
+                       input_ids, attention_mask, cfg, token_type_ids)
+        x = x.astype(dtype)
+    with jax.named_scope("encoder"):
+        # additive mask bias: 0 for real tokens, large negative for padding
+        mask_bias = (1.0 - attention_mask[:, None, None, :].astype(jnp.float32)) * -1e9
+        for layer_params in quant.cast_params(params["layers"], dtype):
+            x = encoder_layer(layer_params, x, mask_bias, cfg)
     return x
 
 
@@ -256,10 +260,11 @@ def embed_sentences(
     defaults to False; e5/bge recipes can turn it on.
     """
     hidden = bert_encode(params, input_ids, attention_mask, cfg)
-    pooled = POOLERS[pooling](hidden, attention_mask)
-    if normalize:
-        pooled = pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
-    return pooled
+    with jax.named_scope("pool"):
+        pooled = POOLERS[pooling](hidden, attention_mask)
+        if normalize:
+            pooled = pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
+        return pooled
 
 
 def cross_encoder_score(

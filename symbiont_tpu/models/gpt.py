@@ -528,6 +528,7 @@ def _decode_step(params, cfg: GPTConfig, kv_valid, temperature, top_k,
 
 
 @partial(jax.jit, static_argnames=("cfg", "max_new_tokens"))
+@jax.named_scope("symbiont.prefill")  # in each device op's metadata
 def prefill(params, prompt_ids, prompt_mask, cfg: GPTConfig,
             max_new_tokens: int):
     """Prompt forward against a fresh cache sized for max_new_tokens more
@@ -544,6 +545,7 @@ def prefill(params, prompt_ids, prompt_mask, cfg: GPTConfig,
 
 @partial(jax.jit, static_argnames=("cfg", "top_k_bucket", "eos_id"),
          donate_argnames=("cache", "cur_logits", "cur_pos", "done"))
+@jax.named_scope("symbiont.decode")  # in each device op's metadata
 def _decode_chunk_jit(params, cache, cur_logits, cur_pos, done, kv_valid,
                       keys, temperature, top_k, cfg: GPTConfig,
                       top_k_bucket: int, eos_id: int):

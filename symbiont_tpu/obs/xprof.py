@@ -31,10 +31,12 @@ deltas. This module turns the claim into first-class series:
 
 * **On-demand device trace** — ``device_trace.capture()`` wraps
   ``jax.profiler.start_trace/stop_trace`` around a bounded window
-  (ObsConfig.xprof_trace_max_s) under telemetry's process-global
-  profiler lock (the jax profiler is NOT reentrant), returning the
-  artifact dir. Served at ``POST /api/profile/device`` and cross-linked
-  from the Perfetto timeline export's otherData.
+  (ObsConfig.xprof_trace_max_s) under this module's profiler lock (the
+  jax profiler is NOT reentrant), returning the artifact dir. Every
+  program span open inside the window (utils/telemetry.span) is a
+  ``symbiont.<name>`` host event in it, on the device ops' clock. Served
+  at ``POST /api/profile/device`` and cross-linked from the Perfetto
+  timeline export's otherData.
 
 Ledger overhead rides the standing perf gate via the ``obs`` bench
 tier's ``obs_dispatch_record_per_s`` primary — the hot path is one
@@ -293,13 +295,15 @@ class DispatchLedger:
         return out
 
 
+_profile_lock = threading.Lock()
+
+
 class DeviceTraceCapture:
     """On-demand bounded jax.profiler trace window.
 
     The jax profiler is process-global and non-reentrant, so captures
-    share telemetry's ``_profile_lock`` with the maybe_profile() spot
-    profiles — a busy lock means SOMETHING is already tracing and the
-    request reports "busy" instead of corrupting the in-flight capture.
+    take ``_profile_lock`` — a busy lock means one is already tracing and
+    the request reports "busy" instead of corrupting the in-flight capture.
     """
 
     def __init__(self) -> None:
@@ -322,8 +326,6 @@ class DeviceTraceCapture:
     def capture(self, duration_s: float = 1.0) -> dict:
         """Trace device+host activity for a bounded window; returns the
         artifact dir (TensorBoard/XProf layout) or a busy/error status."""
-        from symbiont_tpu.utils import telemetry
-
         try:
             dur = float(duration_s)
         except (TypeError, ValueError):
@@ -331,7 +333,7 @@ class DeviceTraceCapture:
         if dur <= 0:
             raise ValueError("duration_s must be positive")
         dur = min(dur, self._max_s)
-        if not telemetry._profile_lock.acquire(blocking=False):
+        if not _profile_lock.acquire(blocking=False):
             metrics.inc("profile.device_busy")
             return {"status": "busy",
                     "detail": "a profiler capture is already in flight"}
@@ -353,7 +355,7 @@ class DeviceTraceCapture:
             metrics.inc("profile.device_errors")
             return {"status": "error", "detail": str(e)}
         finally:
-            telemetry._profile_lock.release()
+            _profile_lock.release()
         self._last_artifact = artifact
         metrics.inc("profile.device_captures")
         return {"status": "captured", "artifact": artifact,

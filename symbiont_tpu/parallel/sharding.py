@@ -151,17 +151,21 @@ def corpus_topk(mesh: Mesh, corpus, query, n_valid, k: int,
     k_local = min(k, rows)
 
     def local(c, q, nv):
-        base = jax.lax.axis_index(axis) * rows
-        scores = (c.astype(jnp.bfloat16) @ q.astype(jnp.bfloat16)
-                  ).astype(jnp.float32)
-        gidx = base + jnp.arange(rows)
-        scores = jnp.where(gidx < nv, scores, -jnp.inf)
-        s, li = jax.lax.top_k(scores, k_local)
-        return s, gidx[li]
+        # the same scope names as the one-device program (engine.py)
+        with jax.named_scope("scan"):
+            base = jax.lax.axis_index(axis) * rows
+            scores = (c.astype(jnp.bfloat16) @ q.astype(jnp.bfloat16)
+                      ).astype(jnp.float32)
+            gidx = base + jnp.arange(rows)
+            scores = jnp.where(gidx < nv, scores, -jnp.inf)
+        with jax.named_scope("topk"):
+            s, li = jax.lax.top_k(scores, k_local)
+            return s, gidx[li]
 
     cand_s, cand_i = shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(None), P()),
         out_specs=(P(axis), P(axis)))(corpus, query, n_valid)
-    merged_s, pos = jax.lax.top_k(cand_s, k)
-    return merged_s, cand_i[pos]
+    with jax.named_scope("topk"):
+        merged_s, pos = jax.lax.top_k(cand_s, k)
+        return merged_s, cand_i[pos]

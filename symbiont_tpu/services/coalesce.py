@@ -85,6 +85,7 @@ class _PendingUpsert:
     payloads: List[dict]
     headers: Optional[dict]
     future: asyncio.Future = field(repr=False)
+    t_add: float = 0.0  # time.monotonic() at add(), for coalesce.wait_ms
 
 
 class UpsertCoalescer:
@@ -161,7 +162,7 @@ class UpsertCoalescer:
         if not self._pending:
             self._oldest_t = time.monotonic()
         self._pending.append(_PendingUpsert(list(ids), arr, list(payloads),
-                                            headers, fut))
+                                            headers, fut, time.monotonic()))
         self._pending_rows += arr.shape[0]
         metrics.inc("coalesce.messages", labels=self._labels)
         metrics.inc("coalesce.rows", arr.shape[0], labels=self._labels)
@@ -224,6 +225,14 @@ class UpsertCoalescer:
                             labels=self._labels)
             rows = (group[0].rows if len(group) == 1
                     else np.concatenate([p.rows for p in group], axis=0))
+            # time work waited for the store, per message: add() -> the
+            # flush that carries it starts its store call. One flush is in
+            # flight at a time, so this is the age window plus the queue
+            # behind earlier flushes; the flush span below is the busy time
+            now = time.monotonic()
+            for p in group:
+                metrics.observe("coalesce.wait_ms", (now - p.t_add) * 1e3,
+                                labels=self._labels)
             try:
                 # the span rides the FIRST message's trace context: one
                 # ingest trace per flush shows the real store write it

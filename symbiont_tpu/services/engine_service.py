@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import time
 from typing import Optional
 
 import numpy as np
@@ -46,7 +47,12 @@ from symbiont_tpu.services.coalesce import (
     store_executor,
     upsert_rows_or_points,
 )
-from symbiont_tpu.utils.telemetry import child_headers, metrics, span
+from symbiont_tpu.utils.telemetry import (
+    carry_context,
+    child_headers,
+    metrics,
+    span,
+)
 
 log = logging.getLogger(__name__)
 
@@ -250,8 +256,23 @@ class EngineService(Service):
             payload = {"error_message": str(e)}
         await self._reply(msg, payload)
 
+    async def _run_on(self, executor, pool: str, fn, *args):
+        """`fn(*args)` on a pool thread, under the caller's context (its
+        spans parent to the handler's), with the time the call waited for
+        a free thread as `engine.executor_wait_ms{pool=}`."""
+        t_submit = time.perf_counter()
+
+        def started():
+            metrics.observe("engine.executor_wait_ms",
+                            (time.perf_counter() - t_submit) * 1e3,
+                            labels={"pool": pool})
+            return fn(*args)
+
+        return await asyncio.get_running_loop().run_in_executor(
+            executor, carry_context(started))
+
     async def _run_blocking(self, fn, *args):
-        return await asyncio.get_running_loop().run_in_executor(None, fn, *args)
+        return await self._run_on(None, "default", fn, *args)
 
     async def _run_store(self, fn, *args):
         """Blocking vector-store WRITES ride the dedicated bounded store
@@ -259,8 +280,7 @@ class EngineService(Service):
         upsert must not steal default-pool threads from the embed forwards
         running concurrently. Reads (search/count) stay on the default
         pool — the latency path must not queue behind a bulk flush."""
-        return await asyncio.get_running_loop().run_in_executor(
-            store_executor(), fn, *args)
+        return await self._run_on(store_executor(), "store", fn, *args)
 
     # ------------------------------------------------------------- compute
 

@@ -27,8 +27,11 @@ downstream publish links to it (services/base.py).
 from __future__ import annotations
 
 import bisect
+import contextvars
+import functools
 import json
 import logging
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -81,64 +84,6 @@ def child_headers(parent: Optional[Dict[str, str]]) -> Dict[str, str]:
     return out
 
 
-_profile_lock = threading.Lock()
-
-# flight-recorder trace id for skipped-profile markers (obs/device.py owns
-# the compile-event twin; duplicated as a literal here to keep this module
-# importable below the whole obs layer)
-_PROFILE_TRACE_ID = "profiler"
-
-
-@contextmanager
-def maybe_profile(name: str):
-    """Device-level profiling hook (SURVEY.md §5.1 plan: "JAX profiler around
-    the embed/decode steps"). When SYMBIONT_PROFILE_DIR is set, the wrapped
-    compute runs under `jax.profiler.trace` and the XPlane trace lands there
-    (view with TensorBoard's profile plugin / xprof). Off (the default) this
-    is a no-op with zero per-call cost beyond one env lookup.
-
-    Intended use: operator sets the env var on the engine process for a short
-    diagnosis window; every embed / rerank / decode call in that window
-    produces a trace annotated with `name`.
-
-    The JAX profiler is process-global and non-reentrant ("Only one profile
-    may be run at a time"); embed / rerank / generate can overlap across
-    threads, so a call that finds a profile already running proceeds
-    unprofiled rather than crashing the live request — but no longer
-    SILENTLY: `profile.skipped{name=}` increments and a `profile.skipped`
-    span lands in the flight recorder (trace id "profiler"), so an operator
-    reading the XPlane output can tell which calls of the window it is
-    missing."""
-    import os
-
-    d = os.environ.get("SYMBIONT_PROFILE_DIR")
-    if not d:
-        yield
-        return
-    if not _profile_lock.acquire(blocking=False):
-        metrics.inc("profile.skipped", labels={"name": name})
-        t0 = time.perf_counter()
-        start_s = time.time()
-        try:
-            yield
-        finally:
-            trace_store.record(SpanRecord(
-                trace_id=_PROFILE_TRACE_ID, span_id=generate_uuid(),
-                parent_id=None, name="profile.skipped", start_s=start_s,
-                duration_ms=(time.perf_counter() - t0) * 1000.0,
-                status="ok", fields={"target": name}))
-        return
-    try:
-        import jax
-
-        metrics.inc("profile.captured", labels={"name": name})
-        with jax.profiler.trace(d):
-            with jax.profiler.TraceAnnotation(name):
-                yield
-    finally:
-        _profile_lock.release()
-
-
 class SpanHandle:
     """Live-span context yielded by `span()`. `headers` is the context to
     publish downstream messages under (same trace, THIS span as the active
@@ -159,18 +104,134 @@ class SpanHandle:
         return {TRACE_HEADER: self.trace_id, SPAN_HEADER: self.span_id}
 
 
+# the open span of the running task or thread: a span() given no headers
+# takes it as its parent, so inner spans join the request that caused them
+# without every signature threading headers down
+_open_span: contextvars.ContextVar = contextvars.ContextVar(
+    "symbiont_open_span", default=None)
+
+
+def current_headers() -> Optional[Dict[str, str]]:
+    """The open span's context (what a message published now should carry),
+    or None outside any span. Queues capture it at submit so the work they
+    later do on another task can ride the submitter's trace."""
+    handle = _open_span.get()
+    return None if handle is None else handle.headers
+
+
+def carry_context(fn: Callable) -> Callable:
+    """`fn` bound to a copy of the caller's context, for `run_in_executor`
+    (which, unlike `asyncio.to_thread`, hands the pool thread an empty
+    one): spans opened on the pool thread then parent to the caller's."""
+    return functools.partial(contextvars.copy_context().run, fn)
+
+
+class _ProfilerAnnotations:
+    """Every open span as a host annotation `symbiont.<name>` (trace id as
+    an event stat) in a running `jax.profiler` trace, so the program's
+    spans lie on the host plane of the same `.xplane.pb` as the device ops,
+    on one clock. While no profile runs a span costs a flag test and one
+    dict entry here.
+
+    The profiler keeps an annotation only if it both began and ended while
+    the trace ran, and nothing tells the program when a trace starts or
+    stops: a span longer than the traced window (a 5 s store flush in a 4 s
+    window — the span that matters most there) would never appear, and
+    while everything waits behind it no other span opens or closes either.
+    So the open spans are registered here and a ticker thread (started with
+    the first span of a process that has jax) looks every `ROLL_S`: while a
+    trace runs, an open span with no annotation in it gets one, and an
+    annotation older than `ROLL_S` is closed and opened again. A span
+    shorter than `ROLL_S` is one event with its own ends; a longer one is a
+    chain of segments under one name and trace id that misses at most
+    `ROLL_S` after the trace's start and 2 x `ROLL_S` before its stop.
+
+    Only jax can start a profile, so a process that has not imported jax
+    has none to annotate, is not made to import it, and gets no ticker."""
+
+    ROLL_S = 0.1
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._open: Dict["SpanHandle", list] = {}  # [event, annotation, t]
+        self._cls = None  # jax.profiler.TraceAnnotation, once jax is there
+        self._ticker: Optional[threading.Thread] = None
+
+    def _tracing(self):
+        """The annotation class while a profile runs, else None."""
+        cls = self._cls
+        if cls is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            cls = self._cls = getattr(profiler, "TraceAnnotation", None)
+        return cls if cls is not None and cls.is_enabled() else None
+
+    @staticmethod
+    def _annotate(cls, entry: list, handle: "SpanHandle") -> None:
+        entry[1] = cls(entry[0], trace_id=handle.trace_id)
+        entry[1].__enter__()
+        entry[2] = time.monotonic()
+
+    def opened(self, handle: "SpanHandle", name: str) -> None:
+        cls = self._tracing()
+        entry = [f"symbiont.{name}", None, 0.0]
+        if cls is not None:
+            self._annotate(cls, entry, handle)
+        with self._lock:
+            self._open[handle] = entry
+            if self._ticker is None and self._cls is not None:
+                self._ticker = threading.Thread(
+                    target=self._run_ticker, name="symbiont-span-ticker",
+                    daemon=True)
+                self._ticker.start()
+
+    def closed(self, handle: "SpanHandle") -> None:
+        with self._lock:
+            annotation = self._open.pop(handle)[1]
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+
+    def tick(self) -> None:
+        """One look of the ticker: bring the open spans' annotations up to
+        date with the running trace, if there is one."""
+        cls = self._tracing()
+        if cls is None:
+            return
+        with self._lock:
+            stale = time.monotonic() - self.ROLL_S
+            for handle, entry in self._open.items():
+                if entry[1] is None or entry[2] <= stale:
+                    if entry[1] is not None:
+                        entry[1].__exit__(None, None, None)
+                    self._annotate(cls, entry, handle)
+
+    def _run_ticker(self) -> None:
+        while True:
+            time.sleep(self.ROLL_S)
+            self.tick()
+
+
+_annotations = _ProfilerAnnotations()
+
+
 @contextmanager
 def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
-    """Timed span: structured log line + `span.<name>.ms` histogram + a
-    SpanRecord in the flight recorder. Errors are accounted, not swallowed:
-    status lands on the record (queryable via /api/traces) and
-    `span.<name>.errors` increments before the exception propagates."""
+    """Timed span: `span.<name>.ms` histogram + a SpanRecord in the flight
+    recorder + a structured log line at INFO + a profiler annotation
+    (`_ProfilerAnnotations`). The parent is the span `headers` name, else the span
+    open in this task or thread, else none (a new trace). Errors are
+    accounted, not swallowed: status lands on the record (queryable via
+    /api/traces) and `span.<name>.errors` increments before the exception
+    propagates."""
     t0 = time.perf_counter()
     start_s = time.time()
-    ctx = headers or {}
+    outer = _open_span.get()
+    ctx = headers if headers and TRACE_HEADER in headers else (
+        outer.headers if outer is not None else {})
     trace_id = ctx.get(TRACE_HEADER) or generate_uuid()
     handle = SpanHandle(trace_id, generate_uuid(), ctx.get(SPAN_HEADER),
                         dict(fields))
+    _annotations.opened(handle, name)
+    _open_span.set(handle)
     status = "ok"
     try:
         yield handle
@@ -180,6 +241,11 @@ def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
         metrics.inc(f"span.{name}.errors")
         raise
     finally:
+        # set, not reset(token): a span closed from another context than
+        # the one that opened it (a generator finalized elsewhere) must
+        # not raise out of the finally
+        _open_span.set(outer)
+        _annotations.closed(handle)
         dur_ms = (time.perf_counter() - t0) * 1000
         # the trace id rides along as an exemplar: a bad histogram bucket
         # on /metrics links straight to a concrete flight-recorder trace
@@ -189,11 +255,12 @@ def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
             trace_id=trace_id, span_id=handle.span_id,
             parent_id=handle.parent_id, name=name, start_s=start_s,
             duration_ms=dur_ms, status=status, fields=handle.fields))
-        log.info(json.dumps({"span": name, "trace": trace_id,
-                             "status": status,
-                             "duration_ms": round(dur_ms, 3),
-                             **handle.fields}, ensure_ascii=False,
-                            default=str))
+        if log.isEnabledFor(logging.INFO):
+            log.info(json.dumps({"span": name, "trace": trace_id,
+                                 "status": status,
+                                 "duration_ms": round(dur_ms, 3),
+                                 **handle.fields}, ensure_ascii=False,
+                                default=str))
 
 
 # default cumulative-bucket bounds for span-duration histograms, in ms

@@ -194,16 +194,6 @@ def test_hash_tokenizer_deterministic():
     assert types[0] == 0 and types[-1] == 1
 
 
-def test_profile_hook_writes_trace(tmp_path, monkeypatch):
-    """SYMBIONT_PROFILE_DIR → embed runs under jax.profiler.trace and an
-    XPlane trace lands in the directory (SURVEY.md §5.1 plan)."""
-    monkeypatch.setenv("SYMBIONT_PROFILE_DIR", str(tmp_path))
-    eng = _small_engine()
-    eng.embed_texts(["profile me"])
-    traces = list(tmp_path.rglob("*.xplane.pb"))
-    assert traces, f"no xplane trace written under {tmp_path}"
-
-
 def test_fused_query_search_matches_split_path(tmp_path):
     """embed_and_search (one device program) must rank exactly like the
     split embed_query → store.search path."""

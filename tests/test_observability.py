@@ -503,25 +503,6 @@ def test_compile_events_land_on_the_timeline():
     _chrome_schema_check(doc, expect_spans=1)
 
 
-def test_maybe_profile_skip_is_loud(monkeypatch, tmp_path):
-    from symbiont_tpu.utils.telemetry import _profile_lock, maybe_profile
-
-    monkeypatch.setenv("SYMBIONT_PROFILE_DIR", str(tmp_path))
-    trace_store.clear()
-    before = metrics.get("profile.skipped", labels={"name": "engine.embed"})
-    assert _profile_lock.acquire(blocking=False)  # simulate a live profile
-    try:
-        with maybe_profile("engine.embed"):
-            pass  # proceeds unprofiled — but no longer silently
-    finally:
-        _profile_lock.release()
-    assert metrics.get("profile.skipped",
-                       labels={"name": "engine.embed"}) == before + 1
-    (rec,) = trace_store.spans_for("profiler")
-    assert rec.name == "profile.skipped"
-    assert rec.fields["target"] == "engine.embed"
-
-
 # ------------------------------------------------------------------ watchdog
 
 def test_watchdog_threshold_parsing():

@@ -287,7 +287,7 @@ def test_grade_executable_unknown_cost_is_all_none():
 # -------------------------------------------------- device trace capture
 
 def test_device_trace_validates_and_reports_busy(tmp_path):
-    from symbiont_tpu.utils import telemetry
+    from symbiont_tpu.obs import xprof
 
     cap = DeviceTraceCapture()
     cap.configure(trace_dir=str(tmp_path), max_s=0.2)
@@ -297,11 +297,11 @@ def test_device_trace_validates_and_reports_busy(tmp_path):
         cap.capture(duration_s="soon")
     # a capture already in flight holds the process-global profiler lock:
     # the request must report busy, never corrupt the in-flight trace
-    assert telemetry._profile_lock.acquire(blocking=False)
+    assert xprof._profile_lock.acquire(blocking=False)
     try:
         res = cap.capture(duration_s=0.05)
     finally:
-        telemetry._profile_lock.release()
+        xprof._profile_lock.release()
     assert res["status"] == "busy"
     assert cap.last_artifact is None
 
