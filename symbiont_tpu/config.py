@@ -344,7 +344,9 @@ class VectorStoreConfig:
     distance: str = "cosine"
     data_dir: str = "data/vector_store"
     device_resident: bool = True  # corpus matrix lives in TPU HBM
-    shard_capacity: int = 65536  # rows per device-resident block
+    # rows per block: the unit the device copy's capacity is rounded to and
+    # the size of the host blocks rows are appended into in place
+    shard_capacity: int = 65536
     # warm_fused pre-compiles the fused embed+top-k executables for every
     # power-of-two k bucket up to this value. Must cover the gateway's
     # ApiConfig.fused_search_max_top_k (default 16) — a fused query in an

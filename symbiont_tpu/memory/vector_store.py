@@ -13,8 +13,19 @@ API parity with the reference's Qdrant adapter:
   durable: main.rs:121-228 (wait=true at :196)
 - search(query, top_k) → hits with id, score, payload: main.rs:230-456
 
-Durability: append-only JSONL WAL + optional compacted .npy snapshot;
-load() replays snapshot + WAL tail (SURVEY.md §5.4: DB-as-truth stance kept,
+Host rows: the corpus is held on the host as a list of row blocks of
+`shard_capacity` rows each ([shard_capacity, dim] f32, unit rows; the unit the
+device copy's capacity is rounded to). A block is allocated when the one before
+it fills and is written in place from then on: an append of n rows moves
+n x dim x 4 bytes, whether or not it crosses a block edge, and never copies a
+row already stored (`vector_store.host_bytes_moved` counts such copies and
+reads 0; `vector_store.host_blocks` is the number of blocks). Row r lives at
+`divmod(r, shard_capacity)`. The device copy is assembled from the blocks, one
+copy into the padded upload (_sync_device).
+
+Durability: append-only JSONL WAL + optional compacted .npy snapshot (one
+[n, dim] f32 array, written block by block); load() reads the snapshot into
+the blocks and replays the WAL tail (SURVEY.md §5.4: DB-as-truth stance kept,
 now inside the framework).
 """
 
@@ -31,7 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from symbiont_tpu.config import VectorStoreConfig
-from symbiont_tpu.utils.telemetry import span
+from symbiont_tpu.utils.telemetry import metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -54,7 +65,8 @@ class VectorStore:
         self._ids: List[str] = []
         self._id_to_row: Dict[str, int] = {}
         self._payloads: List[dict] = []
-        self._vectors = np.zeros((0, self.dim), np.float32)  # L2-normalized rows
+        # L2-normalized rows, [shard_capacity, dim] f32 each, filled in order
+        self._blocks: List[np.ndarray] = []
         self._device_corpus = None  # padded [capacity_blocks, D] on device
         self._device_rows = 0  # rows valid in the device copy
         self._dirty = True
@@ -87,19 +99,63 @@ class VectorStore:
                 raise ValueError(
                     f"collection '{self.config.collection}' already has dim "
                     f"{self.dim}, requested {dim}")
+            if dim != self.dim:  # no rows yet: blocks of the old width go
+                self._blocks = []
             self.dim = dim
-            if self._vectors.shape[1] != dim:
-                self._vectors = np.zeros((0, dim), np.float32)
 
     def count(self) -> int:
         with self._lock:
             return len(self._ids)
+
+    # ----------------------------------------------------------- host rows
+
+    def _stored(self):
+        """The stored rows in order, one view per block (the last block's
+        may be short)."""
+        left = len(self._ids)
+        for block in self._blocks:
+            yield block[:min(left, len(block))]
+            left -= len(block)
+
+    def _gather(self, out: np.ndarray) -> np.ndarray:
+        """Copy the stored rows into the head of `out`: the one host copy an
+        upload, or a tool's whole-matrix read, makes."""
+        at = 0
+        for rows in self._stored():
+            out[at:at + len(rows)] = rows
+            at += len(rows)
+        return out
+
+    @property
+    def _vectors(self) -> np.ndarray:
+        """The stored rows as one [n, dim] f32 array, assembled on every
+        read: for tests and tools, never on a served path."""
+        return self._gather(np.empty((len(self._ids), self.dim), np.float32))
+
+    def _append(self, vecs: np.ndarray) -> None:
+        """Write `vecs` after the last stored row, in place: into the tail
+        block's free rows, then into new blocks as each fills (one call may
+        cross several edges). Only the new rows' bytes move."""
+        cap = self.config.shard_capacity
+        at, done = len(self._ids), 0
+        while done < len(vecs):
+            b, off = divmod(at + done, cap)
+            if b == len(self._blocks):
+                self._blocks.append(np.empty((cap, self.dim), np.float32))
+            take = min(cap - off, len(vecs) - done)
+            self._blocks[b][off:off + take] = vecs[done:done + take]
+            done += take
 
     # -------------------------------------------------------------- upsert
 
     def upsert(self, points: Sequence[Tuple[str, Sequence[float], dict]]) -> int:
         """Insert or overwrite points; ack only after the WAL write+flush
         (the reference's wait=true durability, main.rs:196). Returns count.
+
+        New ids are written in place after the last stored row and an
+        existing id over its own row (module docstring, "Host rows"): the
+        call costs its own rows — normalise, n x dim x 4 bytes into the
+        blocks, the WAL lines and their fsync — whatever the corpus holds.
 
         Normalization is one vectorized pass over the whole batch — the
         per-point numpy calls (asarray + norm per row) were ~1 s of CPU per
@@ -126,7 +182,8 @@ class VectorStore:
         """Tensor-frame fast path: ingest an already-packed [n, dim] float
         block (typically a read-only `np.frombuffer` view straight off the
         bus — schema/frames) without ever materializing per-float Python
-        objects. Same semantics and WAL durability as upsert().
+        objects. Same semantics, WAL durability and cost as upsert(): the
+        rows go into the host blocks in place, nothing already stored moves.
 
         Non-f32 rows (the half-width f16 wire form, or bf16 engine output)
         are upcast to f32 here — the store's in-memory matrix, WAL, and
@@ -157,6 +214,8 @@ class VectorStore:
         normed = np.divide(batch, norms, out=batch.astype(np.float32,
                                                           copy=True),
                            where=norms > 0)
+        cap = self.config.shard_capacity
+        n_before, held = len(self._ids), list(self._blocks)
         rows = []
         new_pos: Dict[str, int] = {}  # ids first seen in THIS call — a
         # duplicate id within one batch (e.g. WAL replay of an update)
@@ -164,7 +223,8 @@ class VectorStore:
         for j, (pid, payload) in enumerate(zip(ids, payloads)):
             if pid in self._id_to_row:
                 r = self._id_to_row[pid]
-                self._vectors[r] = normed[j]
+                b, off = divmod(r, cap)
+                self._blocks[b][off] = normed[j]
                 self._payloads[r] = dict(payload)
                 self._dirty = True
             elif pid in new_pos:
@@ -173,15 +233,18 @@ class VectorStore:
                 new_pos[pid] = len(rows)
                 rows.append((pid, j, dict(payload)))
         if rows:
-            new_vecs = normed[[j for _, j, _ in rows]]
-            base = len(self._ids)
-            self._vectors = (np.concatenate([self._vectors, new_vecs])
-                             if len(self._vectors) else new_vecs)
+            self._append(normed[[j for _, j, _ in rows]])
             for i, (pid, _, payload) in enumerate(rows):
                 self._ids.append(pid)
-                self._id_to_row[pid] = base + i
+                self._id_to_row[pid] = n_before + i
                 self._payloads.append(payload)
             self._dirty = True
+        # read off the blocks, not assumed: one that is another array after
+        # the call has had the rows it held copied
+        metrics.inc("vector_store.host_bytes_moved", self.dim * 4 * sum(
+            min(cap, n_before - i * cap) for i, block in enumerate(held)
+            if self._blocks[i] is not block))
+        metrics.gauge_set("vector_store.host_blocks", len(self._blocks))
         self._wal_append(list(zip(ids, batch, payloads)))
         return len(ids)
 
@@ -205,9 +268,7 @@ class VectorStore:
         if self._device_corpus is not None and not self._dirty and self._device_rows == n:
             return
         cap = self._capacity(n)
-        padded = np.zeros((cap, self.dim), np.float32)
-        if n:
-            padded[:n] = self._vectors
+        padded = self._gather(np.zeros((cap, self.dim), np.float32))
         if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
             from symbiont_tpu.parallel.sharding import batch_sharding
 
@@ -404,12 +465,22 @@ class VectorStore:
         os.fsync(self._wal_file.fileno())
 
     def compact(self) -> None:
-        """Snapshot vectors+payloads, truncate the WAL."""
+        """Snapshot vectors+payloads, truncate the WAL. The vectors file is
+        what `np.save` of the [n, dim] f32 matrix writes, written block by
+        block: the header, then each block's stored rows."""
         if not self.config.data_dir:
             return
         with self._lock:
             root = Path(self.config.data_dir)
-            np.save(root / f"{self.config.collection}.vectors.npy", self._vectors)
+            with open(root / f"{self.config.collection}.vectors.npy",
+                      "wb") as f:
+                np.lib.format.write_array_header_1_0(f, {
+                    "descr": np.lib.format.dtype_to_descr(
+                        np.dtype(np.float32)),
+                    "fortran_order": False,
+                    "shape": (len(self._ids), self.dim)})
+                for rows in self._stored():
+                    rows.tofile(f)
             meta = {"dim": self.dim, "ids": self._ids, "payloads": self._payloads}
             tmp = root / f"{self.config.collection}.meta.json.tmp"
             tmp.write_text(json.dumps(meta, ensure_ascii=False))
@@ -421,6 +492,27 @@ class VectorStore:
             if wal and wal.exists():
                 wal.unlink()
 
+    def _read_snapshot(self, path: Path) -> None:
+        """Read the snapshot's rows straight into fresh blocks: one pass over
+        the bytes, no whole matrix in between."""
+        n, cap = len(self._ids), self.config.shard_capacity
+        with open(path, "rb") as f:
+            major, _ = np.lib.format.read_magic(f)
+            shape, fortran, dtype = (
+                np.lib.format.read_array_header_1_0 if major == 1
+                else np.lib.format.read_array_header_2_0)(f)
+            if shape != (n, self.dim) or fortran or dtype != np.float32:
+                raise ValueError(
+                    f"{path}: holds {shape} {dtype}, the collection's "
+                    f"meta.json {n} ids at dim {self.dim} (float32 rows)")
+            self._blocks = []
+            for start in range(0, n, cap):
+                block = np.empty((cap, self.dim), np.float32)
+                rows = block[:min(cap, n - start)]
+                if f.readinto(rows) != rows.nbytes:
+                    raise ValueError(f"{path}: truncated at row {start}")
+                self._blocks.append(block)
+
     def load(self) -> None:
         root = Path(self.config.data_dir)
         meta_p = root / f"{self.config.collection}.meta.json"
@@ -430,8 +522,9 @@ class VectorStore:
                 self.dim = meta["dim"]
                 self._ids = list(meta["ids"])
                 self._payloads = list(meta["payloads"])
-                self._vectors = np.load(root / f"{self.config.collection}.vectors.npy")
                 self._id_to_row = {pid: i for i, pid in enumerate(self._ids)}
+                self._read_snapshot(
+                    root / f"{self.config.collection}.vectors.npy")
             wal = self._wal_path()
             skipped = 0
             if wal and wal.exists():
@@ -479,3 +572,4 @@ class VectorStore:
                         self._wal_file = wal_file
             self.last_load_skipped_lines = skipped
             self._dirty = True
+            metrics.gauge_set("vector_store.host_blocks", len(self._blocks))
