@@ -660,7 +660,7 @@ def numeric_anchor(engine, texts: list) -> dict:
         engine.params, *engine._device_batch(
             np.ones((bb, engine.config.length_buckets[0]),
                     engine._ids_dtype),
-            np.full((bb,), 4, np.int32)))
+            np.full((bb,), 4, np.int32)))[0]  # (rows, the family's aux)
     out_devices = len(probe.sharding.device_set)
     cfg32 = dataclasses.replace(engine.model_cfg, dtype="float32",
                                 attn_impl="xla")

@@ -50,6 +50,7 @@ from symbiont_tpu.engine.bucketing import (
 )
 from symbiont_tpu.engine.tokenizer import Tokenizer, load_tokenizer
 from symbiont_tpu.models import bert as bert_mod
+from symbiont_tpu.models import families
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.obs.hbm import guard_oom, hbm_ledger
 from symbiont_tpu.obs.xprof import compile_analysis_for, dispatch_ledger
@@ -80,7 +81,7 @@ class TpuEngine:
         config: Optional[EngineConfig] = None,
         mesh=None,
         params=None,
-        model_cfg: Optional[BertConfig] = None,
+        model_cfg=None,  # the family's config (BertConfig, MlaMoeConfig)
         tokenizer: Optional[Tokenizer] = None,
         pooling: str = "mean",
         normalize: bool = False,
@@ -96,10 +97,17 @@ class TpuEngine:
 
         if params is None or model_cfg is None:
             if self.config.model_dir:
-                from symbiont_tpu.models.convert import load_bert_model
-
-                params, model_cfg = load_bert_model(self.config.model_dir)
-                log.info("loaded checkpoint from %s", self.config.model_dir)
+                # the one model-family seam: the checkpoint's own config.json
+                # names its family (models/families.py)
+                family = families.family_of_checkpoint(self.config.model_dir)
+                params, model_cfg = family.load(self.config.model_dir)
+                if self.config.quantize == "none":
+                    # the mode's meaning is float32 at rest, whatever dtype
+                    # the checkpoint holds (a no-op for the BERT loader)
+                    params = jax.tree.map(
+                        lambda a: np.asarray(a, np.float32), params)
+                log.info("loaded %s checkpoint from %s", family.name,
+                         self.config.model_dir)
             else:
                 # synthetic mode: random weights at the configured dim — full
                 # pipeline runs with zero model assets (dev / bench / tests).
@@ -125,6 +133,11 @@ class TpuEngine:
                     self.config.cross_model_dir, with_pooler=True)
                 log.info("loaded cross-encoder from %s",
                          self.config.cross_model_dir)
+            elif not isinstance(model_cfg, BertConfig):
+                raise ValueError(
+                    "rerank_enabled with a non-BERT embedder needs "
+                    "cross_model_dir: the synthetic cross-encoder borrows "
+                    "the embedder's geometry, and rerank is a BERT head")
             else:
                 # synthetic cross-encoder: embedder geometry + pooler head —
                 # the rerank path runs end-to-end with zero model assets
@@ -171,6 +184,7 @@ class TpuEngine:
                                                      self.config.quantize)
             log.info("engine params quantized: %s", self.config.quantize)
         self.model_cfg = model_cfg
+        self.family = families.family_of_config(model_cfg)
         self.tokenizer = tokenizer or load_tokenizer(self.config.model_dir,
                                                      model_cfg.vocab_size)
         self.cross_params = cross_params
@@ -299,6 +313,7 @@ class TpuEngine:
             cfg, pooling, normalize = (self._attn_cfg(self.model_cfg, L),
                                        self.pooling, self.normalize)
             d2h_bf16 = self.config.dtype == "bfloat16"
+            embed = self.family.embed
 
             @jax.named_scope("symbiont.embed")
             def fn(params, ids, lengths):
@@ -309,12 +324,12 @@ class TpuEngine:
                 ids = ids.astype(jnp.int32)
                 mask = (jnp.arange(ids.shape[1]) < lengths[:, None]
                         ).astype(jnp.int32)
-                emb = bert_mod.embed_sentences(params, ids, mask, cfg,
-                                               pooling=pooling,
-                                               normalize=normalize)
-                return emb.astype(jnp.bfloat16) if d2h_bf16 else emb
+                # aux: None, or what the family's forward counted on the
+                # device (expert load), fetched with the rows
+                emb, aux = embed(params, ids, mask, cfg, pooling, normalize)
+                return (emb.astype(jnp.bfloat16) if d2h_bf16 else emb), aux
         elif kind == "qsearch":
-            # fused interactive query: BERT forward + pool + normalize +
+            # fused interactive query: encoder forward + pool + normalize +
             # cosine scores against the device-resident corpus + top-k, ONE
             # compiled program — the whole search hop is a single device
             # dispatch (the split embed→search path pays ≥2). With a mesh whose
@@ -326,14 +341,14 @@ class TpuEngine:
             import jax.numpy as jnp
 
             cfg, pooling = self._attn_cfg(self.model_cfg, L), self.pooling
+            embed = self.family.embed
             cap, k = B  # for qsearch the batch slot carries (capacity, top_k)
             mesh = self.mesh if self._corpus_sharded(cap) else None
 
             @jax.named_scope("symbiont.qsearch")
             def fn(params, ids, mask, corpus, n_valid):
                 ids = ids.astype(jnp.int32)
-                emb = bert_mod.embed_sentences(params, ids, mask, cfg,
-                                               pooling=pooling, normalize=True)
+                emb, _ = embed(params, ids, mask, cfg, pooling, True)
                 q = emb[0].astype(jnp.bfloat16)  # [D]
                 if mesh is not None:
                     from symbiont_tpu.parallel.sharding import corpus_topk
@@ -567,7 +582,21 @@ class TpuEngine:
             fn = self._get_executable("embed", bucket, bb)
             ids_d, lens_d = self._device_batch(ids, lens)
             rows = ([offset + i for i in indices] if offset else indices)
-            pending.append((rows, n_real, fn(self.params, ids_d, lens_d)))
+            pending.append((rows, n_real, *fn(self.params, ids_d, lens_d)))
+
+    @staticmethod
+    def _note_moe(counts: np.ndarray) -> None:
+        """Expert-load series of one embed dispatch: `counts` [expert layers,
+        E] = real tokens each expert took (docs/OBSERVABILITY.md)."""
+        labels = {"service": "engine"}
+        metrics.inc("engine.moe.assignments", int(counts.sum()), labels=labels)
+        metrics.inc("engine.moe.experts_idle", int((counts == 0).sum()),
+                    labels=labels)
+        for layer in counts:
+            if layer.sum() > 0:
+                metrics.observe("engine.moe.expert_load_max_over_mean",
+                                float(layer.max() / layer.mean()),
+                                labels=labels)
 
     def embed_texts(self, texts: Sequence[str]) -> np.ndarray:
         """Texts → [n, hidden] float32 embeddings. Parity surface of the
@@ -622,21 +651,25 @@ class TpuEngine:
                 for i in range(0, len(pending), self.CONCAT_FETCH_MAX):
                     grp = pending[i:i + self.CONCAT_FETCH_MAX]
                     res = (grp[0][2] if len(grp) == 1
-                           else self._concat(*[b for _, _, b in grp]))
+                           else self._concat(*[b[2] for b in grp]))
                     fetches.append((grp, res))
                 _start_host_copies(res for _, res in fetches)
                 for grp, res in fetches:
                     allv = np.asarray(res)
                     off = 0
-                    for rows, n_real, res_dev in grp:
+                    for rows, n_real, res_dev, aux in grp:
                         out[rows] = allv[off:off + n_real]
                         off += res_dev.shape[0]
+                        if aux is not None:  # computed with the rows above
+                            self._note_moe(np.asarray(aux))
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
                                                len(fetches))
             else:
-                _start_host_copies(batch for _, _, batch in pending)
-                for rows, n_real, res_dev in pending:
+                _start_host_copies(b[2] for b in pending)
+                for rows, n_real, res_dev, aux in pending:
                     out[rows] = np.asarray(res_dev)[:n_real]
+                    if aux is not None:
+                        self._note_moe(np.asarray(aux))
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
                                                len(pending))
             self._note_stages("engine.embed", t0, t_dispatched)
@@ -737,7 +770,7 @@ class TpuEngine:
                                            np.full((bb,), L, np.int32))
         fn = self._get_executable(kind, L, bb)
         if kind == "embed":
-            return fn(self.params, ids_d, lens_d)
+            return fn(self.params, ids_d, lens_d)[0]
         (len_a_d,) = self._device_batch(np.full((bb,), L // 2, np.int32))
         return fn(self.cross_params, ids_d, lens_d, len_a_d)
 

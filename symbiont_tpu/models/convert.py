@@ -69,6 +69,8 @@ def load_hf_config(model_dir: str | Path) -> dict:
 
 
 _PREFIXES = ("bert.", "roberta.", "mpnet.", "model.", "electra.")
+# a vision-language checkpoint (Kimi-VL) nests its text tower one level down
+_LM_PREFIXES = ("language_model.model.", "model.")
 
 
 def _strip_prefix(name: str) -> str:
@@ -206,6 +208,70 @@ def convert_gpt(state_dict: Dict[str, Any], cfg) -> Params:
     return params
 
 
+def convert_mla_moe(state_dict: Dict[str, Any], cfg) -> Params:
+    """Map an HF DeepSeek-V3-layout state_dict (Kimi-VL's language tower) to
+    the mla_moe.py pytree. Torch Linear [out, in] -> [in, out]; the experts
+    of a layer are stacked [E, in, out] as they are read. Leaf by leaf and
+    in the checkpoint's own dtype (bfloat16 for the published weights): no
+    float32 copy of a 16 B-parameter checkpoint is ever made on the host;
+    rank-1 leaves (norm scales, the router's correction bias) go to float32.
+    The output head (`lm_head`) and a vision tower are not read: the encoder
+    role pools hidden states of text."""
+    sd = {}
+    for k, v in state_dict.items():
+        for prefix in _LM_PREFIXES:
+            if k.startswith(prefix):
+                sd[k[len(prefix):]] = v
+                break
+
+    def take(name: str) -> np.ndarray:
+        if name not in sd:
+            raise KeyError(f"checkpoint missing tensor {name!r}; have e.g. "
+                           f"{sorted(sd)[:5]}")
+        return _to_numpy(sd.pop(name))
+
+    def kernel(name: str) -> dict:
+        return {"kernel": np.ascontiguousarray(take(f"{name}.weight").T)}
+
+    def ln(name: str) -> dict:
+        return {"scale": take(f"{name}.weight").astype(np.float32)}
+
+    def mlp(prefix: str) -> dict:
+        return {k: kernel(f"{prefix}.{k}_proj") for k in ("gate", "up", "down")}
+
+    def stacked(prefix: str, proj: str) -> dict:
+        return {"kernel": np.stack(
+            [take(f"{prefix}.experts.{e}.{proj}_proj.weight").T
+             for e in range(cfg.n_routed_experts)])}
+
+    params: Params = {"wte": take("embed_tokens.weight"),
+                      "ln_f": ln("norm"), "layers": []}
+    for i in range(cfg.num_layers):
+        p = f"layers.{i}"
+        layer = {
+            "ln1": ln(f"{p}.input_layernorm"),
+            "ln2": ln(f"{p}.post_attention_layernorm"),
+            "attn": {"q": kernel(f"{p}.self_attn.q_proj"),
+                     "kv_a": kernel(f"{p}.self_attn.kv_a_proj_with_mqa"),
+                     "kv_a_ln": ln(f"{p}.self_attn.kv_a_layernorm"),
+                     "kv_b": kernel(f"{p}.self_attn.kv_b_proj"),
+                     "o": kernel(f"{p}.self_attn.o_proj")}}
+        if i < cfg.first_k_dense_replace:
+            layer["mlp"] = mlp(f"{p}.mlp")
+        else:
+            moe = {"router": {
+                       **kernel(f"{p}.mlp.gate"),
+                       "bias": take(f"{p}.mlp.gate.e_score_correction_bias"
+                                    ).astype(np.float32)},
+                   "experts": {k: stacked(f"{p}.mlp", k)
+                               for k in ("gate", "up", "down")}}
+            if cfg.n_shared_experts:
+                moe["shared"] = mlp(f"{p}.mlp.shared_experts")
+            layer["moe"] = moe
+        params["layers"].append(layer)
+    return params
+
+
 def export_hf_bert(params: Params, cfg: BertConfig, out_dir: str | Path,
                    tokenizer_file: str | Path | None = None) -> Path:
     """Inverse of convert_bert: write a hub-format model dir
@@ -297,6 +363,14 @@ def load_gpt_model(model_dir: str | Path):
     cfg = GPTConfig.from_hf(hf_cfg)
     params = convert_gpt(load_state_dict(model_dir), cfg)
     return params, cfg
+
+
+def load_mla_moe_model(model_dir: str | Path):
+    """One-call load: (params, MlaMoeConfig) from a local HF model dir."""
+    from symbiont_tpu.models.mla_moe import MlaMoeConfig
+
+    cfg = MlaMoeConfig.from_hf(load_hf_config(model_dir))
+    return convert_mla_moe(load_state_dict(model_dir), cfg), cfg
 
 
 def load_bert_model(model_dir: str | Path, with_pooler: bool = False):

@@ -31,6 +31,11 @@ import numpy as np
 from symbiont_tpu.kv import paged as _paged
 from symbiont_tpu.kv.paged import PagedKVCache
 from symbiont_tpu.models import quant
+# RMSNorm / RoPE / SwiGLU are shared with models/mla_moe.py; the underscore
+# names are what parallel/context.py and parallel/pipeline.py import
+from symbiont_tpu.models.layers import rmsnorm as _rmsnorm
+from symbiont_tpu.models.layers import rope as _rope
+from symbiont_tpu.models.layers import swiglu
 
 Params = Any
 
@@ -143,7 +148,7 @@ def cache_bytes(cache) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Norms / RoPE
+# Norms (RMSNorm / RoPE: models/layers.py)
 # ---------------------------------------------------------------------------
 
 
@@ -152,24 +157,6 @@ def _ln(x, p, eps):
     mean = xf.mean(-1, keepdims=True)
     var = xf.var(-1, keepdims=True)
     return (((xf - mean) * jax.lax.rsqrt(var + eps)) * p["scale"] + p["bias"]).astype(x.dtype)
-
-
-def _rmsnorm(x, p, eps):
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
-    return (xf * scale * p["scale"]).astype(x.dtype)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: [B, S, H, D], positions: [B, S]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -349,10 +336,7 @@ def _block(layer, x, layer_idx, cache, positions, cfg, kv_valid):
     a, cache = _attn(layer, _rmsnorm(x, layer["ln1"], cfg.layer_norm_eps),
                      layer_idx, cache, positions, cfg, kv_valid)
     x = x + a
-    h = _rmsnorm(x, layer["ln2"], cfg.layer_norm_eps)
-    gate = jax.nn.silu(quant.mm(h, layer["mlp"]["gate"]["kernel"]))
-    up = quant.mm(h, layer["mlp"]["up"]["kernel"])
-    h = quant.mm(gate * up, layer["mlp"]["down"]["kernel"])
+    h = swiglu(_rmsnorm(x, layer["ln2"], cfg.layer_norm_eps), layer["mlp"])
     return x + h, cache
 
 
@@ -386,11 +370,8 @@ def block_nocache(layer, x: jax.Array, cfg: GPTConfig, attn) -> jax.Array:
         h = h @ layer["mlp"]["out"]["kernel"] + layer["mlp"]["out"]["bias"]
         return x + h
     x = x + attn(_rmsnorm(x, layer["ln1"], cfg.layer_norm_eps))
-    h = _rmsnorm(x, layer["ln2"], cfg.layer_norm_eps)
-    gate = jax.nn.silu(h @ layer["mlp"]["gate"]["kernel"])
-    up = h @ layer["mlp"]["up"]["kernel"]
-    h = (gate * up) @ layer["mlp"]["down"]["kernel"]
-    return x + h
+    return x + swiglu(_rmsnorm(x, layer["ln2"], cfg.layer_norm_eps),
+                      layer["mlp"])
 
 
 def forward(

@@ -54,10 +54,13 @@ _FP8_AMAX = 448.0  # float8_e4m3fn finite max
 
 @jax.tree_util.register_pytree_node_class
 class QuantTensor:
-    """A per-channel-quantized 2-D weight: `q` (int8 or fp8, [r, c]) and
-    `scale` (f32, [c], over the LAST axis). Dequantized value = q * scale.
-    Registered as a pytree node so it flows through jit/device_put; every
-    cast-to-compute-dtype sweep must treat it as a leaf (cast_params)."""
+    """A per-channel-quantized weight: `q` (int8 or fp8, [r, c]) and `scale`
+    (f32, [c], over the LAST axis); or a stack of such kernels `q` [E, r, c]
+    with `scale` [E, c], one scale per stacked kernel and output channel
+    (expert kernels: 64 experts must not share one scale). Dequantized value
+    = q * scale. Registered as a pytree node so it flows through
+    jit/device_put; every cast-to-compute-dtype sweep must treat it as a
+    leaf (cast_params)."""
 
     __slots__ = ("q", "scale")
 
@@ -86,7 +89,8 @@ class QuantTensor:
         return cls(*children)
 
     def dequantize(self, dtype=jnp.float32):
-        return (self.q.astype(jnp.float32) * self.scale).astype(dtype)
+        scale = self.scale if self.q.ndim == 2 else self.scale[..., None, :]
+        return (self.q.astype(jnp.float32) * scale).astype(dtype)
 
 
 def is_quantized(x) -> bool:
@@ -98,12 +102,14 @@ def _leaf(x) -> bool:
 
 
 def channel_quantize(w, amax: float, qdtype) -> QuantTensor:
-    """Symmetric per-channel quantization over the last axis. Host-side,
-    runs once at load."""
+    """Symmetric per-channel quantization over the last axis; of a stack of
+    kernels [..., in, out] only the `in` axis is reduced, so every stacked
+    kernel keeps its own scales [..., out]. Host-side, runs once at load."""
     wf = jnp.asarray(w, jnp.float32)
-    scale = jnp.max(jnp.abs(wf), axis=tuple(range(wf.ndim - 1))) / amax
+    axes = tuple(range(wf.ndim - 1)) if wf.ndim <= 2 else (wf.ndim - 2,)
+    scale = jnp.max(jnp.abs(wf), axis=axes) / amax
     scale = jnp.maximum(scale, 1e-12)
-    q = wf / scale
+    q = wf / (scale if wf.ndim <= 2 else scale[..., None, :])
     if jnp.issubdtype(jnp.dtype(qdtype), jnp.integer):
         q = jnp.round(q)
     return QuantTensor(q.astype(qdtype), scale.astype(jnp.float32))
@@ -171,6 +177,20 @@ def mm(x, w):
     if isinstance(w, QuantTensor):
         return ((x @ w.q.astype(x.dtype)) * w.scale).astype(x.dtype)
     return x @ w
+
+
+def ragged_mm(x, w, group_sizes, row_group):
+    """Grouped matmul over stacked kernels `w` [G, in, out]: rows of `x`
+    [m, in] are sorted by group, `group_sizes` [G] says how many rows each
+    kernel takes (rows past their sum belong to no group: their output is
+    unspecified and the caller discards it), `row_group` [m] names each
+    row's group. One `jax.lax.ragged_dot`: no kernel is applied to a row
+    that is not its own. Quantized stacks dequantize in the epilogue with
+    the row's own group's scales."""
+    if isinstance(w, QuantTensor):
+        y = jax.lax.ragged_dot(x, w.q.astype(x.dtype), group_sizes)
+        return (y * w.scale[row_group]).astype(x.dtype)
+    return jax.lax.ragged_dot(x, w, group_sizes)
 
 
 def mm_tied(x, w):

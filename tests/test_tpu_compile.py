@@ -1,0 +1,68 @@
+"""Compile for a described (not attached) TPU v5e, at published widths: what
+interpret-free CPU tests cannot show. No chip is needed and nothing runs; a
+compile that passes is not a chip run. All such tests live in THIS file (one
+process may hold the TPU's library; the topology is described inside a
+fixture, never at import), as the on-chip-measurement guide sets out.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from symbiont_tpu.models import mla_moe
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent cache
+    # but not read back without the chip: keep it out, and the next run quiet
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_routed_experts_compile_to_grouped_matmuls_at_published_widths(
+        one_chip):
+    """Kimi-VL-A3B's expert layer (64 experts of 2,048 x 1,408, top-6) over
+    a 128 x 32-token batch: the TPU compiler takes `ragged_dot` as grouped
+    matmul kernels, and counts the FLOPs of each token's OWN six experts —
+    not of all 64 on every token (10.7 x as many)."""
+    cfg = mla_moe.MlaMoeConfig()
+    T, H, I, E, k = (4096, cfg.hidden_size, cfg.moe_intermediate_size,
+                     cfg.n_routed_experts, cfg.num_experts_per_tok)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    p = {"experts": {"gate": {"kernel": shape(E, H, I)},
+                     "up": {"kernel": shape(E, H, I)},
+                     "down": {"kernel": shape(E, I, H)}}}
+    compiled = jax.jit(
+        lambda p, x, idx, w, real: mla_moe.routed_experts(p, x, idx, w, real,
+                                                          cfg)
+    ).lower(p, shape(T, H), shape(T, k, dtype=jnp.int32),
+            shape(T, k, dtype=jnp.float32), shape(T, dtype=jnp.bool_)
+            ).compile()
+    text = compiled.as_text()
+    assert text.count("ragged_dot_tiling") == 3, "three grouped matmuls"
+    own = 2.0 * 3 * H * I * T * k
+    flops = compiled.cost_analysis()["flops"]
+    assert own <= flops < 1.2 * own, (flops, own)
