@@ -87,6 +87,11 @@ JAX_JIT_IN_FUNCTION_ALLOWED = {
         "THE executable cache: jit wrapped per (kind, length-bucket, "
         "batch-bucket) key, raced-miss-safe under _lock, LRU-bounded by "
         "executable_cache_size — each signature compiles exactly once",
+    ("symbiont_tpu/models/mla_moe.py", "encode"):
+        "no executable: `encode` runs inside the engine's own jit, and the "
+        "two inner jits live for that one trace so that the layers of a "
+        "program (one shape each) are traced and lowered once and called "
+        "per layer; the compiler inlines the calls",
 }
 
 # deliberate device→host sync points on the dispatch hot path: one bulk

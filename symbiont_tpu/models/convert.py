@@ -18,7 +18,9 @@ transposed to [in, out] on conversion (see bert.py layout note).
 from __future__ import annotations
 
 import json
+import os
 import re
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Any, Dict
 
@@ -34,6 +36,31 @@ def _to_numpy(t) -> np.ndarray:
         return t
     # torch tensor (cpu) without importing torch at module load
     return t.detach().cpu().numpy()
+
+
+def _transposed(mats) -> np.ndarray:
+    """n torch kernels [out, in] (numpy) -> ONE C-contiguous array [n, in, out].
+
+    A transposing copy is strided and slow (a 2,048 x 1,408 bfloat16 kernel:
+    ~30 ms on one core), and a stack of `.T` views only postpones it to the
+    upload, one leaf at a time: an expert model pays it 768 times at every
+    boot. So the copies are made here, once, in blocks of columns on a
+    thread pool (numpy drops the GIL for a plain-integer copy, which is why
+    the bytes are viewed as unsigned ints of the same width)."""
+    rows, cols = mats[0].shape
+    out = np.empty((len(mats), cols, rows), mats[0].dtype)
+    bits = np.dtype(f"u{out.dtype.itemsize}")
+    raw, step = out.view(bits), 512
+    srcs = [np.ascontiguousarray(w).view(bits) for w in mats]
+
+    def copy(job):
+        e, lo = job
+        raw[e, lo:lo + step] = srcs[e][:, lo:lo + step].T
+
+    jobs = [(e, lo) for e in range(len(mats)) for lo in range(0, cols, step)]
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        list(pool.map(copy, jobs))
+    return out
 
 
 def load_state_dict(model_dir: str | Path) -> Dict[str, np.ndarray]:
@@ -231,7 +258,7 @@ def convert_mla_moe(state_dict: Dict[str, Any], cfg) -> Params:
         return _to_numpy(sd.pop(name))
 
     def kernel(name: str) -> dict:
-        return {"kernel": np.ascontiguousarray(take(f"{name}.weight").T)}
+        return {"kernel": _transposed([take(f"{name}.weight")])[0]}
 
     def ln(name: str) -> dict:
         return {"scale": take(f"{name}.weight").astype(np.float32)}
@@ -240,8 +267,8 @@ def convert_mla_moe(state_dict: Dict[str, Any], cfg) -> Params:
         return {k: kernel(f"{prefix}.{k}_proj") for k in ("gate", "up", "down")}
 
     def stacked(prefix: str, proj: str) -> dict:
-        return {"kernel": np.stack(
-            [take(f"{prefix}.experts.{e}.{proj}_proj.weight").T
+        return {"kernel": _transposed(
+            [take(f"{prefix}.experts.{e}.{proj}_proj.weight")
              for e in range(cfg.n_routed_experts)])}
 
     params: Params = {"wte": take("embed_tokens.weight"),

@@ -22,9 +22,11 @@ rest all run.
   (`noaux_tc`, one group), weights = chosen scores normalised x
   routed_scaling_factor. The expert layer DROPS NO TOKEN and computes no
   expert for a token that did not choose it: assignments are sorted by
-  expert and each projection is one grouped matmul (`jax.lax.ragged_dot`)
-  over the stacked kernels. Padding positions are sent to no expert (they
-  sort past the last group): their rows never reach a pooled row.
+  expert and each projection is one grouped matmul over the stacked
+  kernels (`quant.ragged_mm`: the Pallas kernel of ops/grouped_matmul.py on
+  the chip, `jax.lax.ragged_dot` elsewhere). Padding positions are sent
+  to no expert (they sort past the last group): their rows never reach a
+  pooled row.
 - `embed_sentences` returns, beside the rows, the per-layer per-expert
   counts of REAL tokens ([expert layers, E] int32): the engine's load
   counters read them at the fetch it already makes.
@@ -236,14 +238,20 @@ def encode(params: Params, input_ids: jax.Array, attention_mask: jax.Array,
     with jax.named_scope("embeddings"):
         x = quant.take(quant.cast_params(params["wte"], dtype),
                        input_ids).astype(dtype)
+    # every layer's attention has one shape, and so has every expert layer:
+    # a `jax.jit` of this call's own traces and lowers each ONCE and calls
+    # it per layer (the compiler inlines the calls: the program is the
+    # same). A warmed bucket is traced and lowered at every boot, inside
+    # `setup_s`, and the expert layers are most of that.
+    attention = jax.jit(lambda p, ln, x, mask: mla_attention(
+        p, rmsnorm(x, ln, cfg.rms_norm_eps), mask, cfg))
+    experts = jax.jit(lambda p, x, mask, ln: moe_ffn(p, x, mask, ln, cfg))
     counts = []
     for layer in quant.cast_params(params["layers"], dtype):
         with jax.named_scope("mla"):
-            x = x + mla_attention(
-                layer["attn"], rmsnorm(x, layer["ln1"], cfg.rms_norm_eps),
-                attention_mask, cfg)
+            x = x + attention(layer["attn"], layer["ln1"], x, attention_mask)
         if "moe" in layer:
-            y, c = moe_ffn(layer["moe"], x, attention_mask, layer["ln2"], cfg)
+            y, c = experts(layer["moe"], x, attention_mask, layer["ln2"])
             counts.append(c)
         else:
             with jax.named_scope("dense_ffn"):

@@ -184,13 +184,19 @@ def ragged_mm(x, w, group_sizes, row_group):
     [m, in] are sorted by group, `group_sizes` [G] says how many rows each
     kernel takes (rows past their sum belong to no group: their output is
     unspecified and the caller discards it), `row_group` [m] names each
-    row's group. One `jax.lax.ragged_dot`: no kernel is applied to a row
-    that is not its own. Quantized stacks dequantize in the epilogue with
-    the row's own group's scales."""
+    row's group. One grouped matmul (ops/grouped_matmul.py: the Pallas
+    kernel on the chip where the shapes tile, `jax.lax.ragged_dot`
+    elsewhere): no kernel is applied to a row that is not its own.
+    Quantized stacks dequantize in the epilogue with the row's own group's
+    scales."""
+    # here and not at the top: pallas costs a second and a half to import,
+    # and a BERT process never routes an expert (bert.py does the same)
+    from symbiont_tpu.ops.grouped_matmul import grouped_matmul
+
     if isinstance(w, QuantTensor):
-        y = jax.lax.ragged_dot(x, w.q.astype(x.dtype), group_sizes)
+        y = grouped_matmul(x, w.q.astype(x.dtype), group_sizes)
         return (y * w.scale[row_group]).astype(x.dtype)
-    return jax.lax.ragged_dot(x, w, group_sizes)
+    return grouped_matmul(x, w, group_sizes)
 
 
 def mm_tied(x, w):

@@ -249,6 +249,23 @@ def test_hf_names_round_trip(checkpoint):
     assert cfg.hidden_size == 64 and cfg.n_routed_experts == 8
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_stacked_kernels_are_transposed_once_and_contiguous(dtype):
+    """Torch [out, in] kernels become ONE C-contiguous [n, in, out] array
+    (the upload then copies nothing), whatever the width: 700 columns are
+    one block of 512 and a rest."""
+    import ml_dtypes
+
+    dt = np.dtype(getattr(ml_dtypes, dtype, dtype))
+    rng = np.random.default_rng(4)
+    mats = [rng.standard_normal((37, 700)).astype(dt) for _ in range(5)]
+    got = convert._transposed(mats)
+    assert got.shape == (5, 700, 37) and got.dtype == dt
+    assert got.flags["C_CONTIGUOUS"]
+    want = np.stack([m.T for m in mats])
+    assert (got.astype(np.float32) == want.astype(np.float32)).all()
+
+
 def test_a_vision_language_checkpoint_nests_its_text_tower(checkpoint):
     out, params, _, _ = checkpoint
     from safetensors.numpy import load_file
@@ -287,6 +304,156 @@ def test_stacked_kernels_get_a_scale_per_expert_and_channel():
     got = quant.ragged_mm(jnp.asarray(x), qt, sizes, group)
     want = np.concatenate([x[:3] @ w[0], x[3:8] @ w[2], x[8:] @ w[3]])
     assert _rel(got, want).max() < 0.02
+
+
+# ------------------------------------------- the Pallas grouped matmul
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """What `grouped_matmul` sees on the chip (a `tpu` backend), with the
+    kernel run by the Pallas TPU interpreter: uninitialised buffers hold
+    NaN there, so a row the kernel must not write shows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+GROUPED_CASES = {
+    # 8 groups over 256 rows of 128 -> 256: two row tiles of 128
+    "an_empty_group": ([40, 0, 88, 30, 0, 50, 48, 0], 128, 256, False),
+    "a_group_straddles_two_row_tiles": ([100, 80, 76, 0, 0, 0, 0, 0],
+                                        128, 256, False),
+    "two_groups_inside_one_tile": ([128, 50, 78, 0, 0, 0, 0, 0],
+                                   128, 256, False),
+    "rows_past_the_last_group": ([30, 0, 20, 0, 0, 0, 10, 0], 128, 256,
+                                 False),
+    "no_row_in_any_group": ([0] * 8, 128, 256, False),
+    # the first kernel fetched is the last group's, and none after it
+    "only_the_last_group_has_rows": ([0] * 7 + [5], 128, 256, False),
+    "a_quantized_stack_with_scales_per_expert": (
+        [40, 0, 88, 30, 0, 50, 20, 0], 128, 256, True),
+    # toy widths (the CPU tests', a lane is 128): the compiler's kernel
+    "a_toy_width_keeps_ragged_dot": ([40, 0, 88, 30, 0, 50, 48, 0], 16, 8,
+                                     False),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_grouped_matmul_kernel_equals_ragged_dot_and_the_loop(
+        as_on_the_chip, case):
+    sizes, k, n, quantized = GROUPED_CASES[case]
+    m, total = 256, sum(sizes)
+    rng = np.random.default_rng(8)
+    x = rng.standard_normal((m, k)).astype(np.float32)
+    w = rng.standard_normal((len(sizes), k, n)).astype(np.float32) * 0.1
+    if quantized:
+        w[2] *= 50.0
+        stack = quant.channel_quantize(w, 127.0, jnp.int8)
+        w = np.asarray(stack.dequantize())
+    else:
+        stack = jnp.asarray(w)
+    group = np.minimum(np.searchsorted(np.cumsum(sizes), np.arange(m),
+                                       side="right"), len(sizes) - 1)
+    path = "pallas" if k % 128 == 0 else "ragged_dot"
+    before = {p: _counter(f'moe.grouped_mm{{path="{p}"}}')
+              for p in ("pallas", "ragged_dot")}
+
+    # a fresh trace each case: the counter bumps when a call is traced
+    got = jax.jit(lambda *a: quant.ragged_mm(*a))(
+        jnp.asarray(x), stack, jnp.asarray(sizes, jnp.int32),
+        jnp.asarray(group, jnp.int32))
+
+    after = {p: _counter(f'moe.grouped_mm{{path="{p}"}}') for p in before}
+    assert {p: after[p] - before[p] for p in before} == {
+        "pallas": float(path == "pallas"),
+        "ragged_dot": float(path == "ragged_dot")}
+    # the caller's `where`: rows of no group are dropped, whatever they hold
+    got = np.asarray(jnp.where((jnp.arange(m) < total)[:, None], got, 0))
+    assert np.isfinite(got).all()
+    loop = np.zeros((m, n), np.float32)
+    at = 0
+    for g, size in enumerate(sizes):
+        loop[at:at + size] = x[at:at + size] @ w[g]
+        at += size
+    assert np.abs(got - loop).max() < 2e-5 * np.sqrt(k)
+    compiler = np.asarray(jax.lax.ragged_dot(
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(sizes, jnp.int32)))
+    assert np.abs(got[:total] - compiler[:total]).max(initial=0) < (
+        2e-5 * np.sqrt(k))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_visits_are_the_group_row_tile_pairs_that_hold_rows(seed):
+    """The kernel's grid, against a plain loop: every (group, 128-row tile)
+    pair with a row in it, in row order; which of the two kernel buffers a
+    group uses and which group's kernel to fetch next."""
+    import importlib
+
+    visits = importlib.import_module(
+        "symbiont_tpu.ops.grouped_matmul").visits
+    rng = np.random.default_rng(seed)
+    for _ in range(40):
+        G, m = int(rng.integers(1, 12)), 128 * int(rng.integers(1, 8))
+        sizes = (rng.integers(0, 300, G) * (rng.random(G) < 0.6)).astype(
+            np.int32)
+        while sizes.sum() > m:
+            sizes //= 2
+        offsets, group_ids, tile_ids, buffer, after, count = (
+            np.asarray(a) for a in visits(jnp.asarray(sizes), m))
+        ends = np.cumsum(sizes)
+        pairs = [(g, t) for g in range(G) if sizes[g]
+                 for t in range((ends[g] - sizes[g]) // 128,
+                                (ends[g] + 127) // 128)]
+        assert list(zip(group_ids[:count], tile_ids[:count])) == pairs
+        assert (offsets == np.concatenate([[0], ends])).all()
+        with_rows = [g for g in range(G) if sizes[g]]
+        assert [buffer[g] for g in with_rows] == [
+            i % 2 for i in range(len(with_rows))]
+        assert [after[g] for g in with_rows] == with_rows[1:] + [-1] * bool(
+            with_rows)
+
+
+def test_expert_layer_through_the_kernel_equals_the_loop(as_on_the_chip):
+    """The routed layer at lane-aligned widths (64 tokens x top-2 = one row
+    tile of 128; a third of the tokens padding, so rows of no group pass
+    through all three projections): what the kernel leaves in them must not
+    reach a token."""
+    cfg = mla_moe.MlaMoeConfig(
+        vocab_size=50, hidden_size=128, num_layers=2, num_heads=2,
+        intermediate_size=128, moe_intermediate_size=128,
+        n_routed_experts=8, n_shared_experts=0, num_experts_per_tok=2,
+        dtype="float32")
+    p = mla_moe.init_params(jax.random.PRNGKey(3), cfg)["layers"][1]["moe"]
+    rng = np.random.default_rng(9)
+    x = rng.standard_normal((64, 128)).astype(np.float32)
+    real = rng.random(64) < 0.67
+    idx, w = mla_moe.route(p["router"], jnp.asarray(x), cfg)
+    before = _counter('moe.grouped_mm{path="pallas"}')
+    y, counts = jax.jit(lambda *a: mla_moe.routed_experts(*a, cfg))(
+        p, jnp.asarray(x), idx, w, jnp.asarray(real))
+    assert _counter('moe.grouped_mm{path="pallas"}') - before == 3
+    assert np.isfinite(np.asarray(y)).all()
+    assert (np.asarray(y)[~real] == 0).all()
+    want = _expert_loop(p, x, idx, np.asarray(w), real)
+    assert np.abs(np.asarray(y) - want).max() < 1e-5
+    assert int(counts.sum()) == int(real.sum()) * 2
+
+
+def test_a_programs_expert_layers_share_one_trace(checkpoint):
+    """Two expert layers, one shape: `encode` traces (and lowers) the layer
+    once and calls it twice, so a program bumps `moe.grouped_mm` 3 times,
+    not 3 a layer. Every warmed bucket is traced at every boot."""
+    _, _, params32, cfg = checkpoint
+    ids, mask = _batch(np.random.default_rng(5), B=2, S=7)
+    before = _counter('moe.grouped_mm{path="ragged_dot"}')
+    got, counts = jax.jit(lambda p, i, m: mla_moe.embed_sentences(
+        p, i, m, cfg))(params32, ids, mask)
+    assert _counter('moe.grouped_mm{path="ragged_dot"}') - before == 3
+    assert counts.shape == (2, 8)
+    want, _ = _ref_rows(ids, mask)
+    assert _rel(got, want).max() < F32_TOL
 
 
 @pytest.mark.parametrize("mode", ["f16", "int8", "fp8"])
