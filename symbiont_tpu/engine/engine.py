@@ -49,6 +49,7 @@ from symbiont_tpu.engine.bucketing import (
     plan_batches,
 )
 from symbiont_tpu.engine.tokenizer import Tokenizer, load_tokenizer
+from symbiont_tpu.memory import device_corpus
 from symbiont_tpu.models import bert as bert_mod
 from symbiont_tpu.models import families
 from symbiont_tpu.models.bert import BertConfig
@@ -292,8 +293,12 @@ class TpuEngine:
         del L
         return cfg
 
-    def _get_executable(self, kind: str, L: int, B: int) -> Callable:
-        """The compiled program of one (kind, length, batch) shape. Every
+    def _get_executable(self, kind: str, L: int, *shape) -> Callable:
+        """The compiled program of one (kind, length, *shape): `shape` is
+        the batch bucket B of an `embed` or `rerank` program and (capacity,
+        k, mesh) of a `qsearch` program — the corpus's rows, the static k
+        and the mesh those rows are sharded over (None: one device), as the
+        caller read them off the corpus it was handed. Every
         jitted function keeps the Python name `fn` (the benchmark's
         rooflines find the XLA module `jit_fn`, and the decorator below
         keeps it); a program and its phases are named by `jax.named_scope`
@@ -301,11 +306,13 @@ class TpuEngine:
         profiler trace) and changes nothing that is compiled."""
         import jax
 
-        key = (kind, L, B)
+        key = (kind, L, *shape)
         with self._lock:
             if key in self._exec_cache:
                 self._exec_cache.move_to_end(key)
                 return self._exec_cache[key]
+        # what a dispatch is booked under (obs/xprof.py): kind[L=,B=]
+        sig_b = shape[0]
 
         if kind == "embed":
             import jax.numpy as jnp
@@ -332,35 +339,22 @@ class TpuEngine:
             # fused interactive query: encoder forward + pool + normalize +
             # cosine scores against the device-resident corpus + top-k, ONE
             # compiled program — the whole search hop is a single device
-            # dispatch (the split embed→search path pays ≥2). With a mesh whose
-            # 'data' axis > 1 the corpus arrives row-sharded: each shard
-            # scores its own rows and keeps a local top-k, and only the
-            # [n_shards × k] candidates cross the interconnect for the
-            # global merge (parallel/sharding.corpus_topk — result order
-            # identical to the unsharded path, pinned in tests).
+            # dispatch (the split embed→search path pays ≥2). The scan, the
+            # top-k and what they do with a row-sharded corpus are
+            # memory/device_corpus.scan_topk's.
             import jax.numpy as jnp
 
             cfg, pooling = self._attn_cfg(self.model_cfg, L), self.pooling
             embed = self.family.embed
-            cap, k = B  # for qsearch the batch slot carries (capacity, top_k)
-            mesh = self.mesh if self._corpus_sharded(cap) else None
+            cap, k, mesh = shape
+            sig_b = (cap, k)
 
             @jax.named_scope("symbiont.qsearch")
             def fn(params, ids, mask, corpus, n_valid):
                 ids = ids.astype(jnp.int32)
                 emb, _ = embed(params, ids, mask, cfg, pooling, True)
-                q = emb[0].astype(jnp.bfloat16)  # [D]
-                if mesh is not None:
-                    from symbiont_tpu.parallel.sharding import corpus_topk
-
-                    return corpus_topk(mesh, corpus, q, n_valid, k)
-                with jax.named_scope("scan"):
-                    scores = (corpus.astype(jnp.bfloat16) @ q
-                              ).astype(jnp.float32)
-                    valid = jnp.arange(cap) < n_valid
-                    scores = jnp.where(valid, scores, -jnp.inf)
-                with jax.named_scope("topk"):
-                    return jax.lax.top_k(scores, k)
+                return device_corpus.scan_topk(corpus, emb[0], n_valid, k,
+                                               mesh)
         elif kind == "rerank":
             import jax.numpy as jnp
 
@@ -380,7 +374,8 @@ class TpuEngine:
         else:
             raise ValueError(kind)
 
-        jitted = self._time_first_call(jax.jit(fn), key)
+        jitted = self._time_first_call(jax.jit(fn),
+                                       f"{kind}[L={L},B={sig_b}]")
         with self._lock:
             # two threads can race the cold-miss check above; the loser
             # discards its wrapper and reuses the winner's, so one shape
@@ -394,7 +389,7 @@ class TpuEngine:
         self._bump(compiles=1)
         return jitted
 
-    def _time_first_call(self, jitted: Callable, key=None) -> Callable:
+    def _time_first_call(self, jitted: Callable, sig: str) -> Callable:
         """Wrap one cache key's jitted fn: the first call lowers + compiles
         it AOT (obs/xprof.compile_analysis_for) and every call dispatches
         through that ONE ``Compiled`` object.
@@ -420,8 +415,6 @@ class TpuEngine:
           ledger (obs/xprof.py) and runs under the OOM guard: a
           RESOURCE_EXHAUSTED escaping XLA is recorded to the hbm forensics
           plane (postmortem + engine.oom_total{site}) and re-raised."""
-        sig = (f"{key[0]}[L={key[1]},B={key[2]}]" if key is not None
-               else "unknown")
         compiled = None  # the AOT Compiled, set once under compile_lock
         compile_lock = threading.Lock()
 
@@ -533,14 +526,6 @@ class TpuEngine:
             b = max(b, self._n_data)
             b = ((b + self._n_data - 1) // self._n_data) * self._n_data
         return b
-
-    def _corpus_sharded(self, cap: int) -> bool:
-        """Whether a [cap, D] corpus operand rides the mesh row-sharded —
-        the store shards whenever it holds the same mesh with 'data' > 1
-        (its capacity blocks are rounded to the axis size)."""
-        return (self.mesh is not None
-                and self.mesh.shape.get("data", 1) > 1
-                and cap % self.mesh.shape["data"] == 0)
 
     @staticmethod
     def _note_stages(name: str, t0: float, t_dispatched: float) -> None:
@@ -686,7 +671,9 @@ class TpuEngine:
         part 4): tokenize on host, then ONE device program does the BERT
         forward, pooling, normalization, cosine scores against the
         device-resident corpus, and top-k. Returns (scores[k], idx[k]) as
-        numpy. corpus_dev rows must be L2-normalized ([cap, D] on device)."""
+        numpy. corpus_dev is the store's device copy ([cap, D] unit rows, as
+        memory/device_corpus.place left it: its capacity and whether its
+        rows are sharded over a mesh are read off the array)."""
         import jax.numpy as jnp
 
         with span("engine.qsearch", top_k=top_k):
@@ -699,8 +686,8 @@ class TpuEngine:
             bucket = choose_bucket(len(encoded), buckets)
             ids, mask = pad_to_bucket([encoded], bucket, self.tokenizer.pad_id,
                                       dtype=self._ids_dtype)
-            cap = corpus_dev.shape[0]
-            fn = self._get_executable("qsearch", bucket, (cap, top_k))
+            fn = self._get_executable("qsearch", bucket, corpus_dev.shape[0],
+                                      top_k, device_corpus.mesh_of(corpus_dev))
             scores, idx = fn(self.params, jnp.asarray(ids), jnp.asarray(mask),
                              corpus_dev, n_valid)
             t_dispatched = time.perf_counter()

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from symbiont_tpu.config import VectorStoreConfig
+from symbiont_tpu.memory import device_corpus
 from symbiont_tpu.utils.telemetry import metrics, span
 
 log = logging.getLogger(__name__)
@@ -250,86 +251,29 @@ class VectorStore:
 
     # -------------------------------------------------------------- search
 
-    def _capacity(self, n: int) -> int:
-        """Static capacity: next multiple of shard_capacity (and of the data
-        axis size when sharded) — keeps device shapes stable across growth."""
-        block = self.config.shard_capacity
-        cap = max(block, ((n + block - 1) // block) * block)
-        if self.mesh is not None:
-            nd = self.mesh.shape.get("data", 1)
-            cap = ((cap + nd - 1) // nd) * nd
-        return cap
-
     def _sync_device(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
         n = len(self._ids)
         if self._device_corpus is not None and not self._dirty and self._device_rows == n:
             return
-        cap = self._capacity(n)
-        padded = self._gather(np.zeros((cap, self.dim), np.float32))
-        if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
-            from symbiont_tpu.parallel.sharding import batch_sharding
-
-            self._device_corpus = jax.device_put(jnp.asarray(padded),
-                                                 batch_sharding(self.mesh))
-        else:
-            self._device_corpus = jnp.asarray(padded)
+        cap = device_corpus.capacity(n, self.config.shard_capacity, self.mesh)
+        self._device_corpus = device_corpus.place(
+            self._gather(np.zeros((cap, self.dim), np.float32)), self.mesh)
         self._device_rows = n
         self._dirty = False
 
-    def _sharded(self, cap: int) -> bool:
-        """Corpus rows live sharded over the mesh 'data' axis (capacity is
-        rounded to the axis size in _capacity, so this holds whenever a
-        multi-device mesh was threaded in)."""
-        return (self.mesh is not None
-                and self.mesh.shape.get("data", 1) > 1
-                and cap % self.mesh.shape["data"] == 0)
-
     def _get_search_fn(self, cap: int, k: int):
         import jax
-        import jax.numpy as jnp
 
         key = (cap, k)
         if key not in self._search_fns:
-            mesh = self.mesh if self._sharded(cap) else None
+            mesh = (self.mesh if device_corpus.is_sharded(self.mesh, cap)
+                    else None)
 
             def fn(corpus, query, n_valid):
-                # cosine == dot product (rows and query pre-normalized);
-                # bf16 matmul on the MXU, fp32 scores. Sharded corpora do a
-                # per-shard top-k + global merge so only k candidates per
-                # shard cross the interconnect — result order identical to
-                # the single-device path (parallel/sharding.corpus_topk,
-                # pinned in tests/test_multichip_serving.py).
-                if mesh is not None:
-                    from symbiont_tpu.parallel.sharding import corpus_topk
-
-                    return corpus_topk(mesh, corpus,
-                                       query.astype(jnp.bfloat16), n_valid, k)
-                q = query.astype(jnp.bfloat16)
-                c = corpus.astype(jnp.bfloat16)
-                scores = (c @ q).astype(jnp.float32)
-                valid = jnp.arange(cap) < n_valid
-                scores = jnp.where(valid, scores, -jnp.inf)
-                return jax.lax.top_k(scores, k)
+                return device_corpus.scan_topk(corpus, query, n_valid, k, mesh)
 
             self._search_fns[key] = jax.jit(fn)
         return self._search_fns[key]
-
-    def _k_static(self, top_k: int, n: int, cap: int) -> int:
-        """Static k bucket (next power of two ≥ k, ≤ cap) bounds executables.
-
-        Floored at 8 so every interactive query with top_k ≤ 8 (the common
-        range) shares ONE executable per (capacity, length-bucket) — without
-        the floor, each distinct top_k minted a fresh XLA compile, which on a
-        cold engine blows the fused-search probe timeout per k value. Extra
-        rows cost nothing (top-8 vs top-2 is the same matmul + tiny sort) and
-        surplus entries are trimmed/-inf-filtered by the caller."""
-        k = 8
-        while k < min(top_k, n):
-            k *= 2
-        return min(k, cap)
 
     def _hits_from(self, scores, idx, top_k: int) -> List[SearchHit]:
         hits = []
@@ -359,7 +303,8 @@ class VectorStore:
             q = np.asarray(query, np.float32)
             if q.shape != (self.dim,):
                 raise ValueError(f"query dim {q.shape} != collection dim {self.dim}")
-            fn = self._get_search_fn(cap, self._k_static(top_k, n, cap))
+            fn = self._get_search_fn(
+                cap, device_corpus.k_bucket(top_k, n, cap))
         qn = float(np.linalg.norm(q))
         q = q / qn if qn > 0 else q
         scores, idx = fn(corpus, jnp.asarray(q), n)
@@ -380,7 +325,7 @@ class VectorStore:
                     return []
                 self._sync_device()
                 corpus = self._device_corpus
-                k = self._k_static(top_k, n, corpus.shape[0])
+                k = device_corpus.k_bucket(top_k, n, corpus.shape[0])
             # device call (and any first-shape compile) outside the lock —
             # see search() for why the snapshot stays valid
             scores, idx = engine.embed_and_search(text, corpus, n, k)
@@ -388,8 +333,7 @@ class VectorStore:
                 return self._hits_from(scores, idx, top_k)
 
     def warm_fused(self, engine,
-                   word_counts: Optional[Sequence[int]] = None,
-                   top_ks: Optional[Sequence[int]] = None) -> None:
+                   word_counts: Optional[Sequence[int]] = None) -> None:
         """Pre-compile the fused embed+top-k executables for the store's
         CURRENT capacity across EVERY query length bucket of the engine —
         including an empty store (capacity is the first block, which the first
@@ -400,26 +344,20 @@ class VectorStore:
         path compiles its own cold executable, and the client gets a 503
         (seen on the chip when only three of the five buckets were warmed).
         `word_counts` defaults to one text per bucket: one word more than
-        the previous bucket holds. Warms every power-of-two k bucket up
-        to config.warm_top_k (default 8 and 16) — the gateways route only
-        top_k ≤ ApiConfig.fused_search_max_top_k to the fused path, and the
-        two knobs must move together — and records the warmed capacity so
-        callers can re-warm when upserts cross a capacity block
-        (fused_warm_stale)."""
+        the previous bucket holds. Warms every k bucket up to
+        config.warm_top_k (device_corpus.warm_k_buckets: 8 and 16 by
+        default) and records the warmed capacity so callers can re-warm when
+        upserts cross a capacity block (fused_warm_stale)."""
         if word_counts is None:
             buckets = [b for b in engine.config.length_buckets
                        if b <= engine.model_cfg.max_position_embeddings]
             word_counts = [prev + 1 for prev in [0] + buckets[:-1]]
-        if top_ks is None:
-            top_ks = [8]
-            while top_ks[-1] < self.config.warm_top_k:
-                top_ks.append(top_ks[-1] * 2)
         with self._lock:
             self._sync_device()
             corpus = self._device_corpus
             n = len(self._ids)
-            ks = sorted({self._k_static(k, max(n, k), corpus.shape[0])
-                         for k in top_ks})
+            ks = device_corpus.warm_k_buckets(self.config.warm_top_k, n,
+                                              corpus.shape[0])
         for k in ks:
             for wc in word_counts:
                 engine.embed_and_search("warm " * wc, corpus, n, k)
@@ -432,7 +370,9 @@ class VectorStore:
         the owner should re-run warm_fused in the background."""
         with self._lock:
             return (self._warmed_capacity is not None
-                    and self._capacity(len(self._ids)) != self._warmed_capacity)
+                    and device_corpus.capacity(
+                        len(self._ids), self.config.shard_capacity,
+                        self.mesh) != self._warmed_capacity)
 
     # --------------------------------------------------------- persistence
 

@@ -39,7 +39,6 @@ from symbiont_tpu.parallel.mesh import (
 )
 from symbiont_tpu.parallel.sharding import (
     batch_sharding,
-    corpus_topk,
     gpt_param_sharding,
     replicate,
     shard_params,

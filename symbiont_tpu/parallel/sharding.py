@@ -119,53 +119,3 @@ def shard_params(mesh: Mesh, params: Params, spec_tree: Params) -> Params:
         spec_tree,
         is_leaf=lambda x: isinstance(x, P) or _is_quant(x),
     )
-
-
-def corpus_topk(mesh: Mesh, corpus, query, n_valid, k: int,
-                axis: str = "data"):
-    """Corpus-sharded exact top-k: per-shard `lax.top_k` + global merge.
-
-    `corpus` is [cap, D] row-sharded over `axis` (cap divisible by the axis
-    size — VectorStore._capacity guarantees it), `query` a replicated [D]
-    vector, `n_valid` the traced count of real rows. Each shard scores its
-    own rows against the replicated query (bf16 on the MXU, f32 scores) and
-    keeps its local top-k with GLOBAL row indices; the merge then top-ks the
-    [n_shards × k] candidate set. Only k candidates per shard ever cross the
-    interconnect instead of the full score vector — the term that keeps the
-    10k-corpus p50 flat at 1M+ rows.
-
-    Result-order identity with the single-device path (pinned in tests):
-    `lax.top_k` breaks score ties by position, shards concatenate in
-    global-row order, so the merged ordering is exactly the unsharded one.
-    Trace-time only (call inside jit with the mesh's sharded operands)."""
-    import jax.numpy as jnp
-
-    from jax import shard_map
-
-    nd = mesh.shape[axis]
-    cap = corpus.shape[0]
-    if cap % nd:
-        raise ValueError(f"corpus capacity {cap} not divisible by "
-                         f"{axis}={nd}")
-    rows = cap // nd
-    k_local = min(k, rows)
-
-    def local(c, q, nv):
-        # the same scope names as the one-device program (engine.py)
-        with jax.named_scope("scan"):
-            base = jax.lax.axis_index(axis) * rows
-            scores = (c.astype(jnp.bfloat16) @ q.astype(jnp.bfloat16)
-                      ).astype(jnp.float32)
-            gidx = base + jnp.arange(rows)
-            scores = jnp.where(gidx < nv, scores, -jnp.inf)
-        with jax.named_scope("topk"):
-            s, li = jax.lax.top_k(scores, k_local)
-            return s, gidx[li]
-
-    cand_s, cand_i = shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axis, None), P(None), P()),
-        out_specs=(P(axis), P(axis)))(corpus, query, n_valid)
-    with jax.named_scope("topk"):
-        merged_s, pos = jax.lax.top_k(cand_s, k)
-        return merged_s, cand_i[pos]

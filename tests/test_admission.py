@@ -11,6 +11,7 @@ for the admission arithmetic itself.
 
 import asyncio
 import json
+import threading
 import urllib.error
 import urllib.request
 
@@ -810,6 +811,7 @@ def test_sse_disconnect_cancels_stream_and_skips_final(tmp_path):
     after the abort."""
     pytest.importorskip("jax")
     from symbiont_tpu.config import LmConfig
+    from symbiont_tpu.services.text_generator import TextGeneratorService
 
     cfg = SymbiontConfig(
         vector_store=VectorStoreConfig(dim=16,
@@ -834,14 +836,40 @@ def test_sse_disconnect_cancels_stream_and_skips_final(tmp_path):
         await stack.start()
         loop = asyncio.get_running_loop()
         port = stack.api.port
-        finals = []
+        # "mid-flight" made certain: the decode is held after its first
+        # delta until the cancel has been counted. Left to itself the
+        # 256-token decode has to outlast the gateway's discovery of the
+        # closed socket (two writes to it: 0.3-0.6 s of keep-alives), and
+        # beside busy test workers it does not always — the stream then
+        # completes, and no cancel is due or sent
+        tg = next(s for s in stack.services
+                  if isinstance(s, TextGeneratorService))
+        decode, cancel_landed = tg.lm_stream, threading.Event()
+
+        def held_after_first_delta(*a, **kw):
+            for i, delta in enumerate(decode(*a, **kw)):
+                yield delta
+                if i == 0:
+                    cancel_landed.wait(60)
+
+        tg.lm_stream = held_after_first_delta
+        finals, closed = [], []
         sub = await bus.subscribe(subjects.EVENTS_TEXT_GENERATED)
+        partials = await bus.subscribe(
+            subjects.EVENTS_TEXT_GENERATED_PARTIAL)
 
         async def collect():
             async for m in sub:
                 finals.append(json.loads(m.data))
 
-        collector = asyncio.create_task(collect())
+        async def collect_partials():
+            async for m in partials:
+                chunk = json.loads(m.data)
+                if chunk["original_task_id"] == "cancel-me" and chunk["done"]:
+                    closed.append(chunk)
+
+        collectors = [asyncio.create_task(collect()),
+                      asyncio.create_task(collect_partials())]
         try:
             # SSE client follows ITS task
             reader, writer = await asyncio.open_connection("127.0.0.1",
@@ -866,23 +894,32 @@ def test_sse_disconnect_cancels_stream_and_skips_final(tmp_path):
             writer.close()
             ok = await _wait_for(
                 lambda: metrics.get("text_generator.cancelled") >= 1,
-                timeout=30.0)
+                timeout=60.0)
+            cancel_landed.set()
             assert ok, "cancel never reached the text generator"
             assert metrics.get("api.sse_gen_cancels") >= 1
-            await asyncio.sleep(0.3)  # drain any delta already in flight
+            # the generator closes a stream it abandons with a terminal
+            # chunk (done=True), after the deltas already in flight: wait
+            # for THAT, not for a fixed drain a loaded machine outlasts.
+            # 60 s: the idle case takes < 1 s.
+            assert await _wait_for(lambda: closed, timeout=60.0), \
+                "the cancelled stream was never closed"
             chunks = metrics.get("text_generator.stream_chunks")
-            await asyncio.sleep(0.5)
             # decode actually STOPPED (no further chunks) and no final
-            # message was published for the cancelled task
-            assert metrics.get("text_generator.stream_chunks") == chunks
-            assert not any(f["original_task_id"] == "cancel-me"
-                           for f in finals)
+            # message was published for the cancelled task: a wait that
+            # load can only lengthen in the passing direction
+            assert not await _wait_for(
+                lambda: metrics.get("text_generator.stream_chunks") != chunks
+                or any(f["original_task_id"] == "cancel-me" for f in finals),
+                timeout=1.0)
+            assert len(closed) == 1
             # stream path holds no session rows: gauges at baseline
             labels = {"service": "lm", "kv_dtype": "float32"}
             assert metrics.gauge_get("lm.kv_rows_active",
                                      labels=labels) == 0
         finally:
-            collector.cancel()
+            for c in collectors:
+                c.cancel()
             await stack.stop()
             await bus.close()
 

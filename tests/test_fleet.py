@@ -541,6 +541,23 @@ def test_two_process_trace_stitching_and_federated_exposition(tmp_path):
                 raise AssertionError(
                     f"gateway never ready: {log_path.read_text()[-2000:]}")
 
+            # ... and the perception process too, before anything is sent
+            # to it: a core-subject publish to a role that has not
+            # subscribed yet is lost, and beside five busy test workers the
+            # second child can boot seconds after the gateway answers. A
+            # role's telemetry starts once its services are up (runner.py),
+            # so its entry in the gateway's roll-up is the event.
+            deadline = time.monotonic() + 120
+            while time.monotonic() < deadline:
+                status, fleet = await http(api_port, "GET", "/api/fleet",
+                                           timeout=2)
+                if status == 200 and "perception" in fleet.get("roles", ()):
+                    break
+                await asyncio.sleep(0.25)
+            else:
+                raise AssertionError(
+                    f"perception never up: {log_path.read_text()[-2000:]}")
+
             trace_id = "fleet-stitch-1"
             status, _ = await http(
                 api_port, "POST", "/api/submit-url",

@@ -11,7 +11,7 @@ stack shapes the runner now builds from config:
 - DP embed through the mesh engine matches single-device (cosine parity on
   a fixed corpus) and the per-replica padding/shard-balance gauges account;
 - corpus-sharded fused search (per-shard top-k + global merge,
-  parallel/sharding.corpus_topk) returns IDENTICAL hits (ids, scores,
+  memory/device_corpus.scan_topk) returns IDENTICAL hits (ids, scores,
   order) to the single-device store, on both the store path and the fused
   engine path;
 - TP greedy decode is token-identical to single-device through

@@ -98,6 +98,11 @@ def _boot_and_collect(tmp_path) -> set:
     cfg.runner.role = "drift"
 
     async def scenario() -> set:
+        # the registry is the process's, and this worker has run other
+        # files: what THEY registered (a real engine's series, an LM's, a
+        # test's own) is not what a stub boot registers, and must not
+        # decide this test either way
+        metrics.reset()
         stack = SymbiontStack(cfg, bus=InprocBus(), engine=_StubEngine(),
                               fetcher=lambda url: page)
         await stack.start()
@@ -109,16 +114,18 @@ def _boot_and_collect(tmp_path) -> set:
                 data=json.dumps({"url": "http://fake/doc"}).encode(),
                 headers={"Content-Type": "application/json"}, method="POST")
             assert (await loop.run_in_executor(
-                None, lambda: urllib.request.urlopen(req, timeout=10))
+                None, lambda: urllib.request.urlopen(req, timeout=60))
                 ).status == 200
-            for _ in range(200):
-                if stack.vector_store.count() >= 2:
-                    break
+            # both sentences stored: 120 s, where an idle machine takes
+            # under one (the stack shares its cores with five other workers)
+            deadline = loop.time() + 120
+            while (stack.vector_store.count() < 2
+                   and loop.time() < deadline):
                 await asyncio.sleep(0.05)
             assert stack.vector_store.count() >= 2
             # scrape once so scrape-path series (if any) register too
             await loop.run_in_executor(None, lambda: urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/metrics", timeout=10).read())
+                f"http://127.0.0.1:{port}/metrics", timeout=60).read())
             ex = metrics.export()
             return ({n for n, _, _ in ex["counters"]}
                     | {n for n, _, _ in ex["gauges"]}

@@ -456,14 +456,22 @@ def test_handler_timeout_cancels_and_frees_slot():
                              labels={"service": "oneshot", "subject": "t.x"})
         await svc.start()
         await bus.publish("t.x", b"x")
-        for _ in range(100):
-            if cancelled:
-                break
+
+        def timed_out() -> int:
+            return metrics.get("bus.handler_timeout", labels={
+                "service": "oneshot", "subject": "t.x"}) - before
+
+        # what the test is about, waited for itself: the timeout is COUNTED
+        # and the semaphore slot is back. Both happen in the service's own
+        # task a loop turn or more AFTER the handler saw its cancellation,
+        # so asserting them the moment `cancelled` fills raced that task on
+        # a loaded machine. 30 s: the idle case takes 0.1 s.
+        deadline = asyncio.get_running_loop().time() + 30
+        while (asyncio.get_running_loop().time() < deadline
+               and not (timed_out() and svc._sem._value == 32)):
             await asyncio.sleep(0.01)
         assert cancelled, "handler was not cancelled at the deadline"
-        after = metrics.get("bus.handler_timeout",
-                            labels={"service": "oneshot", "subject": "t.x"})
-        assert after - before == 1
+        assert timed_out() == 1
         # the semaphore slot came back: no hung-handler pinning
         assert svc._sem._value == 32
         await svc.stop()

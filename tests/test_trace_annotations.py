@@ -436,14 +436,14 @@ def test_the_batched_encoder_observes_its_span_and_stages_once_per_call(
 
 # ------------------------------------------------------ named scopes
 
-def _lowered(monkeypatch, engine, kind, L, B, args) -> str:
+def _lowered(monkeypatch, engine, kind, L, shape, args) -> str:
     """The lowered text of one of the engine's programs (traced, never
     compiled): the jitted function itself, without the first-call wrapper."""
     monkeypatch.setattr(TpuEngine, "_time_first_call",
-                        lambda self, jitted, key=None: jitted)
+                        lambda self, jitted, sig: jitted)
     fresh = TpuEngine(engine.config, params=engine.params,
                       model_cfg=engine.model_cfg, tokenizer=engine.tokenizer)
-    return fresh._get_executable(kind, L, B).lower(*args).as_text(
+    return fresh._get_executable(kind, L, *shape).lower(*args).as_text(
         debug_info=True)
 
 
@@ -452,11 +452,11 @@ def test_the_programs_hold_their_scopes_and_are_still_called_fn(
         monkeypatch, engine, kind):
     ids = np.ones((2, 8), engine._ids_dtype)
     if kind == "embed":
-        text = _lowered(monkeypatch, engine, "embed", 8, 2,
+        text = _lowered(monkeypatch, engine, "embed", 8, (2,),
                         (engine.params, ids, np.full((2,), 8, np.int32)))
         phases = ("embeddings", "encoder", "pool")
     else:
-        text = _lowered(monkeypatch, engine, "qsearch", 8, (64, 8),
+        text = _lowered(monkeypatch, engine, "qsearch", 8, (64, 8, None),
                         (engine.params, ids[:1], np.ones((1, 8), np.int32),
                          jnp.zeros((64, 32), jnp.float32), 12))
         phases = ("embeddings", "encoder", "pool", "scan", "topk")
