@@ -655,12 +655,8 @@ def numeric_anchor(engine, texts: list) -> dict:
     # where one served batch's output lives: all of the mesh's 'data'
     # devices under DP, one device otherwise (the four-chip finding)
     bb = engine._batch_bucket(len(texts))
-    probe = engine._get_executable("embed", engine.config.length_buckets[0],
-                                   bb)(
-        engine.params, *engine._device_batch(
-            np.ones((bb, engine.config.length_buckets[0]),
-                    engine._ids_dtype),
-            np.full((bb,), 4, np.int32)))[0]  # (rows, the family's aux)
+    probe = engine._warm_dispatch("embed", engine.config.length_buckets[-1],
+                                  bb)  # a shape the packer forms: [bb, top]
     out_devices = len(probe.sharding.device_set)
     cfg32 = dataclasses.replace(engine.model_cfg, dtype="float32",
                                 attn_impl="xla")

@@ -1,13 +1,29 @@
-"""Length buckets + padding — the replacement for pad-everything-to-max.
+"""Length buckets, padding and sequence packing — the replacement for
+pad-everything-to-max.
 
 The reference pads every sentence to the model's max_position_embeddings (514
 for mpnet) regardless of true length (reference:
 services/preprocessing_service/src/embedding_generator.rs:83-91), so a 6-token
 sentence pays a 514-token forward. SURVEY.md §5.7 sizes that waste at ~10-80×.
-Here each sequence is padded only to the smallest configured bucket ≥ its
-length, and batches are grouped per bucket; batch sizes are likewise bucketed
-so the executable cache stays bounded at |length_buckets|×|batch_buckets|
-entries (the "recompile storm" guard from SURVEY.md §7 hard-part #2).
+
+Two planners live here, one per executable kind of the engine:
+
+- **`embed` packs** (`plan_packed`): the sentences of one `embed_texts` call
+  are laid end to end into rows of ONE length bucket `L`, at most
+  `segments_per_row(L)` sentences a row and never a sentence split, by
+  first-fit-decreasing. A dispatch is `[B, L]` token ids plus `[B, S]`
+  sentence lengths; the program rebuilds per-sentence positions, a
+  block-diagonal attention mask and per-sentence pooling from the lengths
+  and returns `[B, S, H]`. `L` is the smallest bucket whose ONE row holds the
+  whole call, else the top bucket: so rows below the top bucket only ever
+  come alone, and the executable set of `embed` is |length_buckets| +
+  |batch_buckets| − 1 shapes (six for three and four: (32,1) (64,1) (128,1)
+  (128,8) (128,32) (128,128)), not the grid.
+- **`rerank` buckets** (`plan_batches`): a (query, passage) pair is one row
+  padded to the smallest bucket ≥ its length, batches grouped per bucket;
+  batch sizes are bucketed too, so that executable set stays bounded at
+  |length_buckets|×|batch_buckets| (the "recompile storm" guard from
+  SURVEY.md §7 hard-part #2).
 """
 
 from __future__ import annotations
@@ -126,3 +142,78 @@ def plan_batches(
     if cur:
         plans.append((cur_bucket, cur))
     return plans
+
+
+# ---------------------------------------------------------------- packing
+
+
+def segments_per_row(bucket: int) -> int:
+    """S: the most sentences one packed row of `bucket` tokens may hold. A
+    row's sentence lengths ship as `[S]` int32 (half the bytes of a
+    per-token segment index) and its pooled rows come back `[S, H]`, so S
+    is kept to what rows of short sentences need: a sentence of under 8
+    tokens is rare enough that the cap binds on few rows."""
+    return max(1, bucket // 8)
+
+
+def plan_packed(
+    lengths: Sequence[int],
+    length_buckets: Sequence[int],
+    max_rows: int,
+) -> Tuple[int, List[List[List[int]]]]:
+    """Pack one call's sentences into rows: -> (L, dispatches), a dispatch
+    being a list of ≤ `max_rows` rows and a row a list of original indices
+    in the order their tokens are laid.
+
+    `L` is the smallest bucket whose single row holds the whole call (its
+    tokens ≤ L, its sentences ≤ `segments_per_row(L)`), else the top bucket.
+    Sentences (clipped to L, as the tokenizer has already truncated them)
+    go first-fit-decreasing into rows of L tokens and S sentences. Plain
+    lists: at a page's 200 sentences the loop below costs a tenth of the
+    same thing spelled as a numpy call per sentence, and it runs under the
+    GIL the page's handlers share (PERF.md §6, PR 31)."""
+    top = length_buckets[-1]
+    lens = [min(int(n), top) for n in lengths]
+    n, total = len(lens), sum(lens)
+    L = next((b for b in length_buckets
+              if total <= b and n <= segments_per_row(b)), top)
+    S = segments_per_row(L)
+    rows, free = [], []  # free[r]: tokens left in row r
+    open_rows = []  # rows that can still take the shortest sentence
+    shortest = min(lens, default=0)
+    for i in sorted(range(n), key=lens.__getitem__, reverse=True):
+        need = lens[i]
+        for r in open_rows:
+            if free[r] >= need:
+                break
+        else:
+            r = len(rows)
+            rows.append([])
+            free.append(L)
+            open_rows.append(r)
+        rows[r].append(i)
+        free[r] -= need
+        if free[r] < shortest or len(rows[r]) == S:
+            open_rows.remove(r)
+    return L, [rows[i:i + max_rows] for i in range(0, len(rows), max_rows)]
+
+
+def pack_rows(
+    seqs: Sequence[Sequence[int]], rows: Sequence[Sequence[int]], bucket: int,
+    batch_rows: int, pad_id: int, dtype=np.int32,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """One packed dispatch on the host: ids `[batch_rows, bucket]` with each
+    row's sentences laid end to end, and their lengths `[batch_rows, S]`
+    int32 (0 = no sentence in that slot; rows past `len(rows)` are all
+    padding). The device rebuilds everything else from the lengths."""
+    S = segments_per_row(bucket)
+    ids, seg = [], []
+    for row in rows:
+        parts = [seqs[i][:bucket] for i in row]
+        toks = [t for part in parts for t in part]
+        ids.append(toks + [pad_id] * (bucket - len(toks)))
+        seg.append([len(part) for part in parts] + [0] * (S - len(row)))
+    empty = batch_rows - len(rows)
+    ids.extend([[pad_id] * bucket] * empty)
+    seg.extend([[0] * S] * empty)
+    return np.array(ids, dtype), np.array(seg, np.int32)

@@ -4,8 +4,12 @@ Replaces the reference's EmbeddingGenerator (reference:
 services/preprocessing_service/src/embedding_generator.rs:134-223) and its
 serial batch-8, pad-to-max loop with:
 
-- length-bucketed static shapes (engine/bucketing.py) and a bounded
-  (length-bucket × batch-bucket) executable cache — no recompile storms;
+- static shapes from length and batch buckets (engine/bucketing.py): an
+  `embed` call's sentences are PACKED end to end into full rows of one
+  length bucket (rows below the top bucket only come alone: |lengths| +
+  |batches| − 1 executables), a `rerank` pair is a row padded to its
+  bucket (the length × batch grid) — a bounded executable cache either
+  way, no recompile storms;
 - data-parallel batches over the mesh 'data' axis (params replicated,
   batch dim sharded) — the DP row of SURVEY.md §2's parallelism table;
 - a single-owner design: services talk to the engine, never to the device,
@@ -42,11 +46,14 @@ import numpy as np
 from symbiont_tpu.config import EngineConfig
 from symbiont_tpu.engine.bucketing import (
     choose_bucket,
+    pack_rows,
     pad_batch_rows_ids,
     pad_ids_rows,
     pad_to_bucket,
     padding_stats,
     plan_batches,
+    plan_packed,
+    segments_per_row,
 )
 from symbiont_tpu.engine.tokenizer import Tokenizer, load_tokenizer
 from symbiont_tpu.memory import device_corpus
@@ -185,6 +192,16 @@ class TpuEngine:
                                                      self.config.quantize)
             log.info("engine params quantized: %s", self.config.quantize)
         self.model_cfg = model_cfg
+        # the `embed` program's rows are packed (block-diagonal attention by
+        # sentence); the flash kernel takes a per-key bias and cannot
+        # express that, so an explicit attn_impl='flash' holds for rerank
+        # and the fused query and the embed program runs XLA attention
+        self._embed_cfg = model_cfg
+        if attn_impl == "flash":
+            self._embed_cfg = dataclasses.replace(model_cfg, attn_impl="xla")
+            log.warning("attn_impl='flash': the packed embed program runs "
+                        "XLA attention (the kernel has no block-diagonal "
+                        "mask); rerank and the fused query keep the kernel")
         self.family = families.family_of_config(model_cfg)
         self.tokenizer = tokenizer or load_tokenizer(self.config.model_dir,
                                                      model_cfg.vocab_size)
@@ -317,23 +334,27 @@ class TpuEngine:
         if kind == "embed":
             import jax.numpy as jnp
 
-            cfg, pooling, normalize = (self._attn_cfg(self.model_cfg, L),
+            cfg, pooling, normalize = (self._attn_cfg(self._embed_cfg, L),
                                        self.pooling, self.normalize)
             d2h_bf16 = self.config.dtype == "bfloat16"
             embed = self.family.embed
 
             @jax.named_scope("symbiont.embed")
-            def fn(params, ids, lengths):
-                # mask rebuilt on device from lengths (half the h2d bytes);
-                # ids may arrive uint16 (another halving — see _ids_dtype);
-                # bf16 engines also ship results back as bf16 (half the d2h
-                # bytes), cast to f32 on host
+            def fn(params, ids, seg_lengths):
+                # packed rows (engine/bucketing.py): ids [B, L] hold each
+                # row's sentences end to end, seg_lengths [B, S] their token
+                # counts; positions, the block-diagonal mask and per-sentence
+                # pooling are rebuilt on device from the lengths (a quarter
+                # of an explicit mask's h2d bytes). ids may arrive uint16
+                # (see _ids_dtype); bf16 engines also ship the [B, S, H]
+                # rows back as bf16 (half the d2h bytes), cast to f32 on host
                 ids = ids.astype(jnp.int32)
-                mask = (jnp.arange(ids.shape[1]) < lengths[:, None]
-                        ).astype(jnp.int32)
+                segments = bert_mod.Segments.of_lengths(seg_lengths,
+                                                        ids.shape[1])
                 # aux: None, or what the family's forward counted on the
                 # device (expert load), fetched with the rows
-                emb, aux = embed(params, ids, mask, cfg, pooling, normalize)
+                emb, aux = embed(params, ids, segments.real, cfg, pooling,
+                                 normalize, segments)
                 return (emb.astype(jnp.bfloat16) if d2h_bf16 else emb), aux
         elif kind == "qsearch":
             # fused interactive query: encoder forward + pool + normalize +
@@ -454,7 +475,9 @@ class TpuEngine:
     def _note_padding(self, true_lengths, bucket: int, batch_rows: int,
                       n_real: int) -> None:
         """Bucket padding-waste + batch fill-ratio gauges for one dispatched
-        batch (engine/bucketing.py quantified live)."""
+        batch (engine/bucketing.py quantified live): `true_lengths` of every
+        sequence in it (a packed row holds several), `n_real` of its
+        `batch_rows` rows holding any."""
         real, total = padding_stats(true_lengths, bucket, batch_rows)
         # decode-plane flight recorder, embed side (obs/engine_timeline.py):
         # the per-flush bucket-occupancy/padding timeline behind the
@@ -514,9 +537,9 @@ class TpuEngine:
         shape to run in — found by the engine-restart chaos test, where a
         redelivery surge flushed max_batch-sized work through buckets
         smaller than it. Clamping (rather than rounding shapes up) keeps
-        the executable set exactly |length_buckets|×|batch_buckets| —
-        warmup coverage and the recompile-storm bound stay intact; a surge
-        simply splits into top-bucket batches."""
+        the executable set inside the buckets — warmup coverage and the
+        recompile-storm bound stay intact; a surge simply splits into
+        top-bucket batches."""
         return min(self.config.max_batch, self.config.batch_buckets[-1])
 
     def _batch_bucket(self, n: int) -> int:
@@ -551,23 +574,29 @@ class TpuEngine:
         return self._prep_pool
 
     def _dispatch_embed(self, encoded, offset: int, buckets, pending) -> None:
-        """Plan + pad + dispatch one tokenized chunk; device calls are async,
+        """Plan + pack + dispatch one tokenized chunk; device calls are async,
         so this returns as soon as the last batch is enqueued. `offset` maps
-        chunk-local indices back to the caller's rows."""
+        chunk-local indices back to the caller's rows. A pending entry says
+        where each sentence's row lies in the dispatch's [B, S, H] result."""
         lengths = [len(e) for e in encoded]
-        for bucket, indices in plan_batches(lengths, buckets,
-                                            self._plan_cap):
-            seqs = [encoded[i] for i in indices]
-            ids, lens = pad_ids_rows(seqs, bucket, self.tokenizer.pad_id,
-                                     dtype=self._ids_dtype)
-            bb = self._batch_bucket(len(indices))
-            ids, lens, n_real = pad_batch_rows_ids(ids, lens, bb)
-            self._note_padding([lengths[i] for i in indices], bucket, bb,
-                               n_real)
-            fn = self._get_executable("embed", bucket, bb)
-            ids_d, lens_d = self._device_batch(ids, lens)
-            rows = ([offset + i for i in indices] if offset else indices)
-            pending.append((rows, n_real, *fn(self.params, ids_d, lens_d)))
+        L, dispatches = plan_packed(lengths, buckets, self._plan_cap)
+        labels = {"service": "engine"}
+        for rows in dispatches:
+            bb = self._batch_bucket(len(rows))
+            ids, seg = pack_rows(encoded, rows, L, bb, self.tokenizer.pad_id,
+                                 dtype=self._ids_dtype)
+            sent = [i for row in rows for i in row]
+            self._note_padding([lengths[i] for i in sent], L, bb, len(rows))
+            metrics.inc("engine.embed.dispatches", labels=labels)
+            metrics.observe("engine.pack.segments_per_row",
+                            len(sent) / len(rows), labels=labels)
+            fn = self._get_executable("embed", L, bb)
+            ids_d, seg_d = self._device_batch(ids, seg)
+            # (row, slot) of each sentence in the [B, S, H] result
+            at = ([r for r, row in enumerate(rows) for _ in row],
+                  [s for row in rows for s in range(len(row))])
+            pending.append(([offset + i for i in sent], at,
+                            *fn(self.params, ids_d, seg_d)))
 
     @staticmethod
     def _note_moe(counts: np.ndarray) -> None:
@@ -588,7 +617,7 @@ class TpuEngine:
         reference's generate_sentence_embeddings (embedding_generator.rs:134).
 
         Pipelined in three overlapping stages: a prep thread tokenizes chunk
-        N+1 while this thread pads/dispatches chunk N (host_prep_chunk texts
+        N+1 while this thread packs/dispatches chunk N (host_prep_chunk texts
         per chunk); jax dispatch is async, so device compute and h↔d
         transfers of successive batches overlap too; all results then
         materialize at once (serializing np.asarray per batch would pay a
@@ -618,7 +647,11 @@ class TpuEngine:
                         # chunk N+1 runs while the device chews on chunk N
                         fut = pool.submit(self.tokenizer.encode_batch,
                                           texts[nxt:nxt + chunk], max_len)
-                    self._dispatch_embed(encoded, start, buckets, pending)
+                    # a bulk call's chunks all pack into top-bucket rows
+                    # (only its last could fit a shorter one): one [., S, H]
+                    # result shape for the grouped fetch below
+                    self._dispatch_embed(encoded, start, buckets[-1:],
+                                         pending)
             else:
                 self._dispatch_embed(
                     self.tokenizer.encode_batch(list(texts), max_len),
@@ -642,8 +675,8 @@ class TpuEngine:
                 for grp, res in fetches:
                     allv = np.asarray(res)
                     off = 0
-                    for rows, n_real, res_dev, aux in grp:
-                        out[rows] = allv[off:off + n_real]
+                    for sent, at, res_dev, aux in grp:
+                        out[sent] = allv[off:off + res_dev.shape[0]][at]
                         off += res_dev.shape[0]
                         if aux is not None:  # computed with the rows above
                             self._note_moe(np.asarray(aux))
@@ -651,8 +684,8 @@ class TpuEngine:
                                                len(fetches))
             else:
                 _start_host_copies(b[2] for b in pending)
-                for rows, n_real, res_dev, aux in pending:
-                    out[rows] = np.asarray(res_dev)[:n_real]
+                for sent, at, res_dev, aux in pending:
+                    out[sent] = np.asarray(res_dev)[at]
                     if aux is not None:
                         self._note_moe(np.asarray(aux))
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
@@ -753,24 +786,32 @@ class TpuEngine:
         returns the device result for the caller to materialize. ids ride in
         the runtime wire dtype: a warm-up at int32 would compile a signature
         the uint16 runtime path never hits."""
-        ids_d, lens_d = self._device_batch(np.ones((bb, L), self._ids_dtype),
-                                           np.full((bb,), L, np.int32))
         fn = self._get_executable(kind, L, bb)
         if kind == "embed":
-            return fn(self.params, ids_d, lens_d)[0]
-        (len_a_d,) = self._device_batch(np.full((bb,), L // 2, np.int32))
+            seg = np.zeros((bb, segments_per_row(L)), np.int32)
+            seg[:, 0] = L  # one sentence fills each row
+            return fn(self.params, *self._device_batch(
+                np.ones((bb, L), self._ids_dtype), seg))[0]
+        ids_d, lens_d, len_a_d = self._device_batch(
+            np.ones((bb, L), self._ids_dtype), np.full((bb,), L, np.int32),
+            np.full((bb,), L // 2, np.int32))
         return fn(self.cross_params, ids_d, lens_d, len_a_d)
 
     def warmup(self, buckets: Optional[Sequence[int]] = None,
                batches: Optional[Sequence[int]] = None) -> None:
         """Pre-compile the hot (bucket, batch) executables so first queries
-        don't pay a cold XLA compile. Covers the rerank executables too
-        when a cross-encoder is loaded."""
+        don't pay a cold XLA compile: of the pairs asked for, the `embed`
+        programs the packer can form (a row below the top length bucket
+        only ever comes alone, engine/bucketing.py `plan_packed`), and every
+        pair's rerank executable when a cross-encoder is loaded."""
+        top = max(b for b in self.config.length_buckets
+                  if b <= self.model_cfg.max_position_embeddings)
         for L in buckets or self.config.length_buckets[:2]:
             for B in batches or self.config.batch_buckets[:2]:
                 bb = self._batch_bucket(B)
-                np.asarray(self._warm_dispatch("embed", L, bb))
-                dispatch_ledger.note_host_sync("TpuEngine.warmup")
+                if L >= top or bb == self._batch_bucket(1):
+                    np.asarray(self._warm_dispatch("embed", L, bb))
+                    dispatch_ledger.note_host_sync("TpuEngine.warmup")
                 if self.cross_params is not None:
                     np.asarray(self._warm_dispatch("rerank", L, bb))
                     dispatch_ledger.note_host_sync("TpuEngine.warmup")

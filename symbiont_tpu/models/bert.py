@@ -31,7 +31,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,39 @@ import jax.numpy as jnp
 from symbiont_tpu.models import quant
 
 Params = Any  # nested dict pytree
+
+
+class Segments(NamedTuple):
+    """Where the sentences of packed rows lie (engine/bucketing.py lays a
+    row's sentences end to end). A forward handed one treats every sentence
+    as a row of its own: positions restart, attention stays inside the
+    sentence, pooling is per sentence. Handed None it traces the unpacked
+    code, one sentence a row."""
+    index: jax.Array  # [B, L] int32: the token's sentence in its row; S = padding
+    position: jax.Array  # [B, L] int32: the token's place within its sentence
+    lengths: jax.Array  # [B, S] int32: tokens of each sentence, 0 = none
+
+    @staticmethod
+    def of_lengths(lengths: jax.Array, L: int) -> "Segments":
+        """From `[B, S]` sentence lengths alone (what the engine ships)."""
+        lengths = lengths.astype(jnp.int32)
+        t = jnp.arange(L, dtype=jnp.int32)[None, :, None]
+        ended = jnp.cumsum(lengths, axis=1)[:, None, :] <= t  # [B, L, S]
+        index = ended.sum(-1, dtype=jnp.int32)
+        start = (ended * lengths[:, None, :]).sum(-1, dtype=jnp.int32)
+        return Segments(index, t[:, :, 0] - start, lengths)
+
+    @property
+    def real(self) -> jax.Array:
+        """[B, L] int32, 1 where a token belongs to a sentence."""
+        return (self.index < self.lengths.shape[1]).astype(jnp.int32)
+
+    @property
+    def same(self) -> jax.Array:
+        """[B, L, L] bool: query and key in one sentence (padding, which
+        nothing reads, keeps itself company: no row of the softmax is
+        empty)."""
+        return self.index[:, :, None] == self.index[:, None, :]
 
 
 @dataclass(frozen=True)
@@ -117,7 +150,7 @@ def _act(name: str, compute_dtype=None):
 def attention(
     params: Params,
     x: jax.Array,  # [B, S, H]
-    mask_bias: jax.Array,  # [B, 1, 1, S] additive bias (0 or -inf-ish)
+    mask_bias: jax.Array,  # [B, 1, 1 or S, S] additive bias (0 or -inf-ish)
     cfg: BertConfig,
 ) -> jax.Array:
     B, S, H = x.shape
@@ -179,14 +212,19 @@ def embeddings(
     attention_mask: jax.Array,  # [B, S] int32/bool
     cfg: BertConfig,
     token_type_ids: Optional[jax.Array] = None,
+    segments: Optional[Segments] = None,
 ) -> jax.Array:
     B, S = input_ids.shape
     tok = quant.take(params["word_embeddings"], input_ids)
     if cfg.position_offset:
         # RoBERTa-style: positions count only non-pad tokens, offset past pad id.
         mask = attention_mask.astype(jnp.int32)
-        positions = jnp.cumsum(mask, axis=1) * mask + cfg.position_offset - 1
+        count = (jnp.cumsum(mask, axis=1) if segments is None
+                 else segments.position + 1)  # restarts at every sentence
+        positions = count * mask + cfg.position_offset - 1
         positions = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
+    elif segments is not None:
+        positions = segments.position
     else:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     pos = quant.take(params["position_embeddings"], positions)
@@ -204,8 +242,12 @@ def bert_encode(
     attention_mask: jax.Array,
     cfg: BertConfig,
     token_type_ids: Optional[jax.Array] = None,
+    segments: Optional[Segments] = None,
 ) -> jax.Array:
     """Full encoder forward → last hidden state [B, S, H] in cfg.dtype."""
+    if segments is not None and cfg.attn_impl == "flash":
+        raise ValueError("the flash kernel takes a per-key bias: packed rows "
+                         "need attn_impl='xla'")
     dtype = jnp.dtype(cfg.dtype)
     # shared leaf-aware cast: floating params → compute dtype, QuantTensor
     # leaves untouched (their f32 scales must not be downcast). Cast per
@@ -213,11 +255,15 @@ def bert_encode(
     # table's cast to `embeddings` and the layers' to `encoder`.
     with jax.named_scope("embeddings"):
         x = embeddings(quant.cast_params(params["embeddings"], dtype),
-                       input_ids, attention_mask, cfg, token_type_ids)
+                       input_ids, attention_mask, cfg, token_type_ids,
+                       segments)
         x = x.astype(dtype)
     with jax.named_scope("encoder"):
         # additive mask bias: 0 for real tokens, large negative for padding
-        mask_bias = (1.0 - attention_mask[:, None, None, :].astype(jnp.float32)) * -1e9
+        # (packed rows: 0 inside a sentence, block-diagonal by sentence)
+        attended = (attention_mask[:, None, None, :] if segments is None
+                    else segments.same[:, None])
+        mask_bias = (1.0 - attended.astype(jnp.float32)) * -1e9
         for layer_params in quant.cast_params(params["layers"], dtype):
             x = encoder_layer(layer_params, x, mask_bias, cfg)
     return x
@@ -245,6 +291,29 @@ def cls_pool(hidden: jax.Array, attention_mask: jax.Array) -> jax.Array:
 POOLERS = {"mean": mean_pool, "cls": cls_pool}
 
 
+def pool_segments(hidden: jax.Array, segments: Segments,
+                  pooling: str) -> jax.Array:
+    """POOLERS per sentence of packed rows: [B, L, H] → [B, S, H] float32
+    (the mean over a sentence's tokens, or its first token); a slot that
+    holds no sentence comes out zero. One weighted sum per slot at full
+    float32 precision: each weight is 0 or 1, so it is the same sum."""
+    S = segments.lengths.shape[1]
+    L = hidden.shape[1]
+    if pooling == "mean":
+        pick = segments.index[:, None, :] == jnp.arange(S)[None, :, None]
+        count = jnp.maximum(segments.lengths, 1)[..., None]
+    else:
+        assert pooling == "cls", pooling
+        first = jnp.cumsum(segments.lengths, axis=1) - segments.lengths
+        pick = ((jnp.arange(L)[None, None, :] == first[..., None])
+                & (segments.lengths[..., None] > 0))
+        count = 1
+    summed = jnp.einsum("bsl,blh->bsh", pick.astype(jnp.float32),
+                        hidden.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    return summed / count
+
+
 def embed_sentences(
     params: Params,
     input_ids: jax.Array,
@@ -252,16 +321,20 @@ def embed_sentences(
     cfg: BertConfig,
     pooling: str = "mean",
     normalize: bool = False,
+    segments: Optional[Segments] = None,
 ) -> jax.Array:
-    """Encoder forward + pooling → [B, H] float32 sentence embeddings.
+    """Encoder forward + pooling → [B, H] float32 sentence embeddings
+    ([B, S, H] for packed rows: `segments`, and `attention_mask` its `real`).
 
     The reference does not L2-normalize (cosine distance is computed by Qdrant,
     reference: services/vector_memory_service/src/main.rs:36), so normalize
     defaults to False; e5/bge recipes can turn it on.
     """
-    hidden = bert_encode(params, input_ids, attention_mask, cfg)
+    hidden = bert_encode(params, input_ids, attention_mask, cfg,
+                         segments=segments)
     with jax.named_scope("pool"):
-        pooled = POOLERS[pooling](hidden, attention_mask)
+        pooled = (POOLERS[pooling](hidden, attention_mask) if segments is None
+                  else pool_segments(hidden, segments, pooling))
         if normalize:
             pooled = pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)
         return pooled

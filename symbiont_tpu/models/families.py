@@ -1,10 +1,13 @@
 """The embedder's model family: the one seam between `TpuEngine` and a
 forward. A checkpoint names its family (`config.json` `model_type`); each
 family gives its config class, its loader (HF names -> params pytree), a
-random `init_params` and `embed(params, ids, mask, cfg, pooling, normalize)
--> (rows [B, H] float32, aux)` where `aux` is None or what the family's
-forward counts on the device (mla_moe: real tokens per expert layer and
-expert). Everything else — tokenizer, bucketing, batcher, the `embed` /
+random `init_params` and `embed(params, ids, mask, cfg, pooling, normalize,
+segments=None) -> (rows [B, H] float32, aux)` where `aux` is None or what
+the family's forward counts on the device (mla_moe: real tokens per expert
+layer and expert). With `segments` (models/bert.py `Segments`: the batched
+`embed` program's packed rows) a row holds several sentences and the rows
+come back [B, S, H]; without, the forward is the unpacked one the fused
+query runs. Everything else — tokenizer, bucketing, batcher, the `embed` /
 `qsearch` executables and their cache, pooling, the store — is shared.
 """
 
@@ -18,9 +21,9 @@ from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.models.mla_moe import MlaMoeConfig
 
 
-def _bert_embed(params, ids, mask, cfg, pooling, normalize):
+def _bert_embed(params, ids, mask, cfg, pooling, normalize, segments=None):
     return bert.embed_sentences(params, ids, mask, cfg, pooling=pooling,
-                                normalize=normalize), None
+                                normalize=normalize, segments=segments), None
 
 
 def _load_bert(model_dir):

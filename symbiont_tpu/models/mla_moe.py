@@ -39,13 +39,13 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from symbiont_tpu.models import quant
-from symbiont_tpu.models.bert import POOLERS
+from symbiont_tpu.models.bert import POOLERS, Segments, pool_segments
 from symbiont_tpu.models.layers import rmsnorm, rope, swiglu
 
 Params = Any
@@ -129,13 +129,17 @@ def _deinterleave(x: jax.Array) -> jax.Array:
 
 
 def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
-                  cfg: MlaMoeConfig) -> jax.Array:
+                  cfg: MlaMoeConfig,
+                  segments: Optional[Segments] = None) -> jax.Array:
     """x [B, S, H] (normed), mask [B, S] (1 = attended; right-padded, so an
-    attended token's position is its index) -> [B, S, H]."""
+    attended token's position is its index) -> [B, S, H]. Packed rows
+    (`segments`): a token's position is its place in its sentence, and it
+    attends causally inside that sentence."""
     B, S, _ = x.shape
     nh, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
                       cfg.qk_rope_head_dim, cfg.v_head_dim)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    positions = (jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+                 if segments is None else segments.position)
     q = quant.mm(x, p["q"]["kernel"]).reshape(B, S, nh, dn + dr)
     kva = quant.mm(x, p["kv_a"]["kernel"])
     c, k_rope = kva[..., :cfg.kv_lora_rank], kva[..., cfg.kv_lora_rank:]
@@ -147,7 +151,8 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
     scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], kv[..., :dn])
               + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope))
     keep = (jnp.tril(jnp.ones((S, S), bool))[None, None]
-            & (mask[:, None, None, :] > 0))
+            & ((mask[:, None, None, :] > 0) if segments is None
+               else segments.same[:, None]))
     scores = jnp.where(keep, scores.astype(jnp.float32)
                        / math.sqrt(dn + dr), -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
@@ -231,7 +236,7 @@ def moe_ffn(p: Params, h: jax.Array, mask: jax.Array, ln: Params,
 
 
 def encode(params: Params, input_ids: jax.Array, attention_mask: jax.Array,
-           cfg: MlaMoeConfig):
+           cfg: MlaMoeConfig, segments: Optional[Segments] = None):
     """-> (last hidden state after the final norm [B, S, H] in cfg.dtype,
     counts [expert layers, E] int32)."""
     dtype = jnp.dtype(cfg.dtype)
@@ -243,13 +248,14 @@ def encode(params: Params, input_ids: jax.Array, attention_mask: jax.Array,
     # it per layer (the compiler inlines the calls: the program is the
     # same). A warmed bucket is traced and lowered at every boot, inside
     # `setup_s`, and the expert layers are most of that.
-    attention = jax.jit(lambda p, ln, x, mask: mla_attention(
-        p, rmsnorm(x, ln, cfg.rms_norm_eps), mask, cfg))
+    attention = jax.jit(lambda p, ln, x, mask, segments: mla_attention(
+        p, rmsnorm(x, ln, cfg.rms_norm_eps), mask, cfg, segments))
     experts = jax.jit(lambda p, x, mask, ln: moe_ffn(p, x, mask, ln, cfg))
     counts = []
     for layer in quant.cast_params(params["layers"], dtype):
         with jax.named_scope("mla"):
-            x = x + attention(layer["attn"], layer["ln1"], x, attention_mask)
+            x = x + attention(layer["attn"], layer["ln1"], x, attention_mask,
+                              segments)
         if "moe" in layer:
             y, c = experts(layer["moe"], x, attention_mask, layer["ln2"])
             counts.append(c)
@@ -266,12 +272,15 @@ def encode(params: Params, input_ids: jax.Array, attention_mask: jax.Array,
 
 def embed_sentences(params: Params, input_ids: jax.Array,
                     attention_mask: jax.Array, cfg: MlaMoeConfig,
-                    pooling: str = "mean", normalize: bool = False):
-    """Decoder stack + pooling -> ([B, H] float32 sentence embeddings,
+                    pooling: str = "mean", normalize: bool = False,
+                    segments: Optional[Segments] = None):
+    """Decoder stack + pooling -> ([B, H] float32 sentence embeddings, or
+    [B, S, H] for packed rows: `segments`, and `attention_mask` its `real`;
     counts [expert layers, E] int32 of real tokens per expert)."""
-    hidden, counts = encode(params, input_ids, attention_mask, cfg)
+    hidden, counts = encode(params, input_ids, attention_mask, cfg, segments)
     with jax.named_scope("pool"):
-        pooled = POOLERS[pooling](hidden, attention_mask)
+        pooled = (POOLERS[pooling](hidden, attention_mask) if segments is None
+                  else pool_segments(hidden, segments, pooling))
         if normalize:
             pooled = pooled / jnp.maximum(
                 jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12)

@@ -108,7 +108,7 @@ def test_timeline_disabled_records_nothing():
     tl = _tl(capacity=0)
     tl.note_decode_step(wall_ms=1.0, rows_live=1, rows_capacity=1,
                         kv_rows_live=1, kv_rows_allocated=1, steps=1)
-    tl.note_embed_flush(64, 8, 8, real_tokens=10, total_tokens=512)
+    tl.note_embed_flush(128, 8, 8, real_tokens=10, total_tokens=1024)
     assert tl.prompt_prefix_share([[1, 2, 3]]) == 0.0
     assert len(tl) == 0
 
@@ -136,7 +136,7 @@ def test_prefix_probe_registry_is_bounded():
 
 def test_packing_opportunity_gauge_from_flush_window():
     tl = _tl()
-    tl.note_embed_flush(64, 8, 4, real_tokens=128, total_tokens=512)
+    tl.note_embed_flush(128, 8, 4, real_tokens=256, total_tokens=1024)
     g = tl.registry.snapshot()["gauges"]
     assert g['engine.packing_opportunity_pct{service="engine"}'] == \
         pytest.approx(75.0)
@@ -164,8 +164,8 @@ def _golden_inputs():
          "rows_capacity": 8, "kv_rows_live": 4, "kv_rows_allocated": 8,
          "steps": 8, "sessions": 1},
         {"kind": "queue", "t": 100.012, "queue": "generate", "depth": 3},
-        {"kind": "flush", "t": 100.015, "bucket": 64, "batch_rows": 8,
-         "n_real": 5, "real_tokens": 100, "total_tokens": 512},
+        {"kind": "flush", "t": 100.015, "bucket": 128, "batch_rows": 8,
+         "n_real": 5, "real_tokens": 100, "total_tokens": 1024},
         {"kind": "step", "t": 100.020, "wall_ms": 4.0, "rows_live": 6,
          "rows_capacity": 8, "kv_rows_live": 6, "kv_rows_allocated": 8,
          "steps": 8, "sessions": 1},
@@ -194,7 +194,7 @@ def test_export_timeline_counters_and_span_lanes():
     assert by_name["decode.kv_rows"]["args"] in (
         {"live": 4, "stranded": 4}, {"live": 6, "stranded": 2})
     assert by_name["embed.flush_tokens"]["args"] == {"real": 100,
-                                                     "padding": 412}
+                                                     "padding": 924}
     # counter/instant events are chronologically sorted in document order
     # and share the span time axis (µs)
     cts = [e["ts"] for e in doc["traceEvents"] if e["ph"] in ("C", "i")]

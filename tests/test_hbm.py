@@ -260,7 +260,7 @@ def test_peak_temp_bytes_prefix_filter():
                                  memory={"temp_bytes": 4096})
     dispatch_ledger.note_compile("lm.prefill[P=32]", None,
                                  memory={"temp_bytes": 1 << 20})
-    dispatch_ledger.note_compile("embed[L=64]", None,
+    dispatch_ledger.note_compile("embed[L=128,B=32]", None,
                                  memory={"temp_bytes": 1 << 30})
     assert hbm.peak_temp_bytes("lm.") == 1 << 20
     assert hbm.peak_temp_bytes() == 1 << 30

@@ -492,11 +492,11 @@ def test_compile_events_land_on_the_timeline():
 
     trace_store.clear()
     record_compile_event("engine.compile", 1.5, start_s=1000.0,
-                         signature="embed[L=64,B=32]")
+                         signature="embed[L=128,B=32]")
     (rec,) = trace_store.spans_for(COMPILE_TRACE_ID)
     assert rec.name == "engine.compile"
     assert rec.duration_ms == pytest.approx(1500.0)
-    assert rec.fields["signature"] == "embed[L=64,B=32]"
+    assert rec.fields["signature"] == "embed[L=128,B=32]"
     # and the timeline exports like any other trace
     doc = chrome_trace.export_spans(
         COMPILE_TRACE_ID, trace_store.spans_for(COMPILE_TRACE_ID))

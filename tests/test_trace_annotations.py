@@ -452,8 +452,9 @@ def test_the_programs_hold_their_scopes_and_are_still_called_fn(
         monkeypatch, engine, kind):
     ids = np.ones((2, 8), engine._ids_dtype)
     if kind == "embed":
+        # packed rows: [B, S] sentence lengths, S = segments_per_row(8) = 1
         text = _lowered(monkeypatch, engine, "embed", 8, (2,),
-                        (engine.params, ids, np.full((2,), 8, np.int32)))
+                        (engine.params, ids, np.full((2, 1), 8, np.int32)))
         phases = ("embeddings", "encoder", "pool")
     else:
         text = _lowered(monkeypatch, engine, "qsearch", 8, (64, 8, None),

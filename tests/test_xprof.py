@@ -52,27 +52,27 @@ def _ledger(**kw) -> DispatchLedger:
 
 def test_ledger_counts_dispatches_and_recompiles():
     led = _ledger()
-    led.note_compile("embed[L=64,B=8]", {"flops": 1e9,
+    led.note_compile("embed[L=128,B=8]", {"flops": 1e9,
                                          "bytes_accessed": 1e8})
-    led.note_dispatch("embed[L=64,B=8]", 0.010)
-    led.note_dispatch("embed[L=64,B=8]", 0.020)
+    led.note_dispatch("embed[L=128,B=8]", 0.010)
+    led.note_dispatch("embed[L=128,B=8]", 0.020)
     # a cache eviction recompiles the SAME signature: compiles accumulate
-    led.note_compile("embed[L=64,B=8]", {"flops": 1e9,
+    led.note_compile("embed[L=128,B=8]", {"flops": 1e9,
                                          "bytes_accessed": 1e8})
-    led.note_dispatch("embed[L=128,B=8]", 0.005)
+    led.note_dispatch("embed[L=128,B=32]", 0.005)
     rows = {r["executable"]: r for r in led.snapshot()}
-    r = rows["embed[L=64,B=8]"]
+    r = rows["embed[L=128,B=8]"]
     assert r["dispatches"] == 2 and r["compiles"] == 2
     assert r["host_wall_ms"] == pytest.approx(30.0)
     assert r["mean_dispatch_us"] == pytest.approx(15000.0)
     assert r["flops"] == 1e9 and r["bytes_accessed"] == 1e8
-    assert rows["embed[L=128,B=8]"]["dispatches"] == 1
+    assert rows["embed[L=128,B=32]"]["dispatches"] == 1
     # snapshot orders by dispatch count (hottest executable first)
-    assert led.snapshot()[0]["executable"] == "embed[L=64,B=8]"
+    assert led.snapshot()[0]["executable"] == "embed[L=128,B=8]"
     # the counter family carries the per-executable label
     assert led.registry.get(
         "xla.dispatches_total",
-        labels={"executable": "embed[L=64,B=8]"}) == 2
+        labels={"executable": "embed[L=128,B=8]"}) == 2
 
 
 def test_ledger_lru_bound_and_configure():
@@ -432,9 +432,9 @@ def test_executables_and_profile_endpoints(tmp_path):
 
     dispatch_ledger.clear()
     dispatch_ledger.configure(enabled=True)
-    dispatch_ledger.note_compile("embed[L=64,B=8]",
+    dispatch_ledger.note_compile("embed[L=128,B=8]",
                                  {"flops": 1e9, "bytes_accessed": 1e8})
-    dispatch_ledger.note_dispatch("embed[L=64,B=8]", 0.010)
+    dispatch_ledger.note_dispatch("embed[L=128,B=8]", 0.010)
     cfg = SymbiontConfig(
         vector_store=VectorStoreConfig(dim=16, data_dir=str(tmp_path / "vs"),
                                        shard_capacity=64),
@@ -475,8 +475,8 @@ def test_executables_and_profile_endpoints(tmp_path):
             body = await loop.run_in_executor(
                 None, lambda: get("/api/engine/executables"))
             rows = {r["executable"]: r for r in body["executables"]}
-            assert "embed[L=64,B=8]" in rows
-            r = rows["embed[L=64,B=8]"]
+            assert "embed[L=128,B=8]" in rows
+            r = rows["embed[L=128,B=8]"]
             assert r["dispatches"] >= 1 and r["compiles"] == 1
             # the roofline grade rides each row (cost model present here)
             assert r["achieved_gbps"] is not None
