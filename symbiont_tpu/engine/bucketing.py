@@ -147,13 +147,21 @@ def plan_batches(
 # ---------------------------------------------------------------- packing
 
 
+LONG_ROW_SEGMENTS = 64
+
+
 def segments_per_row(bucket: int) -> int:
     """S: the most sentences one packed row of `bucket` tokens may hold. A
     row's sentence lengths ship as `[S]` int32 (half the bytes of a
     per-token segment index) and its pooled rows come back `[S, H]`, so S
     is kept to what rows of short sentences need: a sentence of under 8
-    tokens is rare enough that the cap binds on few rows."""
-    return max(1, bucket // 8)
+    tokens is rare enough that the cap binds on few rows. That holds up to
+    rows of 512 tokens (64 slots); a longer bucket exists for longer
+    passages, not for more of them, and keeps the 64: S no longer grows
+    with the bucket, so a 32,768-token row ships 256 bytes of lengths,
+    brings back `[64, H]` and the device's `[B, L, S]` index of
+    `Segments.of_lengths` stays linear in L."""
+    return max(1, min(bucket // 8, LONG_ROW_SEGMENTS))
 
 
 def plan_packed(

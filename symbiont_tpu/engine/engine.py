@@ -352,7 +352,7 @@ class TpuEngine:
                 segments = bert_mod.Segments.of_lengths(seg_lengths,
                                                         ids.shape[1])
                 # aux: None, or what the family's forward counted on the
-                # device (expert load), fetched with the rows
+                # device (`Family.note_aux` books it), fetched with the rows
                 emb, aux = embed(params, ids, segments.real, cfg, pooling,
                                  normalize, segments)
                 return (emb.astype(jnp.bfloat16) if d2h_bf16 else emb), aux
@@ -598,20 +598,6 @@ class TpuEngine:
             pending.append(([offset + i for i in sent], at,
                             *fn(self.params, ids_d, seg_d)))
 
-    @staticmethod
-    def _note_moe(counts: np.ndarray) -> None:
-        """Expert-load series of one embed dispatch: `counts` [expert layers,
-        E] = real tokens each expert took (docs/OBSERVABILITY.md)."""
-        labels = {"service": "engine"}
-        metrics.inc("engine.moe.assignments", int(counts.sum()), labels=labels)
-        metrics.inc("engine.moe.experts_idle", int((counts == 0).sum()),
-                    labels=labels)
-        for layer in counts:
-            if layer.sum() > 0:
-                metrics.observe("engine.moe.expert_load_max_over_mean",
-                                float(layer.max() / layer.mean()),
-                                labels=labels)
-
     def embed_texts(self, texts: Sequence[str]) -> np.ndarray:
         """Texts → [n, hidden] float32 embeddings. Parity surface of the
         reference's generate_sentence_embeddings (embedding_generator.rs:134).
@@ -679,7 +665,7 @@ class TpuEngine:
                         out[sent] = allv[off:off + res_dev.shape[0]][at]
                         off += res_dev.shape[0]
                         if aux is not None:  # computed with the rows above
-                            self._note_moe(np.asarray(aux))
+                            self.family.note_aux(np.asarray(aux))
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
                                                len(fetches))
             else:
@@ -687,7 +673,7 @@ class TpuEngine:
                 for sent, at, res_dev, aux in pending:
                     out[sent] = np.asarray(res_dev)[at]
                     if aux is not None:
-                        self._note_moe(np.asarray(aux))
+                        self.family.note_aux(np.asarray(aux))
                 dispatch_ledger.note_host_sync("TpuEngine.embed_texts",
                                                len(pending))
             self._note_stages("engine.embed", t0, t_dispatched)

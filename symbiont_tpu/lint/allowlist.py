@@ -92,6 +92,11 @@ JAX_JIT_IN_FUNCTION_ALLOWED = {
         "two inner jits live for that one trace so that the layers of a "
         "program (one shape each) are traced and lowered once and called "
         "per layer; the compiler inlines the calls",
+    ("symbiont_tpu/models/sala.py", "encode"):
+        "no executable, as models/mla_moe.py `encode`: the inner jits live "
+        "for the one trace of the engine's own jit, so that each kind of "
+        "layer (sparse mixer, linear mixer, feed-forward: one shape each) is "
+        "traced and lowered once and called per layer",
 }
 
 # deliberate device→host sync points on the dispatch hot path: one bulk

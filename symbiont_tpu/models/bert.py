@@ -38,6 +38,12 @@ import jax.numpy as jnp
 
 from symbiont_tpu.models import quant
 
+# `config.json` `model_type`s this loader reads (models/families.py: a type
+# no family claims is refused). The second group starts position ids past
+# the padding index (`BertConfig.from_hf`).
+_OFFSET_TYPES = ("roberta", "xlm-roberta", "mpnet")
+MODEL_TYPES = ("bert", "electra") + _OFFSET_TYPES
+
 Params = Any  # nested dict pytree
 
 
@@ -100,7 +106,7 @@ class BertConfig:
         """Map an HF config.json dict (BertConfig/XLMRobertaConfig) to ours."""
         model_type = cfg.get("model_type", "bert")
         offset = 0
-        if model_type in ("xlm-roberta", "roberta", "mpnet"):
+        if model_type in _OFFSET_TYPES:
             offset = cfg.get("pad_token_id", 1) + 1
         return BertConfig(
             vocab_size=cfg["vocab_size"],

@@ -299,6 +299,48 @@ def convert_mla_moe(state_dict: Dict[str, Any], cfg) -> Params:
     return params
 
 
+def convert_sala(state_dict: Dict[str, Any], cfg) -> Params:
+    """Map a `minicpm_sala` state_dict to the sala.py pytree: the HF MiniCPM
+    layout, with each mixer's gate and norms as `self_attn.o_gate`,
+    `q_norm`, `k_norm` and (linear layers) `o_norm` (assumed names: no
+    published checkpoint is in the repository to read them from; the seeded
+    one, benchmark/refs/minicpm_sala.py, uses them). Torch Linear [out, in]
+    -> [in, out], leaf by leaf in the checkpoint's own dtype as
+    `convert_mla_moe` does; rank-1 leaves go to float32. `lm_head` is not
+    read: the encoder role pools hidden states."""
+    from symbiont_tpu.models.sala import LINEAR
+
+    sd = {k.removeprefix("model."): v for k, v in state_dict.items()}
+
+    def take(name: str) -> np.ndarray:
+        if name not in sd:
+            raise KeyError(f"checkpoint missing tensor {name!r}; have e.g. "
+                           f"{sorted(sd)[:5]}")
+        return _to_numpy(sd.pop(name))
+
+    def kernel(name: str) -> dict:
+        return {"kernel": _transposed([take(f"{name}.weight")])[0]}
+
+    def ln(name: str) -> dict:
+        return {"scale": take(f"{name}.weight").astype(np.float32)}
+
+    params: Params = {"wte": take("embed_tokens.weight"),
+                      "ln_f": ln("norm"), "layers": []}
+    for i, kind in enumerate(cfg.mixer_types):
+        p, a = f"layers.{i}", f"layers.{i}.self_attn"
+        mixer = {**{k: kernel(f"{a}.{k}_proj") for k in "qkvo"},
+                 "gate": kernel(f"{a}.o_gate"),
+                 "q_norm": ln(f"{a}.q_norm"), "k_norm": ln(f"{a}.k_norm")}
+        if kind == LINEAR:
+            mixer["o_norm"] = ln(f"{a}.o_norm")
+        params["layers"].append({
+            "ln1": ln(f"{p}.input_layernorm"),
+            "ln2": ln(f"{p}.post_attention_layernorm"), "mixer": mixer,
+            "mlp": {k: kernel(f"{p}.mlp.{k}_proj")
+                    for k in ("gate", "up", "down")}})
+    return params
+
+
 def export_hf_bert(params: Params, cfg: BertConfig, out_dir: str | Path,
                    tokenizer_file: str | Path | None = None) -> Path:
     """Inverse of convert_bert: write a hub-format model dir
@@ -398,6 +440,14 @@ def load_mla_moe_model(model_dir: str | Path):
 
     cfg = MlaMoeConfig.from_hf(load_hf_config(model_dir))
     return convert_mla_moe(load_state_dict(model_dir), cfg), cfg
+
+
+def load_sala_model(model_dir: str | Path):
+    """One-call load: (params, SalaConfig) from a local HF model dir."""
+    from symbiont_tpu.models.sala import SalaConfig
+
+    cfg = SalaConfig.from_hf(load_hf_config(model_dir))
+    return convert_sala(load_state_dict(model_dir), cfg), cfg
 
 
 def load_bert_model(model_dir: str | Path, with_pooler: bool = False):

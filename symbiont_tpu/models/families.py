@@ -4,7 +4,9 @@ family gives its config class, its loader (HF names -> params pytree), a
 random `init_params` and `embed(params, ids, mask, cfg, pooling, normalize,
 segments=None) -> (rows [B, H] float32, aux)` where `aux` is None or what
 the family's forward counts on the device (mla_moe: real tokens per expert
-layer and expert). With `segments` (models/bert.py `Segments`: the batched
+layer and expert; sala: keys attended, causal keys and dense-path tokens per
+row and sparse layer), and `note_aux(aux)`, which books a fetched `aux`
+under the family's own series (None where the forward counts nothing). With `segments` (models/bert.py `Segments`: the batched
 `embed` program's packed rows) a row holds several sentences and the rows
 come back [B, S, H]; without, the forward is the unpacked one the fused
 query runs. Everything else — tokenizer, bucketing, batcher, the `embed` /
@@ -14,11 +16,15 @@ query runs. Everything else — tokenizer, bucketing, batcher, the `embed` /
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable
+from typing import Callable, Optional
 
-from symbiont_tpu.models import bert, mla_moe
+import numpy as np
+
+from symbiont_tpu.models import bert, mla_moe, sala
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.models.mla_moe import MlaMoeConfig
+from symbiont_tpu.models.sala import SalaConfig
+from symbiont_tpu.utils.telemetry import metrics
 
 
 def _bert_embed(params, ids, mask, cfg, pooling, normalize, segments=None):
@@ -38,30 +44,82 @@ def _load_mla_moe(model_dir):
     return load_mla_moe_model(model_dir)
 
 
+def _load_sala(model_dir):
+    from symbiont_tpu.models.convert import load_sala_model
+
+    return load_sala_model(model_dir)
+
+
+_LABELS = {"service": "engine"}
+
+
+def _note_moe(counts) -> None:
+    """Expert-load series of one embed dispatch: `counts` [expert layers,
+    E] = real tokens each expert took (docs/OBSERVABILITY.md)."""
+    metrics.inc("engine.moe.assignments", int(counts.sum()), labels=_LABELS)
+    metrics.inc("engine.moe.experts_idle", int((counts == 0).sum()),
+                labels=_LABELS)
+    for layer in counts:
+        if layer.sum() > 0:
+            metrics.observe("engine.moe.expert_load_max_over_mean",
+                            float(layer.max() / layer.mean()),
+                            labels=_LABELS)
+
+
+def _note_sparse(counts) -> None:
+    """Block-selection series of one embed dispatch: `counts` [rows, sparse
+    layers, 3] = keys attended, keys a causal attention reads, tokens on the
+    dense path (docs/OBSERVABILITY.md)."""
+    per_layer = np.asarray(counts, np.int64).sum(0)
+    attended, causal, dense = (int(v) for v in per_layer.sum(0))
+    metrics.inc("engine.sparse.keys_attended", attended, labels=_LABELS)
+    metrics.inc("engine.sparse.keys_causal", causal, labels=_LABELS)
+    metrics.inc("engine.sparse.dense_path_tokens", dense, labels=_LABELS)
+    for kept, of, _ in per_layer:
+        if of > 0:
+            metrics.observe("engine.sparse.kept_share", float(kept / of),
+                            labels=_LABELS)
+
+
 @dataclass(frozen=True)
 class Family:
     name: str
+    model_types: tuple  # `config.json` `model_type`s that name it
     config_cls: type
     load: Callable  # model_dir -> (params, cfg)
     init_params: Callable  # (key, cfg) -> params
     embed: Callable
+    note_aux: Optional[Callable] = None  # fetched aux -> the family's series
 
 
-BERT = Family("bert", BertConfig, _load_bert, bert.init_params, _bert_embed)
-MLA_MOE = Family("mla_moe", MlaMoeConfig, _load_mla_moe, mla_moe.init_params,
-                 mla_moe.embed_sentences)
+BERT = Family("bert", bert.MODEL_TYPES, BertConfig, _load_bert,
+              bert.init_params, _bert_embed)
+MLA_MOE = Family("mla_moe", mla_moe.MODEL_TYPES, MlaMoeConfig, _load_mla_moe,
+                 mla_moe.init_params, mla_moe.embed_sentences, _note_moe)
+SALA = Family("sala", sala.MODEL_TYPES, SalaConfig, _load_sala,
+              sala.init_params, sala.embed_sentences, _note_sparse)
+FAMILIES = (BERT, MLA_MOE, SALA)
+_BY_TYPE = {t: f for f in FAMILIES for t in f.model_types}
+_BY_CONFIG = {f.config_cls: f for f in FAMILIES}
 
 
 def family_of_checkpoint(model_dir) -> Family:
-    """By the checkpoint's own `model_type`; anything that is not a known
-    other family loads as BERT, as every checkpoint did before the seam."""
+    """By the checkpoint's own `model_type` (a vision-language checkpoint
+    nests its text tower's under `text_config`; a config that names none
+    reads as `bert`, as `BertConfig.from_hf` reads it). A type no family
+    claims is refused here, before a loader meets tensors it cannot name."""
     from symbiont_tpu.models.convert import load_hf_config
 
     hf = load_hf_config(model_dir)
-    types = {hf.get("model_type"),
-             (hf.get("text_config") or {}).get("model_type")}
-    return MLA_MOE if types & set(mla_moe.MODEL_TYPES) else BERT
+    types = (hf.get("model_type", "bert"),
+             (hf.get("text_config") or {}).get("model_type"))
+    for model_type in types:
+        if model_type in _BY_TYPE:
+            return _BY_TYPE[model_type]
+    raise ValueError(
+        f"{model_dir}: no embedder family claims model_type {types[0]!r} "
+        f"(claimed: {', '.join(sorted(_BY_TYPE))})")
 
 
 def family_of_config(model_cfg) -> Family:
-    return MLA_MOE if isinstance(model_cfg, MLA_MOE.config_cls) else BERT
+    return _BY_CONFIG.get(type(model_cfg), BERT)

@@ -232,7 +232,10 @@ def test_zero_loss_under_handler_hang_past_timeout(tmp_path):
                                        "data.text.with_embeddings"}) >= 2
             vm = next(s for s in stack.services
                       if s.name == "vector_memory")
-            assert vm._sem._value == 32  # no slot pinned by a hung handler
+            # no slot pinned by a hung handler (the last handlers free
+            # theirs a moment after their rows are counted: wait, a pinned
+            # slot never comes back)
+            assert await _wait_for(lambda: vm._sem._value == 32, timeout=5.0)
         finally:
             await stack.stop()
             await bus.close()

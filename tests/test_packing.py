@@ -108,6 +108,76 @@ def test_planner_fills_the_cells_page_into_thirty_two_rows():
     assert L == 128 and [len(d) for d in dispatches] == [32]
 
 
+@pytest.mark.parametrize("bucket,slots", [
+    (8, 1), (32, 4), (64, 8), (128, 16), (256, 32), (512, 64), (1024, 64),
+    (8192, 64), (32768, 64)])
+def test_segments_per_row_is_an_eighth_of_short_rows_and_capped_on_long(
+        bucket, slots):
+    """The short buckets' values are what they were (128 -> 16 slots); from
+    512 tokens on a row keeps 64: a long bucket is for longer passages."""
+    assert segments_per_row(bucket) == slots
+
+
+def _longdocs_lengths():
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent
+                           / "benchmark"))
+    import traffic
+    from refs.xlmr import token_count
+    from kinds import ingest
+
+    return [token_count(s, 32768) for s in ingest.page_sentences(
+        traffic.load_mix("ingest_longdocs"), 0, 0)]
+
+
+def test_planner_packs_a_page_of_long_passages_into_four_full_length_rows():
+    """benchmark/traffic/ingest_longdocs.json: six passages of 8.4 k-32 k
+    tokens, none splits, one or two to a 32,768-token row."""
+    lengths = _longdocs_lengths()
+    assert len(lengths) == 6
+    assert all(8192 < n <= 32768 for n in lengths)
+    L, dispatches = plan_packed(lengths, [32768], 1)
+    rows = [row for d in dispatches for row in d]
+    assert L == 32768 and [len(d) for d in dispatches] == [1] * len(rows)
+    assert sorted(i for row in rows for i in row) == list(range(6))
+    assert all(sum(lengths[i] for i in row) <= L for row in rows)
+    assert len(rows) == 4 and sorted(len(r) for r in rows) == [1, 1, 2, 2]
+
+
+def test_a_long_row_ships_a_few_bytes_and_builds_nothing_cubic():
+    """What a 32,768-token row ships and what the device builds from it:
+    [B, 64] lengths (256 B a row, where L // 8 slots were 16 KB and the
+    pooled rows 32 MB), a [B, L] index and position, and a [B, L, 64]
+    comparison (2 MB) where [B, L, L // 8] was 134 M entries."""
+    L = 32768
+    lengths = _longdocs_lengths()
+    seqs = [[1] * n for n in lengths]
+    _, dispatches = plan_packed(lengths, [L], 1)
+    two = next(d[0] for d in dispatches if len(d[0]) == 2)
+    ids, seg = pack_rows(seqs, [two], L, 1, pad_id=0)
+    assert ids.shape == (1, L) and seg.shape == (1, 64)
+    assert seg.nbytes == 256
+    a, b = (lengths[i] for i in two)
+    shapes = jax.eval_shape(lambda s: bert.Segments.of_lengths(s, L),
+                            jnp.asarray(seg))
+    assert shapes.index.shape == shapes.position.shape == (1, L)
+    s = bert.Segments.of_lengths(jnp.asarray(seg), L)
+    index, position = np.asarray(s.index[0]), np.asarray(s.position[0])
+    assert (index[:a] == 0).all() and (index[a:a + b] == 1).all()
+    assert (index[a + b:] == 64).all()
+    np.testing.assert_array_equal(position[:a], np.arange(a))
+    np.testing.assert_array_equal(position[a:a + b], np.arange(b))
+    assert int(np.asarray(s.real).sum()) == a + b
+    # the largest intermediate of of_lengths is [B, L, S] with S = 64
+    jaxpr = jax.make_jaxpr(lambda x: bert.Segments.of_lengths(x, L))(
+        jnp.asarray(seg))
+    biggest = max(int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
+                  for v in eqn.outvars)
+    assert biggest == L * 64
+
+
 def test_pack_rows_lays_sentences_end_to_end():
     seqs = [[1, 5, 2], [1, 6, 7, 2], [1, 2]]
     ids, seg = pack_rows(seqs, [[1, 2], [0]], 16, 4, pad_id=0)

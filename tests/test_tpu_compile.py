@@ -79,3 +79,39 @@ def test_routed_experts_compile_to_grouped_matmuls_at_published_widths(
     own = 2.0 * 3 * H * I * T * k
     flops = compiled.cost_analysis()["flops"]
     assert own <= flops < 1.2 * own, (flops, own)
+
+
+@pytest.mark.parametrize("kind", ["minicpm4", "lightning-attn"])
+def test_long_row_mixers_compile_within_the_chip_at_published_widths(
+        one_chip, kind):
+    """MiniCPM-SALA's two mixers over ONE packed 32,768-token row at
+    published widths (32 heads of 128, 2 kv heads, 64 slots a row): the
+    chip's compiler takes the blocked sparse attention (scan over query
+    blocks, a traced-bound loop over key chunks) and the chunked linear
+    scan, and neither holds anything [L, L]: float32 scores of 32 heads at
+    this length would be 137 GB, the whole temp here is under 2 GB."""
+    from symbiont_tpu.engine.bucketing import segments_per_row
+    from symbiont_tpu.models import sala
+    from symbiont_tpu.models.bert import Segments
+
+    L = 32768
+    cfg = sala.SalaConfig(num_layers=1, mixer_types=(kind,))
+    layer = jax.eval_shape(lambda: sala.init_params(
+        jax.random.key(0), cfg))["layers"][0]["mixer"]
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16 if a.ndim > 1 else jnp.float32,
+        sharding=one_chip), layer)
+    x = jax.ShapeDtypeStruct((1, L, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, segments_per_row(L)), jnp.int32,
+                               sharding=one_chip)
+
+    def mixer(p, x, seg_lengths):
+        segments = Segments.of_lengths(seg_lengths, L)
+        if kind == sala.SPARSE:
+            return sala.sparse_mixer(p, x, segments, cfg)
+        return sala.lightning_mixer(p, x, segments, cfg)
+
+    compiled = jax.jit(mixer).lower(p, x, seg).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert "while" in compiled.as_text()
