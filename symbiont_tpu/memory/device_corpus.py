@@ -11,7 +11,12 @@ arm, by a corpus whose rows are sharded over chips:
   over the mesh's `AXIS` axis or on one device), `place` (host array → device
   under that rule) and `mesh_of` (the rule read back off a placed array);
 - the program: `scan_topk`, the cosine scan of every row against one query
-  and the exact top-k, traced into whatever jit calls it;
+  and the exact top-k, traced into whatever jit calls it. "The exact top-k"
+  is what `jax.lax.top_k` returns over the whole score vector, values and
+  indices, ties broken by row order: `_exact_topk` selects it without
+  sorting the vector where the vector is long (per-block maxes pick the k
+  blocks that can hold a winner; only their rows are sorted), and
+  `corpus.topk{path}` counts which form each traced program took;
 - the k policy: `k_bucket` (the static k a query is served with) beside
   `warm_k_buckets` (the ks a warm-up compiles): what is routed fused is what
   was warmed.
@@ -27,6 +32,8 @@ ROWS_DTYPE = "float32"   # unit rows at rest on the device
 SCAN_DTYPE = "bfloat16"  # corpus and query cast to this per query (MXU)
 AXIS = "data"            # the mesh axis rows shard over
 K_FLOOR = 8              # smallest k bucket
+TOPK_BLOCK = 1024        # scores per block of the blocked top-k
+TOPK_MIN_BLOCKS_PER_K = 4  # blocked from TOPK_MIN_BLOCKS_PER_K * k blocks up
 
 
 # ---------------------------------------------------------------- layout
@@ -92,9 +99,48 @@ def _masked_scores(rows, q, n_valid, base=None):
     return jnp.where(ids < n_valid, scores, -jnp.inf), ids
 
 
+def _exact_topk(scores, k: int, block: int = TOPK_BLOCK):
+    """`jax.lax.top_k(scores, k)` of a score vector [n], element for element
+    (values and indices; equal scores in position order), without sorting
+    the vector where it is long: n >= TOPK_MIN_BLOCKS_PER_K * k * block, a
+    static choice on the shape and k, counted in `corpus.topk{path}` once
+    per traced call. The blocked form takes each block's max in one pass,
+    top-ks the maxes for the k blocks that can hold a top-k row, and top-ks
+    those blocks' k * block scores.
+
+    Why no row is lost, ties included: `lax.top_k` orders by (score
+    descending, position ascending). Were a top-k row e in a block b that
+    was not chosen, k blocks would come before b by (max descending, block
+    ascending), each holding a row that scores >= b's max >= e and, where
+    equal, lies in a lower block, so at a lower position: k rows ahead of
+    e. The chosen blocks are gathered in ascending order, so a candidate's
+    position orders as its row does and the last top-k breaks ties as the
+    whole one would."""
+    import jax
+    import jax.numpy as jnp
+
+    from symbiont_tpu.utils.telemetry import metrics
+
+    n = scores.shape[0]
+    blocked = n >= TOPK_MIN_BLOCKS_PER_K * k * block
+    metrics.inc("corpus.topk",
+                labels={"path": "blocked" if blocked else "direct"})
+    if not blocked:
+        return jax.lax.top_k(scores, k)
+    if n % block:
+        scores = jnp.pad(scores, (0, -n % block), constant_values=-jnp.inf)
+    tiles = scores.reshape(-1, block)
+    _, blocks = jax.lax.top_k(tiles.max(axis=1), k)
+    blocks = jnp.sort(blocks)
+    vals, pos = jax.lax.top_k(tiles[blocks].reshape(-1), k)
+    return vals, blocks[pos // block] * block + pos % block
+
+
 def scan_topk(corpus, q, n_valid, k: int, mesh=None):
     """Exact cosine top-k of `q` [dim] over the `n_valid` stored rows of
-    `corpus` [cap, dim]: (scores[k], row indices[k]), best first. Trace-time
+    `corpus` [cap, dim]: (scores[k], row indices[k]), best first, as
+    `jax.lax.top_k` over all cap scores orders them (`_exact_topk`: equal
+    scores, common at bfloat16 spacing, come back in row order). Trace-time
     only (call inside jit), under the scopes `scan` and `topk`.
 
     With a `mesh` (`is_sharded`: rows over its data axis) each shard scores
@@ -102,7 +148,8 @@ def scan_topk(corpus, q, n_valid, k: int, mesh=None):
     merge top-ks the [n_shards x k] candidates: only k candidates per shard
     cross the interconnect, never the score vector. The order is the
     one-device order: `lax.top_k` breaks ties by position and shards
-    concatenate in global-row order (pinned in tests)."""
+    concatenate in global-row order, and a shard's own top-k is the same
+    `_exact_topk` (pinned in tests)."""
     import jax
 
     q = q.astype(SCAN_DTYPE)
@@ -110,7 +157,7 @@ def scan_topk(corpus, q, n_valid, k: int, mesh=None):
         with jax.named_scope("scan"):
             scores, _ = _masked_scores(corpus, q, n_valid)
         with jax.named_scope("topk"):
-            return jax.lax.top_k(scores, k)
+            return _exact_topk(scores, k)
 
     from jax import shard_map
 
@@ -127,7 +174,7 @@ def scan_topk(corpus, q, n_valid, k: int, mesh=None):
             base = jax.lax.axis_index(AXIS) * rows
             scores, gidx = _masked_scores(c, q, nv, base)
         with jax.named_scope("topk"):
-            s, li = jax.lax.top_k(scores, min(k, rows))
+            s, li = _exact_topk(scores, min(k, rows))
             return s, gidx[li]
 
     cand_s, cand_i = shard_map(

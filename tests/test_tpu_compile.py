@@ -115,3 +115,24 @@ def test_long_row_mixers_compile_within_the_chip_at_published_widths(
     compiled = jax.jit(mixer).lower(p, x, seg).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     assert "while" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k", [8, 16])
+def test_the_search_program_sorts_blocks_not_the_score_vector(one_chip, k):
+    """`search_fused`'s scan + top-k (1,450,000 rows of 768 in 23 capacity
+    blocks, k buckets 8 and 16): the chip's compiler is handed sorts of the
+    1,472 block maxes and of the k chosen blocks' k x 1,024 scores, and none
+    of the 1,507,328 scores themselves (2.1 ms a query on the v5e)."""
+    from symbiont_tpu.memory import device_corpus
+
+    cap = device_corpus.capacity(1_450_000, 65_536)
+    text = jax.jit(
+        lambda c, q, n: device_corpus.scan_topk(c, q, n, k)
+    ).lower(jax.ShapeDtypeStruct((cap, 768), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((768,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+            ).compile().as_text()
+    sorted_lengths = sorted(
+        int(n) for n in re.findall(r"= \(\w+\[(\d+)\][^=]*\) sort\(", text))
+    assert sorted_lengths == [k, cap // device_corpus.TOPK_BLOCK,
+                              k * device_corpus.TOPK_BLOCK]
