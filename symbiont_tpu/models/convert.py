@@ -341,6 +341,49 @@ def convert_sala(state_dict: Dict[str, Any], cfg) -> Params:
     return params
 
 
+def convert_ouro(state_dict: Dict[str, Any], cfg) -> Params:
+    """Map an `ouro` state_dict (HF names: the Llama layout plus each
+    block's second norms `input_layernorm_2` / `post_attention_layernorm_2`
+    and `model.early_exit_gate`) to the ouro.py pytree, whose layers are
+    STACKED leaf by leaf on a leading axis for the scan over them. Torch
+    Linear [out, in] -> [layers, in, out], read leaf by leaf in the
+    checkpoint's own dtype as `convert_mla_moe` does; norm scales and the
+    gate's bias go to float32. `lm_head` is not read: the encoder role pools
+    hidden states."""
+    sd = {k.removeprefix("model."): v for k, v in state_dict.items()}
+    n = cfg.num_layers
+
+    def take(name: str) -> np.ndarray:
+        if name not in sd:
+            raise KeyError(f"checkpoint missing tensor {name!r}; have e.g. "
+                           f"{sorted(sd)[:5]}")
+        return _to_numpy(sd.pop(name))
+
+    def stacked(name: str) -> dict:
+        return {"kernel": _transposed(
+            [take(f"layers.{i}.{name}.weight") for i in range(n)])}
+
+    def ln(name: str) -> dict:
+        return {"scale": np.stack(
+            [take(f"layers.{i}.{name}.weight").astype(np.float32)
+             for i in range(n)])}
+
+    return {
+        "wte": take("embed_tokens.weight"),
+        "ln_f": {"scale": take("norm.weight").astype(np.float32)},
+        "gate": {"kernel": _transposed([take("early_exit_gate.weight")])[0],
+                 "bias": take("early_exit_gate.bias").astype(np.float32)},
+        "layers": {
+            "ln1": ln("input_layernorm"),
+            "ln1_post": ln("input_layernorm_2"),
+            "ln2": ln("post_attention_layernorm"),
+            "ln2_post": ln("post_attention_layernorm_2"),
+            "attn": {k: stacked(f"self_attn.{k}_proj") for k in "qkvo"},
+            "mlp": {k: stacked(f"mlp.{k}_proj")
+                    for k in ("gate", "up", "down")}},
+    }
+
+
 def export_hf_bert(params: Params, cfg: BertConfig, out_dir: str | Path,
                    tokenizer_file: str | Path | None = None) -> Path:
     """Inverse of convert_bert: write a hub-format model dir
@@ -448,6 +491,14 @@ def load_sala_model(model_dir: str | Path):
 
     cfg = SalaConfig.from_hf(load_hf_config(model_dir))
     return convert_sala(load_state_dict(model_dir), cfg), cfg
+
+
+def load_ouro_model(model_dir: str | Path):
+    """One-call load: (params, OuroConfig) from a local HF model dir."""
+    from symbiont_tpu.models.ouro import OuroConfig
+
+    cfg = OuroConfig.from_hf(load_hf_config(model_dir))
+    return convert_ouro(load_state_dict(model_dir), cfg), cfg
 
 
 def load_bert_model(model_dir: str | Path, with_pooler: bool = False):

@@ -5,8 +5,10 @@ random `init_params` and `embed(params, ids, mask, cfg, pooling, normalize,
 segments=None) -> (rows [B, H] float32, aux)` where `aux` is None or what
 the family's forward counts on the device (mla_moe: real tokens per expert
 layer and expert; sala: keys attended, causal keys and dense-path tokens per
-row and sparse layer), and `note_aux(aux)`, which books a fetched `aux`
-under the family's own series (None where the forward counts nothing). With `segments` (models/bert.py `Segments`: the batched
+row and sparse layer; ouro: each loop step's exit mass and the token-steps
+run, per row), and `note_aux(aux)`, which books a fetched `aux` under the
+family's own series (None where the forward counts nothing). With
+`segments` (models/bert.py `Segments`: the batched
 `embed` program's packed rows) a row holds several sentences and the rows
 come back [B, S, H]; without, the forward is the unpacked one the fused
 query runs. Everything else — tokenizer, bucketing, batcher, the `embed` /
@@ -20,9 +22,10 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from symbiont_tpu.models import bert, mla_moe, sala
+from symbiont_tpu.models import bert, mla_moe, ouro, sala
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.models.mla_moe import MlaMoeConfig
+from symbiont_tpu.models.ouro import OuroConfig
 from symbiont_tpu.models.sala import SalaConfig
 from symbiont_tpu.utils.telemetry import metrics
 
@@ -48,6 +51,12 @@ def _load_sala(model_dir):
     from symbiont_tpu.models.convert import load_sala_model
 
     return load_sala_model(model_dir)
+
+
+def _load_ouro(model_dir):
+    from symbiont_tpu.models.convert import load_ouro_model
+
+    return load_ouro_model(model_dir)
 
 
 _LABELS = {"service": "engine"}
@@ -81,6 +90,27 @@ def _note_sparse(counts) -> None:
                             labels=_LABELS)
 
 
+def _note_loop(aux) -> None:
+    """Loop series of one embed dispatch: `aux` [rows, steps + 1] float32 =
+    per row each published step's exit mass (the sum over its real tokens of
+    `p_t`: a row's steps add up to its real tokens) and, last, the
+    token-steps the loop ran (docs/OBSERVABILITY.md)."""
+    aux = np.asarray(aux, np.float64)
+    mass, steps = aux[:, :-1].sum(0), aux.shape[1] - 1
+    tokens = int(round(mass.sum()))
+    metrics.inc("engine.loop.token_steps_run", int(round(aux[:, -1].sum())),
+                labels=_LABELS)
+    metrics.inc("engine.loop.token_steps_published", tokens * steps,
+                labels=_LABELS)
+    for t, m in enumerate(mass):
+        metrics.inc("engine.loop.exit_mass", float(m),
+                    labels={**_LABELS, "step": str(t)})
+    if tokens > 0:
+        metrics.observe("engine.loop.expected_exit_step",
+                        float((mass * np.arange(steps)).sum() / mass.sum()),
+                        labels=_LABELS)
+
+
 @dataclass(frozen=True)
 class Family:
     name: str
@@ -98,7 +128,9 @@ MLA_MOE = Family("mla_moe", mla_moe.MODEL_TYPES, MlaMoeConfig, _load_mla_moe,
                  mla_moe.init_params, mla_moe.embed_sentences, _note_moe)
 SALA = Family("sala", sala.MODEL_TYPES, SalaConfig, _load_sala,
               sala.init_params, sala.embed_sentences, _note_sparse)
-FAMILIES = (BERT, MLA_MOE, SALA)
+OURO = Family("ouro", ouro.MODEL_TYPES, OuroConfig, _load_ouro,
+              ouro.init_params, ouro.embed_sentences, _note_loop)
+FAMILIES = (BERT, MLA_MOE, SALA, OURO)
 _BY_TYPE = {t: f for f in FAMILIES for t in f.model_types}
 _BY_CONFIG = {f.config_cls: f for f in FAMILIES}
 

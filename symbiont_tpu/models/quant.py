@@ -50,6 +50,7 @@ Params = Any
 
 _INT8_AMAX = 127.0
 _FP8_AMAX = 448.0  # float8_e4m3fn finite max
+_NEVER_QUANTIZED = ("scale", "bias")  # leaf names of norm params and biases
 
 
 @jax.tree_util.register_pytree_node_class
@@ -57,10 +58,11 @@ class QuantTensor:
     """A per-channel-quantized weight: `q` (int8 or fp8, [r, c]) and `scale`
     (f32, [c], over the LAST axis); or a stack of such kernels `q` [E, r, c]
     with `scale` [E, c], one scale per stacked kernel and output channel
-    (expert kernels: 64 experts must not share one scale). Dequantized value
-    = q * scale. Registered as a pytree node so it flows through
-    jit/device_put; every cast-to-compute-dtype sweep must treat it as a
-    leaf (cast_params)."""
+    (expert kernels: 64 experts must not share one scale; the layers of a
+    scanned stack: `lax.scan` slices `q` and `scale` together, and the body
+    gets the first form). Dequantized value = q * scale. Registered as a
+    pytree node so it flows through jit/device_put; every
+    cast-to-compute-dtype sweep must treat it as a leaf (cast_params)."""
 
     __slots__ = ("q", "scale")
 
@@ -117,18 +119,23 @@ def channel_quantize(w, amax: float, qdtype) -> QuantTensor:
 
 def quantize_params(params: Params, mode: str) -> Params:
     """Quantize every floating leaf of rank ≥ 2 (matmul kernels, embedding
-    tables) per `mode`; rank-1 leaves (biases, norm params) stay f32.
+    tables) per `mode`; rank-1 leaves (biases, norm params) stay f32, and so
+    do norm scales and biases STACKED on a leading layer axis (`scale` /
+    `bias` [layers, n] of a stack a scan slices, models/ouro.py): what a
+    leaf is for is said by its name, which a stack does not change.
     Idempotent on already-quantized leaves. Runs ONCE on host."""
     if mode not in MODES:
         raise ValueError(f"quantize must be one of {MODES}, got {mode!r}")
     if mode == "none":
         return params
 
-    def one(a):
+    def one(path, a):
         if isinstance(a, QuantTensor):
             return a
         if not (hasattr(a, "dtype") and hasattr(a, "ndim")
                 and jnp.issubdtype(a.dtype, jnp.floating) and a.ndim >= 2):
+            return a
+        if path and getattr(path[-1], "key", None) in _NEVER_QUANTIZED:
             return a
         if mode == "f16":
             return jnp.asarray(a, jnp.bfloat16)
@@ -136,7 +143,7 @@ def quantize_params(params: Params, mode: str) -> Params:
             return channel_quantize(a, _INT8_AMAX, jnp.int8)
         return channel_quantize(a, _FP8_AMAX, jnp.float8_e4m3fn)
 
-    return jax.tree.map(one, params, is_leaf=_leaf)
+    return jax.tree_util.tree_map_with_path(one, params, is_leaf=_leaf)
 
 
 def cast_params(params: Params, dtype) -> Params:
