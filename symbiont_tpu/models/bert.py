@@ -221,7 +221,12 @@ def embeddings(
     segments: Optional[Segments] = None,
 ) -> jax.Array:
     B, S = input_ids.shape
-    tok = quant.take(params["word_embeddings"], input_ids)
+    # tables stay at rest and `quant.take` casts the rows it gathered (a
+    # QuantTensor's come back float32 and are summed so); the LayerNorm's
+    # scale and bias go through the shared leaf-aware cast
+    dtype = jnp.dtype(cfg.dtype)
+    ln = quant.cast_params(params["ln"], dtype)
+    tok = quant.take(params["word_embeddings"], input_ids, dtype)
     if cfg.position_offset:
         # RoBERTa-style: positions count only non-pad tokens, offset past pad id.
         mask = attention_mask.astype(jnp.int32)
@@ -233,12 +238,12 @@ def embeddings(
         positions = segments.position
     else:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    pos = quant.take(params["position_embeddings"], positions)
+    pos = quant.take(params["position_embeddings"], positions, dtype)
     if token_type_ids is None:
         token_type_ids = jnp.zeros_like(input_ids)
-    typ = quant.take(params["token_type_embeddings"], token_type_ids)
+    typ = quant.take(params["token_type_embeddings"], token_type_ids, dtype)
     x = tok + pos + typ
-    x = layer_norm(x, params["ln"]["scale"], params["ln"]["bias"], cfg.layer_norm_eps)
+    x = layer_norm(x, ln["scale"], ln["bias"], cfg.layer_norm_eps)
     return x
 
 
@@ -255,14 +260,9 @@ def bert_encode(
         raise ValueError("the flash kernel takes a per-key bias: packed rows "
                          "need attn_impl='xla'")
     dtype = jnp.dtype(cfg.dtype)
-    # shared leaf-aware cast: floating params → compute dtype, QuantTensor
-    # leaves untouched (their f32 scales must not be downcast). Cast per
-    # subtree inside the scope that uses it, so a device trace charges the
-    # table's cast to `embeddings` and the layers' to `encoder`.
     with jax.named_scope("embeddings"):
-        x = embeddings(quant.cast_params(params["embeddings"], dtype),
-                       input_ids, attention_mask, cfg, token_type_ids,
-                       segments)
+        x = embeddings(params["embeddings"], input_ids, attention_mask, cfg,
+                       token_type_ids, segments)
         x = x.astype(dtype)
     with jax.named_scope("encoder"):
         # additive mask bias: 0 for real tokens, large negative for padding
@@ -270,6 +270,10 @@ def bert_encode(
         attended = (attention_mask[:, None, None, :] if segments is None
                     else segments.same[:, None])
         mask_bias = (1.0 - attended.astype(jnp.float32)) * -1e9
+        # shared leaf-aware cast: floating params → compute dtype,
+        # QuantTensor leaves untouched (their f32 scales must not be
+        # downcast); inside the scope that uses them, so a device trace
+        # charges the layers' casts to `encoder`
         for layer_params in quant.cast_params(params["layers"], dtype):
             x = encoder_layer(layer_params, x, mask_bias, cfg)
     return x

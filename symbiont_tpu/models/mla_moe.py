@@ -241,8 +241,7 @@ def encode(params: Params, input_ids: jax.Array, attention_mask: jax.Array,
     counts [expert layers, E] int32)."""
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("embeddings"):
-        x = quant.take(quant.cast_params(params["wte"], dtype),
-                       input_ids).astype(dtype)
+        x = quant.take(params["wte"], input_ids, dtype).astype(dtype)
     # every layer's attention has one shape, and so has every expert layer:
     # a `jax.jit` of this call's own traces and lowers each ONCE and calls
     # it per layer (the compiler inlines the calls: the program is the

@@ -27,7 +27,9 @@ Quantized leaves are `QuantTensor` pytree nodes — (q, scale) ride through
 jit / device_put / donation like any other params, and `cast_params` (the
 shared entry-cast used by models/bert.py, models/gpt.py and engine/lm.py)
 treats them as atomic leaves so the f32 scales are never downcast by the
-compute-dtype sweep.
+compute-dtype sweep. An encoder's embedding tables stay out of that sweep:
+`take` gathers the rows a call names from the table at rest and casts the
+rows, so a float32 table is never rewritten whole inside a forward.
 
 Rank-1 params (biases, norm scales) stay f32: they are a rounding error of
 the byte budget and the norms want exact statistics.
@@ -215,14 +217,21 @@ def mm_tied(x, w):
     return x @ w.T
 
 
-def take(w, ids):
-    """Embedding-table gather with per-hidden-channel dequant: `q[ids] *
-    scale` (scale is over the hidden axis, exact per element). Returns f32
-    for quantized tables — callers cast the summed embedding to compute
-    dtype, which they already do for the unquantized path."""
+def take(w, ids, dtype=None):
+    """Embedding-table gather: the rows `ids` name, and only those, are read
+    and converted. A plain table is gathered in its at-rest dtype and the
+    gathered rows are cast to `dtype` (the compute dtype; None leaves them at
+    rest): `cast(table)[ids]` equals `cast(table[ids])` bit for bit, but a
+    table cast first is swept whole on every forward (250,002 x 768 float32
+    read, bfloat16 written, for the 8-128 rows a query names), so the gather
+    comes first and no caller casts a table before it. A quantized table
+    dequantizes per hidden channel, `q[ids] * scale` (exact per element), and
+    returns float32 whatever `dtype` says: a caller that sums several tables
+    sums them at full precision and casts the sum to its compute dtype."""
     if isinstance(w, QuantTensor):
         return w.q[ids].astype(jnp.float32) * w.scale
-    return w[ids]
+    rows = w[ids]
+    return rows if dtype is None else rows.astype(dtype)
 
 
 def kv_channel_quantize(t, eps: float = 1e-8):

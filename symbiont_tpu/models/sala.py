@@ -247,7 +247,7 @@ def encode(params: Params, input_ids: jax.Array, segments: Segments,
     counts [B, sparse layers, 3] int32)."""
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("embeddings"):
-        x = (quant.take(quant.cast_params(params["wte"], dtype), input_ids)
+        x = (quant.take(params["wte"], input_ids, dtype)
              * cfg.scale_emb).astype(dtype)
     a = cfg.scale_depth / math.sqrt(cfg.depth_layers)
     # each kind of layer has one shape: traced and lowered once, called per

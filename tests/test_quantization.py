@@ -21,8 +21,10 @@ on this platform.
 """
 
 import dataclasses
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -290,6 +292,125 @@ def test_f16_storage_survives_wider_compute_dtype():
     # and it still decodes (bf16 weights upcast at trace into f32 compute)
     assert narrow.generate("hello", 8, temperature=0.0)
     del wide, narrow
+
+
+# ------------------------------------------- the embedding gather's order
+
+def _parent_take(w, ids, dtype=None):
+    """`quant.take` as every caller spelled it before the gather came first:
+    the whole table through the entry cast, THEN the rows; a QuantTensor
+    rides the cast untouched and dequantizes to float32."""
+    if dtype is not None:
+        w = quant.cast_params(w, dtype)
+    if quant.is_quantized(w):
+        return w.q[ids].astype(jnp.float32) * w.scale
+    return w[ids]
+
+
+def _toy_family(name: str):
+    """(family, cfg) at the toy widths the family's own test file uses."""
+    from symbiont_tpu.models import families, mla_moe, ouro, sala
+
+    if name == "bert":
+        # three tables of three shapes, so a text names each by its shape
+        cfg = BertConfig(vocab_size=211, hidden_size=32, num_layers=2,
+                         num_heads=4, intermediate_size=64, type_vocab_size=3,
+                         max_position_embeddings=70, position_offset=2,
+                         dtype="bfloat16")
+    elif name == "mla_moe":
+        from test_packing import MOE
+
+        cfg = mla_moe.MlaMoeConfig.from_hf(MOE)
+    elif name == "sala":
+        from test_sala import MODEL
+
+        cfg = sala.SalaConfig.from_hf(MODEL)
+    else:
+        from test_ouro import MODEL
+
+        cfg = ouro.OuroConfig.from_hf(MODEL)
+    return families.family_of_config(cfg), cfg
+
+
+_ROWS, _LEN = 2, 64
+_SEG_LENGTHS = np.asarray([[20, 7, 30, 0], [64, 0, 0, 0]], np.int32)
+
+
+def _embed_program(fam, cfg, packed: bool):
+    """The family's embed forward as the engine traces it: packed rows (the
+    batched `embed` program) or one text a row (what `qsearch` runs), and its
+    arguments less the params."""
+    rng = np.random.default_rng(5)
+    ids = jnp.asarray(rng.integers(3, cfg.vocab_size, (_ROWS, _LEN)),
+                      jnp.int32)
+    if packed:
+        def fn(params, ids, seg_lengths):
+            seg = bert_mod.Segments.of_lengths(seg_lengths, _LEN)
+            return fam.embed(params, ids, seg.real, cfg, "mean", True, seg)[0]
+        return fn, (ids, jnp.asarray(_SEG_LENGTHS))
+    mask = jnp.asarray(np.arange(_LEN)[None, :] < np.asarray([[50], [9]]),
+                       jnp.int32)
+    return (lambda params, ids, mask: fam.embed(
+        params, ids, mask, cfg, "mean", True)[0]), (ids, mask)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("tables", ["plain", "int8"])
+def test_embedding_rows_are_gathered_at_rest_then_cast(tables, packed,
+                                                       monkeypatch):
+    """float32 at rest, bfloat16 compute (`xlmr-base-retrieval`'s stated
+    precision): no table is converted whole inside the program (the [250002,
+    768] sweep was 1.8 of `search_fused`'s 8.3 ms), and the rows are the
+    rows cast-then-gather gives, bit for bit, from plain and from int8
+    tables (whose text is held equal in the test below)."""
+    fam, cfg = _toy_family("bert")
+    params = bert_mod.init_params(jax.random.key(2), cfg)
+    assert params["embeddings"]["word_embeddings"].dtype == jnp.float32
+    if tables == "int8":
+        params = quant.quantize_params(params, "int8")
+    fn, args = _embed_program(fam, cfg, packed)
+    text = jax.jit(fn).lower(params, *args).as_text()
+    H = cfg.hidden_size
+    for rows in (cfg.vocab_size, cfg.max_position_embeddings,
+                 cfg.type_vocab_size):
+        assert f"tensor<{rows}x{H}x" in text  # the table is an argument
+        assert not re.search(
+            rf"stablehlo\.convert[^\n]*\(tensor<{rows}x{H}x", text), rows
+    got = jax.jit(fn)(params, *args)
+    monkeypatch.setattr(quant, "take", _parent_take)
+    fn, args = _embed_program(fam, cfg, packed)  # a function not yet traced
+    parent_text = jax.jit(fn).lower(params, *args).as_text()
+    want = jax.jit(fn)(params, *args)
+    assert np.isfinite(np.asarray(got)).all() and np.asarray(got).any()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the spelling inlined above is the parent's: it did sweep plain tables
+    swept = re.search(
+        rf"stablehlo\.convert[^\n]*\(tensor<{cfg.vocab_size}x{H}xf32>",
+        parent_text)
+    assert bool(swept) == (tables == "plain")
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("family,mode", [
+    ("mla_moe", "f16"), ("sala", "f16"), ("ouro", "f16"),
+    ("bert", "f16"), ("bert", "int8")])
+def test_tables_at_rest_in_the_compute_dtype_lower_to_the_text_they_had(
+        family, mode, packed, monkeypatch):
+    """`ingest_pages_moe`, `ingest_longdocs_sala` and `ingest_chunks_ouro`
+    rest in bfloat16 (`engine.quantize` f16), where a cast is the identity
+    before or after the gather: through `quant.take` their embed programs
+    lower to the text the parent's spelling lowers to, and so does a BERT
+    at rest in bfloat16 or int8. An edit of `quant.take` that changes what
+    three cells run fails here, not in their ledger lines."""
+    fam, cfg = _toy_family(family)
+    params = quant.quantize_params(fam.init_params(jax.random.key(4), cfg),
+                                   mode)
+    fn, args = _embed_program(fam, cfg, packed)
+    text = jax.jit(fn).lower(params, *args).as_text()
+    monkeypatch.setattr(quant, "take", _parent_take)
+    fn, args = _embed_program(fam, cfg, packed)  # a function not yet traced
+    assert jax.jit(fn).lower(params, *args).as_text() == text
+    assert "stablehlo.gather" in text
 
 
 # ----------------------------------------------------- training interplay
