@@ -62,7 +62,7 @@ from symbiont_tpu.models import families
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.obs.hbm import guard_oom, hbm_ledger
 from symbiont_tpu.obs.xprof import compile_analysis_for, dispatch_ledger
-from symbiont_tpu.utils.telemetry import metrics, span
+from symbiont_tpu.utils.telemetry import carry_context, metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -474,10 +474,10 @@ class TpuEngine:
 
     def _note_padding(self, true_lengths, bucket: int, batch_rows: int,
                       n_real: int) -> None:
-        """Bucket padding-waste + batch fill-ratio gauges for one dispatched
-        batch (engine/bucketing.py quantified live): `true_lengths` of every
-        sequence in it (a packed row holds several), `n_real` of its
-        `batch_rows` rows holding any."""
+        """Real and padding token counters + the batch fill-ratio gauge for
+        one dispatched batch (engine/bucketing.py quantified live):
+        `true_lengths` of every sequence in it (a packed row holds several),
+        `n_real` of its `batch_rows` rows holding any."""
         real, total = padding_stats(true_lengths, bucket, batch_rows)
         # decode-plane flight recorder, embed side (obs/engine_timeline.py):
         # the per-flush bucket-occupancy/padding timeline behind the
@@ -492,9 +492,6 @@ class TpuEngine:
         metrics.inc("engine.tokens_padding", total - real, labels=labels)
         metrics.gauge_set("engine.batch_fill_ratio",
                           round(n_real / batch_rows, 4) if batch_rows else 0.0,
-                          labels=labels)
-        metrics.gauge_set("engine.bucket_pad_waste_ratio",
-                          round(1.0 - real / total, 4) if total else 0.0,
                           labels=labels)
         if self._n_data > 1 and batch_rows:
             # DP accounting (docs/SCALING.md): rows shard contiguously over
@@ -578,25 +575,31 @@ class TpuEngine:
         so this returns as soon as the last batch is enqueued. `offset` maps
         chunk-local indices back to the caller's rows. A pending entry says
         where each sentence's row lies in the dispatch's [B, S, H] result."""
-        lengths = [len(e) for e in encoded]
-        L, dispatches = plan_packed(lengths, buckets, self._plan_cap)
+        with span("engine.embed.pack", cpu=True):
+            lengths = [len(e) for e in encoded]
+            L, dispatches = plan_packed(lengths, buckets, self._plan_cap)
         labels = {"service": "engine"}
         for rows in dispatches:
-            bb = self._batch_bucket(len(rows))
-            ids, seg = pack_rows(encoded, rows, L, bb, self.tokenizer.pad_id,
-                                 dtype=self._ids_dtype)
-            sent = [i for row in rows for i in row]
-            self._note_padding([lengths[i] for i in sent], L, bb, len(rows))
+            with span("engine.embed.pack", cpu=True):
+                bb = self._batch_bucket(len(rows))
+                ids, seg = pack_rows(encoded, rows, L, bb,
+                                     self.tokenizer.pad_id,
+                                     dtype=self._ids_dtype)
+                sent = [i for row in rows for i in row]
+                self._note_padding([lengths[i] for i in sent], L, bb,
+                                   len(rows))
+                # (row, slot) of each sentence in the [B, S, H] result
+                at = ([r for r, row in enumerate(rows) for _ in row],
+                      [s for row in rows for s in range(len(row))])
+                sent = [offset + i for i in sent]
             metrics.inc("engine.embed.dispatches", labels=labels)
             metrics.observe("engine.pack.segments_per_row",
                             len(sent) / len(rows), labels=labels)
-            fn = self._get_executable("embed", L, bb)
-            ids_d, seg_d = self._device_batch(ids, seg)
-            # (row, slot) of each sentence in the [B, S, H] result
-            at = ([r for r, row in enumerate(rows) for _ in row],
-                  [s for row in rows for s in range(len(row))])
-            pending.append(([offset + i for i in sent], at,
-                            *fn(self.params, ids_d, seg_d)))
+            with span("engine.embed.dispatch", cpu=True):
+                fn = self._get_executable("embed", L, bb)
+                ids_d, seg_d = self._device_batch(ids, seg)
+                res = fn(self.params, ids_d, seg_d)
+            pending.append((sent, at, *res))
 
     def embed_texts(self, texts: Sequence[str]) -> np.ndarray:
         """Texts → [n, hidden] float32 embeddings. Parity surface of the
@@ -620,28 +623,32 @@ class TpuEngine:
                            np.float32)
             chunk = self.config.host_prep_chunk
             pending = []
+
+            def tokenize(part):
+                with span("engine.embed.tokenize", cpu=True, rows=len(part)):
+                    return self.tokenizer.encode_batch(part, max_len)
+
             if 0 < chunk < len(texts):
                 texts = list(texts)
                 pool = self._prep_executor()
-                fut = pool.submit(self.tokenizer.encode_batch,
-                                  texts[:chunk], max_len)
+                # on the prep thread the span still parents to this call's
+                prep = carry_context(tokenize)
+                fut = pool.submit(prep, texts[:chunk])
                 for start in range(0, len(texts), chunk):
                     encoded = fut.result()
                     nxt = start + chunk
                     if nxt < len(texts):
                         # prefetch BEFORE dispatching this chunk: tokenize of
                         # chunk N+1 runs while the device chews on chunk N
-                        fut = pool.submit(self.tokenizer.encode_batch,
-                                          texts[nxt:nxt + chunk], max_len)
+                        fut = pool.submit(prep, texts[nxt:nxt + chunk])
                     # a bulk call's chunks all pack into top-bucket rows
                     # (only its last could fit a shorter one): one [., S, H]
                     # result shape for the grouped fetch below
                     self._dispatch_embed(encoded, start, buckets[-1:],
                                          pending)
             else:
-                self._dispatch_embed(
-                    self.tokenizer.encode_batch(list(texts), max_len),
-                    0, buckets, pending)
+                self._dispatch_embed(tokenize(list(texts)), 0, buckets,
+                                     pending)
             # every batch is dispatched: what is left is waiting for the
             # device and fetching (no stamp adds a sync of its own)
             t_dispatched = time.perf_counter()
@@ -699,16 +706,20 @@ class TpuEngine:
             t0 = time.perf_counter()
             max_len = min(self.config.length_buckets[-1],
                           self.model_cfg.max_position_embeddings)
-            encoded = self.tokenizer.encode(text, max_len)
-            buckets = [b for b in self.config.length_buckets
-                       if b <= self.model_cfg.max_position_embeddings]
-            bucket = choose_bucket(len(encoded), buckets)
-            ids, mask = pad_to_bucket([encoded], bucket, self.tokenizer.pad_id,
-                                      dtype=self._ids_dtype)
-            fn = self._get_executable("qsearch", bucket, corpus_dev.shape[0],
-                                      top_k, device_corpus.mesh_of(corpus_dev))
-            scores, idx = fn(self.params, jnp.asarray(ids), jnp.asarray(mask),
-                             corpus_dev, n_valid)
+            with span("engine.qsearch.tokenize", cpu=True):
+                encoded = self.tokenizer.encode(text, max_len)
+                buckets = [b for b in self.config.length_buckets
+                           if b <= self.model_cfg.max_position_embeddings]
+                bucket = choose_bucket(len(encoded), buckets)
+                ids, mask = pad_to_bucket([encoded], bucket,
+                                          self.tokenizer.pad_id,
+                                          dtype=self._ids_dtype)
+            with span("engine.qsearch.dispatch", cpu=True):
+                fn = self._get_executable(
+                    "qsearch", bucket, corpus_dev.shape[0], top_k,
+                    device_corpus.mesh_of(corpus_dev))
+                scores, idx = fn(self.params, jnp.asarray(ids),
+                                 jnp.asarray(mask), corpus_dev, n_valid)
             t_dispatched = time.perf_counter()
             _start_host_copies((scores, idx))  # both d2h copies in flight
             self._bump(qsearch_calls=1)

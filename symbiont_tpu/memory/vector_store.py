@@ -211,42 +211,43 @@ class VectorStore:
         """Shared ingest tail (caller holds the lock, batch is validated
         [n, dim] f32 — possibly a read-only view; the WAL records the RAW
         vectors, normalization happens on the in-memory copy only)."""
-        norms = np.linalg.norm(batch, axis=1, keepdims=True)
-        normed = np.divide(batch, norms, out=batch.astype(np.float32,
-                                                          copy=True),
-                           where=norms > 0)
-        cap = self.config.shard_capacity
-        n_before, held = len(self._ids), list(self._blocks)
-        rows = []
-        new_pos: Dict[str, int] = {}  # ids first seen in THIS call — a
-        # duplicate id within one batch (e.g. WAL replay of an update)
-        # must overwrite, not append twice
-        for j, (pid, payload) in enumerate(zip(ids, payloads)):
-            if pid in self._id_to_row:
-                r = self._id_to_row[pid]
-                b, off = divmod(r, cap)
-                self._blocks[b][off] = normed[j]
-                self._payloads[r] = dict(payload)
+        with span("store.ingest_rows", cpu=True, rows=len(ids)):
+            norms = np.linalg.norm(batch, axis=1, keepdims=True)
+            normed = np.divide(batch, norms, out=batch.astype(np.float32,
+                                                              copy=True),
+                               where=norms > 0)
+            cap = self.config.shard_capacity
+            n_before, held = len(self._ids), list(self._blocks)
+            rows = []
+            new_pos: Dict[str, int] = {}  # ids first seen in THIS call — a
+            # duplicate id within one batch (e.g. WAL replay of an update)
+            # must overwrite, not append twice
+            for j, (pid, payload) in enumerate(zip(ids, payloads)):
+                if pid in self._id_to_row:
+                    r = self._id_to_row[pid]
+                    b, off = divmod(r, cap)
+                    self._blocks[b][off] = normed[j]
+                    self._payloads[r] = dict(payload)
+                    self._dirty = True
+                elif pid in new_pos:
+                    rows[new_pos[pid]] = (pid, j, dict(payload))
+                else:
+                    new_pos[pid] = len(rows)
+                    rows.append((pid, j, dict(payload)))
+            if rows:
+                self._append(normed[[j for _, j, _ in rows]])
+                for i, (pid, _, payload) in enumerate(rows):
+                    self._ids.append(pid)
+                    self._id_to_row[pid] = n_before + i
+                    self._payloads.append(payload)
                 self._dirty = True
-            elif pid in new_pos:
-                rows[new_pos[pid]] = (pid, j, dict(payload))
-            else:
-                new_pos[pid] = len(rows)
-                rows.append((pid, j, dict(payload)))
-        if rows:
-            self._append(normed[[j for _, j, _ in rows]])
-            for i, (pid, _, payload) in enumerate(rows):
-                self._ids.append(pid)
-                self._id_to_row[pid] = n_before + i
-                self._payloads.append(payload)
-            self._dirty = True
-        # read off the blocks, not assumed: one that is another array after
-        # the call has had the rows it held copied
-        metrics.inc("vector_store.host_bytes_moved", self.dim * 4 * sum(
-            min(cap, n_before - i * cap) for i, block in enumerate(held)
-            if self._blocks[i] is not block))
-        metrics.gauge_set("vector_store.host_blocks", len(self._blocks))
-        self._wal_append(list(zip(ids, batch, payloads)))
+            # read off the blocks, not assumed: one that is another array
+            # after the call has had the rows it held copied
+            metrics.inc("vector_store.host_bytes_moved", self.dim * 4 * sum(
+                min(cap, n_before - i * cap) for i, block in enumerate(held)
+                if self._blocks[i] is not block))
+            metrics.gauge_set("vector_store.host_blocks", len(self._blocks))
+        self._wal_append(zip(ids, batch, payloads))
         return len(ids)
 
     # -------------------------------------------------------------- search
@@ -393,16 +394,20 @@ class VectorStore:
         # accepts both this and the pre-r5 "vector" float-list records.
         import base64
 
-        lines = []
-        for pid, vec, payload in points:
-            rec = {"id": pid,
-                   "vector_b64": base64.b64encode(
-                       np.asarray(vec, np.float32).tobytes()).decode("ascii"),
-                   "payload": payload}
-            lines.append(json.dumps(rec, ensure_ascii=False))
-        self._wal_file.write("\n".join(lines) + "\n")
-        self._wal_file.flush()
-        os.fsync(self._wal_file.fileno())
+        with span("store.wal_encode", cpu=True):
+            lines = []
+            for pid, vec, payload in points:
+                rec = {"id": pid,
+                       "vector_b64": base64.b64encode(
+                           np.asarray(vec, np.float32).tobytes()
+                       ).decode("ascii"),
+                       "payload": payload}
+                lines.append(json.dumps(rec, ensure_ascii=False))
+            data = "\n".join(lines) + "\n"
+        with span("store.wal_sync", cpu=True):
+            self._wal_file.write(data)
+            self._wal_file.flush()
+            os.fsync(self._wal_file.fileno())
 
     def compact(self) -> None:
         """Snapshot vectors+payloads, truncate the WAL. The vectors file is

@@ -77,14 +77,13 @@ def _note_moe(counts) -> None:
 
 def _note_sparse(counts) -> None:
     """Block-selection series of one embed dispatch: `counts` [rows, sparse
-    layers, 3] = keys attended, keys a causal attention reads, tokens on the
-    dense path (docs/OBSERVABILITY.md)."""
+    layers, 2] = keys attended, keys a causal attention reads
+    (docs/OBSERVABILITY.md)."""
     per_layer = np.asarray(counts, np.int64).sum(0)
-    attended, causal, dense = (int(v) for v in per_layer.sum(0))
+    attended, causal = (int(v) for v in per_layer.sum(0))
     metrics.inc("engine.sparse.keys_attended", attended, labels=_LABELS)
     metrics.inc("engine.sparse.keys_causal", causal, labels=_LABELS)
-    metrics.inc("engine.sparse.dense_path_tokens", dense, labels=_LABELS)
-    for kept, of, _ in per_layer:
+    for kept, of in per_layer:
         if of > 0:
             metrics.observe("engine.sparse.kept_share", float(kept / of),
                             labels=_LABELS)

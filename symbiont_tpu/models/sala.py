@@ -31,9 +31,9 @@ feed-forward runs over chunks of rows so a 65,536-token dispatch does not
 hold two [tokens, intermediate] activations. Every projection goes through
 `quant.mm`, so f32, bf16, int8 and fp8 at rest all run.
 
-`embed_sentences` returns, beside the rows, `[B, sparse layers, 3]` int32
-per row: keys attended (mean over the KV groups), keys a causal attention
-would have read, tokens that took the dense path.
+`embed_sentences` returns, beside the rows, `[B, sparse layers, 2]` int32
+per row: keys attended (mean over the KV groups) and keys a causal
+attention would have read.
 
 Not here (ROADMAP Reach A4): recurrent state in the page pool, state
 snapshots for the radix cache, selection inside paged attention — the
@@ -174,7 +174,7 @@ def _heads(x, kernel, norm, heads: int, eps: float):
 
 def sparse_mixer(p: Params, x: jax.Array, segments: Segments,
                  cfg: SalaConfig):
-    """x [B, L, H] (normed) -> (out [B, L, H], counts [B, 3] int32)."""
+    """x [B, L, H] (normed) -> (out [B, L, H], counts [B, 2] int32)."""
     eps = cfg.rms_norm_eps
     with jax.named_scope("proj"):
         q = _heads(x, p["q"], p["q_norm"], cfg.num_heads, eps)
@@ -244,7 +244,7 @@ def one_passage(attention_mask: jax.Array) -> Segments:
 def encode(params: Params, input_ids: jax.Array, segments: Segments,
            cfg: SalaConfig):
     """-> (last hidden state after the final norm [B, L, H] in cfg.dtype,
-    counts [B, sparse layers, 3] int32)."""
+    counts [B, sparse layers, 2] int32)."""
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("embeddings"):
         x = (quant.take(params["wte"], input_ids, dtype)
@@ -270,7 +270,7 @@ def encode(params: Params, input_ids: jax.Array, segments: Segments,
             x = x + (a * ffn(layer["mlp"], layer["ln2"], x)).astype(dtype)
     x = rmsnorm(x, quant.cast_params(params["ln_f"], dtype), cfg.rms_norm_eps)
     counts = (jnp.stack(counts, axis=1) if counts
-              else jnp.zeros((x.shape[0], 0, 3), jnp.int32))
+              else jnp.zeros((x.shape[0], 0, 2), jnp.int32))
     return x, counts
 
 
@@ -280,7 +280,7 @@ def embed_sentences(params: Params, input_ids: jax.Array,
                     segments: Optional[Segments] = None):
     """Decoder stack + pooling -> ([B, H] float32 passage embeddings, or
     [B, S, H] for packed rows: `segments`, and `attention_mask` its `real`;
-    counts [B, sparse layers, 3] int32)."""
+    counts [B, sparse layers, 2] int32)."""
     packed = segments is not None
     if not packed:
         segments = one_passage(attention_mask)

@@ -32,8 +32,8 @@ costs what the masked keys do.
 
 `sp` is the model's sparse sizes (models/sala.py `SparseConfig`: kernel_size,
 kernel_stride, block_size, init_blocks, window_size, topk, dense_len).
-Returns, beside the output, per row: keys attended (mean over the groups),
-keys a causal attention reads, tokens of passages that took the dense path.
+Returns, beside the output, per row: keys attended (mean over the groups)
+and keys a causal attention reads.
 """
 
 from __future__ import annotations
@@ -148,7 +148,7 @@ def block_sparse_attention(q, k, v, index, position, lengths, sp, *,
                            with_sets: bool = False):
     """q [B, L, nh, d]; k, v [B, L, G, d] (nh a multiple of G); `index`,
     `position` [B, L] and `lengths` [B, S] as `Segments` has them ->
-    (out [B, L, nh, d] in q's dtype, counts [B, 3] int32).
+    (out [B, L, nh, d] in q's dtype, counts [B, 2] int32).
 
     The keyword arguments are test hooks; the model passes none. `q_block`
     and `k_chunk` shrink the tiles so that toy rows span several of each;
@@ -233,10 +233,9 @@ def block_sparse_attention(q, k, v, index, position, lengths, sp, *,
             attended = (jnp.where(sel, span[None], 0).sum(dtype=jnp.int32)
                         // G)
             causal = jnp.where(real, b_pos + 1, 0).sum(dtype=jnp.int32)
-            dense = (real & (b_len <= sp.dense_len)).sum(dtype=jnp.int32)
             extra = (sel, gap) if with_sets else ()
             return None, (out.astype(q.dtype),
-                          jnp.stack([attended, causal, dense]), *extra)
+                          jnp.stack([attended, causal]), *extra)
 
         return jax.lax.scan(block, None, (qr, r_seg, r_pos, r_at, r_len))[1]
 

@@ -51,6 +51,7 @@ class SymbiontStack:
         self.api: Optional[ApiService] = None
         self.watchdog = None  # obs.watchdog.SloWatchdog when configured
         self._heartbeat_task: Optional[asyncio.Task] = None
+        self._stop_lag_probe = None  # telemetry.start_loop_lag_probe's stop
         # drain protocol (resilience/autoscale.py scale-in): flipped by a
         # `_sys.drain.<role>` message from the supervisor; `drained` wakes
         # main() so the process exits once the drain completes
@@ -89,7 +90,11 @@ class SymbiontStack:
         from symbiont_tpu.obs.engine_timeline import engine_timeline
         from symbiont_tpu.obs.trace_store import trace_store
         from symbiont_tpu.obs.usage import usage
-        from symbiont_tpu.utils.telemetry import metrics
+        from symbiont_tpu.utils.telemetry import (
+            metrics,
+            python_cpu_s,
+            start_loop_lag_probe,
+        )
 
         if trace_store.capacity != cfg.obs.trace_capacity:
             trace_store.set_capacity(cfg.obs.trace_capacity)
@@ -149,6 +154,10 @@ class SymbiontStack:
         if cfg.obs.histogram_buckets_ms:
             metrics.set_bucket_bounds(cfg.obs.histogram_buckets_ms)
         register_process_gauges()  # platform-guarded no-op off Linux
+        # who holds the interpreter: its threads' CPU seconds, and how long
+        # a ready continuation waits for this loop
+        metrics.register_gauge("host.python_cpu_s", python_cpu_s)
+        self._stop_lag_probe = start_loop_lag_probe()
         if cfg.obs.slo_p99_ms:
             from symbiont_tpu.obs.watchdog import SloWatchdog, parse_thresholds
 
@@ -588,6 +597,9 @@ class SymbiontStack:
         if self.watchdog is not None:
             await self.watchdog.stop()
             self.watchdog = None
+        if self._stop_lag_probe is not None:
+            self._stop_lag_probe()
+            self._stop_lag_probe = None
         if self.api:
             await self.api.stop()
         for s in self.services:

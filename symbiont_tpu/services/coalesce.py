@@ -44,7 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from symbiont_tpu.utils.telemetry import metrics, span
+from symbiont_tpu.utils.telemetry import carry_context, metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -241,7 +241,8 @@ class UpsertCoalescer:
                 with span(f"{self.name}.flush", group[0].headers,
                           rows=len(ids), messages=len(group)):
                     await loop.run_in_executor(
-                        store_executor(), self._flush_fn, ids, rows, payloads)
+                        store_executor(), carry_context(self._flush_fn),
+                        ids, rows, payloads)
             except Exception as e:
                 log.exception("%s: coalesced flush of %d rows from %d "
                               "messages failed", self.name, len(ids),

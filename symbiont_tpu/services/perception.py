@@ -22,7 +22,7 @@ from symbiont_tpu.schema import PerceiveUrlTask, RawTextMessage, from_json, to_j
 from symbiont_tpu.services.base import Service
 from symbiont_tpu.services.html_extract import extract_main_text
 from symbiont_tpu.utils.ids import current_timestamp_ms, generate_uuid
-from symbiont_tpu.utils.telemetry import child_headers, metrics
+from symbiont_tpu.utils.telemetry import child_headers, metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -59,14 +59,16 @@ class PerceptionService(Service):
             metrics.inc("perception.scrape_failed")
             log.warning("scrape failed for %s: %s", task.url, e)
             return
-        text = extract_main_text(html)
+        with span("perception.extract", msg.headers, cpu=True):
+            text = extract_main_text(html)
+            if text:
+                data = to_json_bytes(RawTextMessage(
+                    id=generate_uuid(), source_url=task.url, raw_text=text,
+                    timestamp_ms=current_timestamp_ms()))
         if not text:
             metrics.inc("perception.empty_extraction")
             log.warning("no meaningful text extracted from %s", task.url)
             return
-        out = RawTextMessage(id=generate_uuid(), source_url=task.url,
-                             raw_text=text, timestamp_ms=current_timestamp_ms())
-        await self.bus.publish(subjects.DATA_RAW_TEXT_DISCOVERED,
-                               to_json_bytes(out),
+        await self.bus.publish(subjects.DATA_RAW_TEXT_DISCOVERED, data,
                                headers=child_headers(msg.headers))
         metrics.inc("perception.published")

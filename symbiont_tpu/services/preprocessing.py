@@ -40,7 +40,7 @@ from symbiont_tpu.schema import frames
 from symbiont_tpu.resilience import admission
 from symbiont_tpu.services.base import Service
 from symbiont_tpu.utils.ids import current_timestamp_ms
-from symbiont_tpu.utils.telemetry import child_headers, metrics
+from symbiont_tpu.utils.telemetry import child_headers, metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -84,13 +84,14 @@ class PreprocessingService(Service):
     # ------------------------------------------------------------- pipeline
 
     async def _handle_raw_text(self, msg: Msg) -> None:
-        raw = from_json(RawTextMessage, msg.data)
-        cleaned = clean_text(raw.raw_text)
+        with span("preprocessing.split", msg.headers, cpu=True):
+            raw = from_json(RawTextMessage, msg.data)
+            cleaned = clean_text(raw.raw_text)
+            sentences = split_sentences(cleaned) if cleaned else []
         if not cleaned:
             metrics.inc("preprocessing.empty_text")
             log.warning("cleaned text empty for id %s", raw.id)
             return
-        sentences = split_sentences(cleaned)
         # engine-plane fairness: the tenant header threaded from the edge
         # picks this document's lane in the micro-batcher — fairness holds
         # even when the API edge's admission plane is bypassed or restarted
@@ -99,10 +100,11 @@ class PreprocessingService(Service):
         # engine output → wire without a single per-float Python conversion:
         # frame mode appends the [n, dim] f32 block to the JSON metadata
         # (schema/frames); fallback mode emits the reference wire shape
-        data, fheaders = frames.encode_embeddings_message(
-            raw.id, raw.source_url, sentences, vectors, self.model_name,
-            current_timestamp_ms(), use_frame=self.use_frames)
-        headers = child_headers(msg.headers)
+        with span("preprocessing.frame", msg.headers, cpu=True):
+            data, fheaders = frames.encode_embeddings_message(
+                raw.id, raw.source_url, sentences, vectors, self.model_name,
+                current_timestamp_ms(), use_frame=self.use_frames)
+            headers = child_headers(msg.headers)
         # the frame header rides ONLY on the frame-bearing publish — the
         # tokenized publish below shares the trace context, not the frame
         await self.bus.publish(subjects.DATA_TEXT_WITH_EMBEDDINGS,

@@ -122,26 +122,27 @@ class VectorMemoryService(Service):
         # both wire forms (schema/frames), zero-churn: scalar metadata +
         # sentence texts + ONE [n, dim] row block — no per-sentence
         # dataclass, no per-float Python object
-        m = frames.decode_embeddings_lazy(msg.data, msg.headers)
-        now = current_timestamp_ms()
-        ids, payloads = [], []
-        for order, sentence in enumerate(m.sentences):
-            # content-derived id: durable redelivery (and a re-coalesced
-            # flush retry) overwrites the same point instead of duplicating
-            # it (reference mints random uuids, main.rs:142-177 — safe only
-            # at-most-once)
-            ids.append(deterministic_point_id(m.original_id, order))
-            # direct dict build — the 6 QdrantPointPayload wire fields;
-            # keep in lockstep with the schema dataclass (pinned by
-            # tests/test_store_wire_fixtures.py)
-            payloads.append({
-                "original_document_id": m.original_id,
-                "source_url": m.source_url,
-                "sentence_text": sentence,
-                "sentence_order": order,
-                "model_name": m.model_name,
-                "processed_at_ms": now,
-            })
+        with span("vector_memory.decode", msg.headers, cpu=True):
+            m = frames.decode_embeddings_lazy(msg.data, msg.headers)
+            now = current_timestamp_ms()
+            ids, payloads = [], []
+            for order, sentence in enumerate(m.sentences):
+                # content-derived id: durable redelivery (and a
+                # re-coalesced flush retry) overwrites the same point
+                # instead of duplicating it (reference mints random uuids,
+                # main.rs:142-177 — safe only at-most-once)
+                ids.append(deterministic_point_id(m.original_id, order))
+                # direct dict build — the 6 QdrantPointPayload wire fields;
+                # keep in lockstep with the schema dataclass (pinned by
+                # tests/test_store_wire_fixtures.py)
+                payloads.append({
+                    "original_document_id": m.original_id,
+                    "source_url": m.source_url,
+                    "sentence_text": sentence,
+                    "sentence_order": order,
+                    "model_name": m.model_name,
+                    "processed_at_ms": now,
+                })
         with span("vector_memory.upsert", msg.headers, points=len(ids)):
             if self._coalescer is not None:
                 # ack-after-flush: resolves once the coalesced store call

@@ -14,6 +14,10 @@ zero counters. Here:
   scrape time). All three kinds take optional `{label: value}` labels —
   rendered as JSON (api /api/metrics) and as Prometheus text exposition
   (api /metrics, obs/prometheus.py).
+- Who holds the interpreter: `span(..., cpu=True)` records the thread's CPU
+  time beside the wall time, `python_cpu_s` sums the interpreter's threads,
+  `start_loop_lag_probe` measures how long a ready continuation waits for the
+  event loop (docs/OBSERVABILITY.md "Wall against CPU under a GIL").
 
 Span-id semantics (the contract the trace tree depends on): the X-Span-Id
 header names the ACTIVE span — the one under which a message was published.
@@ -26,6 +30,7 @@ downstream publish links to it (services/base.py).
 
 from __future__ import annotations
 
+import asyncio
 import bisect
 import contextvars
 import functools
@@ -214,14 +219,25 @@ _annotations = _ProfilerAnnotations()
 
 
 @contextmanager
-def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
+def span(name: str, headers: Optional[Dict[str, str]] = None, *,
+         cpu: bool = False, **fields):
     """Timed span: `span.<name>.ms` histogram + a SpanRecord in the flight
     recorder + a structured log line at INFO + a profiler annotation
     (`_ProfilerAnnotations`). The parent is the span `headers` name, else the span
     open in this task or thread, else none (a new trace). Errors are
     accounted, not swallowed: status lands on the record (queryable via
     /api/traces) and `span.<name>.errors` increments before the exception
-    propagates."""
+    propagates.
+
+    `cpu=True` is for a synchronous section (one thread, no `await`
+    inside): the thread's CPU time over the body is also added to the
+    counter `span.<name>.cpu_ms_total` and put on the record's fields as
+    `cpu_ms`. A counter, since the thread clock may tick coarsely (10 ms on
+    the v5e's host), so only a sum over many spans means anything. wall -
+    cpu is the time the section did not hold the interpreter (waiting for
+    the GIL, in `fsync`, on the device). Closed on another thread than the
+    one that opened it, a span records no CPU number rather than a wrong
+    one."""
     t0 = time.perf_counter()
     start_s = time.time()
     outer = _open_span.get()
@@ -233,6 +249,8 @@ def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
     _annotations.opened(handle, name)
     _open_span.set(handle)
     status = "ok"
+    if cpu:
+        cpu_thread, cpu0 = threading.get_ident(), time.thread_time()
     try:
         yield handle
     except BaseException as e:
@@ -241,6 +259,9 @@ def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
         metrics.inc(f"span.{name}.errors")
         raise
     finally:
+        if cpu and threading.get_ident() == cpu_thread:
+            handle.fields["cpu_ms"] = (time.thread_time() - cpu0) * 1000
+            metrics.inc(f"span.{name}.cpu_ms_total", handle.fields["cpu_ms"])
         # set, not reset(token): a span closed from another context than
         # the one that opened it (a generator finalized elsewhere) must
         # not raise out of the finally
@@ -261,6 +282,61 @@ def span(name: str, headers: Optional[Dict[str, str]] = None, **fields):
                                  "duration_ms": round(dur_ms, 3),
                                  **handle.fields}, ensure_ascii=False,
                                 default=str))
+
+
+def _thread_cpu_clock(native_id: int) -> int:
+    """The clock id of a thread's CPU clock, from its kernel id: Linux's
+    encoding of CPUCLOCK_SCHED for one thread, what `pthread_getcpuclockid`
+    returns. That call reads through a `pthread_t`, which is undefined once
+    its thread has ended; a kernel id that is gone fails with EINVAL."""
+    return (~native_id << 3) | 6
+
+
+def python_cpu_s() -> Optional[float]:
+    """CPU seconds the interpreter's live threads have used (callback gauge
+    `host.python_cpu_s`): the threads `threading` knows, each by its own
+    CPU clock. The runtime's C++ threads are not among them, so a delta of
+    this is what Python threads cost, whichever of them ran the work; a
+    thread that has ended takes its seconds with it. None (the gauge
+    retires) where the platform has no such clocks."""
+    if not sys.platform.startswith("linux"):
+        return None
+    total = 0.0
+    for thread in threading.enumerate():
+        if thread.native_id is None:
+            continue
+        try:
+            total += time.clock_gettime(_thread_cpu_clock(thread.native_id))
+        except OSError:
+            continue  # ended between the enumeration and the read
+    return total
+
+
+# how often the lag probe's timer is due. Each firing wakes the loop and so
+# takes the interpreter from whichever thread holds it: every 10 ms cost
+# the host-paced ingest cell 0.5% of its rate (PERF.md section 6, PR 36)
+LOOP_LAG_PROBE_S = 0.05
+
+
+def start_loop_lag_probe() -> Callable[[], None]:
+    """Histogram `loop.lag_ms`: a timer on the running loop, due every
+    `LOOP_LAG_PROBE_S`, observes how late it fired. That is how long a
+    continuation that is ready waits for the event loop: what a flush's
+    resume, a handler's next step and a search's reply all pay while
+    something else holds the loop (or the interpreter). A bare timer, not a
+    task asleep: one loop iteration a sample. Returns what stops it."""
+    loop = asyncio.get_running_loop()
+
+    def tick(due: float) -> None:
+        nonlocal handle
+        now = loop.time()
+        metrics.observe("loop.lag_ms", max(0.0, now - due) * 1000)
+        handle = loop.call_later(LOOP_LAG_PROBE_S, tick,
+                                 now + LOOP_LAG_PROBE_S)
+
+    handle = loop.call_later(LOOP_LAG_PROBE_S, tick,
+                             loop.time() + LOOP_LAG_PROBE_S)
+    return lambda: handle.cancel()
 
 
 # default cumulative-bucket bounds for span-duration histograms, in ms

@@ -216,7 +216,7 @@ def test_sparse_mixer_and_its_block_sets_match_reference(
         at += n
         a_block += blocks
     causal = sum(n * (n + 1) // 2 for n in LENS)
-    assert np.asarray(counts).tolist() == [[attended, causal, 20]]
+    assert np.asarray(counts).tolist() == [[attended, causal]]
     assert attended < causal
     assert np.isinf(np.asarray(gap)[0, :, 100:120]).all()  # the dense one
 
@@ -243,7 +243,7 @@ def test_packed_rows_match_reference_and_each_passage_alone(
     assert got.shape == (1, 8, 64)
     assert _rel(np.asarray(got)[0, :3], rows).max() < F32_TOL
     assert (np.asarray(got)[0, 3:] == 0).all()  # slots that hold nothing
-    assert np.asarray(counts).shape == (1, 2, 3)
+    assert np.asarray(counts).shape == (1, 2, 2)
     alone = []
     for p in passages:
         row = np.zeros((1, 128), np.int32)
@@ -395,7 +395,7 @@ def test_engine_boots_the_checkpoint_embeds_and_counts(checkpoint, want):
     words = [f"w{i}" for i in range(400)]
     texts = [" ".join(rng.choice(words, n)) for n in (98, 18, 55, 150, 40)]
     before = {k: _snap(f"engine.sparse.{k}") for k in
-              ("keys_attended", "keys_causal", "dense_path_tokens")}
+              ("keys_attended", "keys_causal")}
     h0 = metrics.snapshot()["histograms"].get(
         'engine.sparse.kept_share{service="engine"}', {"count": 0})["count"]
     d0 = _snap("engine.embed.dispatches")
@@ -410,8 +410,6 @@ def test_engine_boots_the_checkpoint_embeds_and_counts(checkpoint, want):
         n * (n + 1) // 2 for n in lens)
     assert after["keys_attended"] - before["keys_attended"] == layers * sum(
         int(ys.keys_attended(n, MODEL).sum()) for n in lens)
-    assert (after["dense_path_tokens"] - before["dense_path_tokens"]
-            == layers * sum(n for n in lens if n <= 32))
     dispatches = _snap("engine.embed.dispatches") - d0
     h1 = metrics.snapshot()["histograms"][
         'engine.sparse.kept_share{service="engine"}']["count"]

@@ -54,6 +54,10 @@ def _sum(name: str) -> float:
     return sum(s["sum"] for _, s in metrics.histogram_summaries(name))
 
 
+def _counters() -> dict:
+    return metrics.snapshot()["counters"]
+
+
 def _host_events(trace_dir) -> dict:
     """{event name: [(start_ns, duration_ns, {stat: value})]} of the
     `symbiont.*` host events in the one trace under `trace_dir`."""
@@ -90,10 +94,28 @@ def store(engine, tmp_path_factory):
 
 # ------------------------------------------------- the profiler annotation
 
-def test_span_across_await_and_on_pool_thread_are_one_event_each(tmp_path):
+def _one_span(events: dict, name: str) -> tuple:
+    """(start_ns, end_ns, trace ids) of the one span `name` made: its
+    segments in order (a span the ticker found older than ROLL_S is closed
+    and opened again under the same name and trace id), from the first
+    one's start to the last one's end."""
+    segments = sorted(events[f"symbiont.t_ann.{name}"])
+    assert all(a[0] + a[1] <= b[0] for a, b in zip(segments, segments[1:]))
+    return (segments[0][0], segments[-1][0] + segments[-1][1],
+            {stats["trace_id"] for _, _, stats in segments})
+
+
+@pytest.mark.parametrize("roll_s", [None, 0.02])
+def test_span_across_await_and_on_pool_thread_are_one_event_each(
+        tmp_path, monkeypatch, roll_s):
     """Two spans of one request — one held across an `await` while another
     task's span opens and closes inside it, one on a pool thread — come
-    out as one host event each, covering the work, with the trace id."""
+    out as one span each, covering the work, with the trace id. On a loaded
+    machine the 0.05 s sleep can outlast ROLL_S and the ticker rolls the
+    annotation over: the segments of one name and trace id are one span
+    (`roll_s` 0.02 makes every run that machine)."""
+    if roll_s is not None:
+        monkeypatch.setattr(telemetry._annotations, "ROLL_S", roll_s)
     sleeps = {"held": 0.05, "other": 0.01, "pooled": 0.02}
     seen = {}
 
@@ -122,17 +144,16 @@ def test_span_across_await_and_on_pool_thread_are_one_event_each(tmp_path):
     finally:
         jax.profiler.stop_trace()
     events = _host_events(tmp_path)
+    got = {}
     for name, trace in (("held", "trace-held"), ("other", "trace-other"),
                         ("pooled", "trace-held")):
-        ((start, dur, stats),) = events[f"symbiont.t_ann.{name}"]
-        assert dur >= sleeps[name] * 1e9, (name, dur)
-        assert stats["trace_id"] == trace
-    (held,), (pooled_ev,) = (events["symbiont.t_ann.held"],
-                             events["symbiont.t_ann.pooled"])
+        start, end, traces = got[name] = _one_span(events, name)
+        assert end - start >= sleeps[name] * 1e9, (name, end - start)
+        assert traces == {trace}
     # the pool thread's span lies inside the span that caused it, on one
     # clock, and names it as its parent in the flight recorder
-    assert held[0] <= pooled_ev[0]
-    assert pooled_ev[0] + pooled_ev[1] <= held[0] + held[1]
+    assert got["held"][0] <= got["pooled"][0]
+    assert got["pooled"][1] <= got["held"][1]
     assert seen["pooled"].parent_id == seen["held"].span_id
     assert seen["pooled"].trace_id == "trace-held"
 
@@ -410,7 +431,11 @@ def test_a_fused_search_is_one_trace_with_every_stage_observed_once(
     assert {n: _count(n) - before[n] for n in names} == dict.fromkeys(names, 1)
     by_name = {r.name: r for r in trace_store.spans_for("trace-search")}
     assert set(by_name) == {"engine.query.search", "store.search_fused",
-                            "engine.qsearch"}
+                            "engine.qsearch", "engine.qsearch.tokenize",
+                            "engine.qsearch.dispatch"}
+    for stage in ("engine.qsearch.tokenize", "engine.qsearch.dispatch"):
+        assert (by_name[stage].parent_id
+                == by_name["engine.qsearch"].span_id)
     assert by_name["engine.query.search"].parent_id == "gateway-span"
     assert (by_name["store.search_fused"].parent_id
             == by_name["engine.query.search"].span_id)
@@ -432,6 +457,253 @@ def test_the_batched_encoder_observes_its_span_and_stages_once_per_call(
     out = engine.embed_texts(["one two", "three four five", "six"])
     assert out.shape == (3, 32)
     assert {n: _count(n) - before[n] for n in names} == dict.fromkeys(names, 1)
+
+
+# ------------------------------------- who holds the interpreter: CPU time
+
+def _busy(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_a_cpu_span_around_a_busy_loop_reads_about_its_wall_time():
+    """`cpu=True`: the thread's CPU time over the body, added to the
+    counter and put on the record. A busy loop holds the thread for (nearly) all of its
+    wall time; a descheduled attempt on a loaded machine is taken again."""
+    trace_store.clear()
+    for attempt in range(8):
+        before = metrics.get("span.t_ann.busy.cpu_ms_total")
+        wall0 = _sum("span.t_ann.busy.ms")
+        with span("t_ann.busy", {TRACE_HEADER: f"trace-busy-{attempt}"},
+                  cpu=True) as sp:
+            _busy(0.05)
+        cpu_ms = metrics.get("span.t_ann.busy.cpu_ms_total") - before
+        wall_ms = _sum("span.t_ann.busy.ms") - wall0
+        assert _count("span.t_ann.busy.cpu_ms") == 0  # no histogram of it
+        assert sp.fields["cpu_ms"] == pytest.approx(cpu_ms)
+        (rec,) = trace_store.spans_for(f"trace-busy-{attempt}")
+        assert rec.fields["cpu_ms"] == sp.fields["cpu_ms"]
+        assert 0.0 < cpu_ms <= wall_ms * 1.001
+        if cpu_ms >= 0.8 * wall_ms:
+            break
+    else:
+        pytest.fail(f"cpu {cpu_ms:.1f} ms of {wall_ms:.1f} ms wall, 8 times")
+
+
+def test_a_cpu_span_around_a_sleep_reads_next_to_nothing():
+    before = metrics.get("span.t_ann.asleep.cpu_ms_total")
+    with span("t_ann.asleep", cpu=True):
+        time.sleep(0.05)
+    assert metrics.get("span.t_ann.asleep.cpu_ms_total") - before < 5.0
+    assert _sum("span.t_ann.asleep.ms") >= 50.0
+
+
+def test_a_cpu_span_closed_on_another_thread_records_no_cpu_number():
+    """A thread's CPU clock says nothing about another thread's work: no
+    number rather than a wrong one. The wall time and the record stay."""
+    import threading
+
+    trace_store.clear()
+    before = _count("span.t_ann.moved.ms")
+    cm = span("t_ann.moved", {TRACE_HEADER: "trace-moved"}, cpu=True)
+    cm.__enter__()
+    closer = threading.Thread(target=cm.__exit__, args=(None, None, None))
+    closer.start()
+    closer.join(timeout=30)
+    assert not closer.is_alive()
+    assert "span.t_ann.moved.cpu_ms_total" not in _counters()
+    assert _count("span.t_ann.moved.ms") == before + 1
+    (rec,) = trace_store.spans_for("trace-moved")
+    assert "cpu_ms" not in rec.fields
+    # without cpu=True nothing of the kind is recorded
+    with span("t_ann.plain_cpu") as sp:
+        pass
+    assert "cpu_ms" not in sp.fields
+    assert "span.t_ann.plain_cpu.cpu_ms_total" not in _counters()
+
+
+def test_python_cpu_s_rises_by_a_pool_threads_busy_time():
+    """The gauge sums the CPU clocks of the interpreter's live threads,
+    whichever thread did the work."""
+    with ThreadPoolExecutor(1) as pool:
+        pool.submit(lambda: None).result()   # the thread exists
+        for _ in range(8):
+            cpu0, proc0 = telemetry.python_cpu_s(), time.process_time()
+            pool.submit(_busy, 0.1).result()
+            rose = telemetry.python_cpu_s() - cpu0
+            proc = time.process_time() - proc0
+            # the process's clock also counts threads the interpreter does
+            # not know (the runtime's pools), so it bounds from above
+            assert 0.0 < rose <= proc + 0.01
+            if 0.08 <= rose <= 0.15:
+                break
+        else:
+            pytest.fail(f"rose by {rose:.3f} s around 0.1 s of work, 8 times")
+
+
+# ------------------------------------- the stage spans, through the stack
+
+ENGINE = {"service": "engine"}
+STAGES_PER_PAGE = ("perception.extract", "preprocessing.split",
+                   "preprocessing.frame", "vector_memory.decode")
+STAGES_PER_STORE_FLUSH = ("store.ingest_rows", "store.wal_encode",
+                          "store.wal_sync")
+STAGES_PER_QUERY = ("engine.qsearch.tokenize", "engine.qsearch.dispatch")
+STAGES = (STAGES_PER_PAGE + STAGES_PER_STORE_FLUSH + STAGES_PER_QUERY
+          + ("engine.embed.tokenize", "engine.embed.pack",
+             "engine.embed.dispatch"))
+
+
+def _stack(engine, tmp_path, services, fetcher=None):
+    from symbiont_tpu.bus.inproc import InprocBus
+    from symbiont_tpu.config import (
+        GraphStoreConfig,
+        SymbiontConfig,
+    )
+    from symbiont_tpu.runner import SymbiontStack
+
+    cfg = SymbiontConfig(
+        vector_store=VectorStoreConfig(dim=32, data_dir=str(tmp_path / "vs"),
+                                       shard_capacity=4096),
+        graph_store=GraphStoreConfig(data_dir=str(tmp_path / "gs")))
+    cfg.runner.services = services
+    return SymbiontStack(cfg, bus=InprocBus(), engine=engine,
+                         fetcher=fetcher)
+
+
+async def _until(cond, what: str, timeout_s: float = 120.0) -> None:
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        await asyncio.sleep(0.02)
+
+
+def test_every_stage_span_is_observed_as_often_as_its_stage_runs(
+        engine, tmp_path):
+    """Pages through perception -> preprocessing -> the embed batcher -> the
+    engine -> vector_memory -> the coalescer -> the store, then fused
+    queries through EngineService: each of the twelve stage spans once per
+    page, store flush, embed call, dispatch or query, no more; all with a
+    CPU reading; the store's three on the flush's trace, as its children."""
+    from symbiont_tpu import subjects
+
+    pages, queries = 5, 3
+    html = {f"http://fake/{i}": "<html><body><main>" + "".join(
+        f"<p>Page {i} sentence {j} about topic {j % 3}.</p>"
+        for j in range(3)) + "</main></body></html>" for i in range(pages)}
+    stack = _stack(engine, tmp_path,
+                   "perception,preprocessing,vector_memory,engine",
+                   fetcher=html.__getitem__)
+    names = [f"span.{n}.ms" for n in STAGES] + [
+        "span.engine.embed.ms", "span.vector_memory.flush.ms"]
+
+    async def scenario():
+        await stack.start()
+        try:
+            # the fused warm-up runs queries of its own: let it finish
+            await _until(lambda: metrics.get(
+                "engine.fused_warmups", labels={"result": "ok"}) >= 1,
+                "the fused warm-up")
+            trace_store.clear()
+            before = {n: _count(n) for n in names}
+            dispatches = metrics.get("engine.embed.dispatches", labels=ENGINE)
+            for url in html:
+                await stack.bus.publish(
+                    subjects.TASKS_PERCEIVE_URL,
+                    json.dumps({"url": url}).encode())
+            await _until(lambda: stack.vector_store.count() >= 3 * pages,
+                         "the pages' rows")
+            await _until(lambda: _count("span.vector_memory.handle.ms") >= pages
+                         and not stack.services[2]._coalescer._pending,
+                         "the upsert handlers")
+            for q in range(queries):
+                reply = await stack.bus.request(
+                    subjects.ENGINE_QUERY_SEARCH, json.dumps(
+                        {"text": f"topic {q}", "top_k": 3}).encode(), 60.0)
+                assert len(json.loads(reply.data)["hits"]) == 3
+            got = {n: _count(n) - before[n] for n in names}
+            return got, metrics.get("engine.embed.dispatches",
+                                    labels=ENGINE) - dispatches
+        finally:
+            await stack.stop()
+
+    got, dispatches = asyncio.run(scenario())
+    embeds, flushes = (got["span.engine.embed.ms"],
+                       got["span.vector_memory.flush.ms"])
+    assert embeds >= 1 and flushes >= 1 and dispatches >= embeds
+    want = {**dict.fromkeys(STAGES_PER_PAGE, pages),
+            **dict.fromkeys(STAGES_PER_STORE_FLUSH, flushes),
+            **dict.fromkeys(STAGES_PER_QUERY, queries),
+            "engine.embed.tokenize": embeds,
+            # once round the plan of a call, once round each dispatch's rows
+            "engine.embed.pack": embeds + dispatches,
+            "engine.embed.dispatch": dispatches}
+    assert {n: got[f"span.{n}.ms"] for n in STAGES} == want
+    # each with a CPU reading: summed in a counter, and on every record
+    assert all(f"span.{n}.cpu_ms_total" in _counters() for n in STAGES)
+    # one ingest trace shows the store's stages under the flush it rode
+    by_id = {r.span_id: r for spans in
+             trace_store.spans_by_trace().values() for r in spans}
+    staged = [r for r in by_id.values() if r.name in STAGES]
+    assert len(staged) == sum(want.values())
+    assert all("cpu_ms" in r.fields for r in staged)
+    store_spans = [r for r in by_id.values()
+                   if r.name in STAGES_PER_STORE_FLUSH]
+    assert len(store_spans) == 3 * flushes
+    for r in store_spans:
+        parent = by_id[r.parent_id]
+        assert parent.name == "vector_memory.flush"
+        assert parent.trace_id == r.trace_id and "cpu_ms" in r.fields
+    # the engine's stages under the embed call, the handlers' under theirs
+    for r in by_id.values():
+        if r.name.startswith("engine.embed."):
+            assert by_id[r.parent_id].name == "engine.embed"
+        elif r.name in STAGES_PER_PAGE:
+            assert by_id[r.parent_id].name == (
+                r.name.split(".")[0] + ".handle")
+
+
+# --------------------------------------------- the event-loop lag probe
+
+def test_the_lag_probe_samples_the_loop_sees_a_block_and_stops(
+        engine, tmp_path):
+    """A sample every `LOOP_LAG_PROBE_S` while the stack is up; a handler
+    that blocks the loop for 50 ms past the timer's next firing shows as
+    >= 40 ms of lag (a block reads as what it overran a firing by); after `stop()` the timer
+    is gone and nothing more is observed."""
+    stack = _stack(engine, tmp_path, "preprocessing")
+
+    async def scenario():
+        await stack.start()
+        try:
+            assert "host.python_cpu_s" in metrics.snapshot()["gauges"]
+            await asyncio.sleep(0.1)       # the probe's first firings
+            n0, t0 = _count("loop.lag_ms"), time.monotonic()
+            await asyncio.sleep(1.0)
+            rate = (_count("loop.lag_ms") - n0) / (time.monotonic() - t0)
+            lag0 = _sum("loop.lag_ms")
+            # a handler that blocks the loop
+            time.sleep(telemetry.LOOP_LAG_PROBE_S + 0.05)
+            await asyncio.sleep(2.5 * telemetry.LOOP_LAG_PROBE_S)
+            return rate, _sum("loop.lag_ms") - lag0
+        finally:
+            await stack.stop()
+
+    async def after():
+        n = _count("loop.lag_ms")
+        await asyncio.sleep(3 * telemetry.LOOP_LAG_PROBE_S)
+        return _count("loop.lag_ms") - n
+
+    rate, blocked_ms = asyncio.run(scenario())
+    # a timer overshoots a little, more on a loaded machine: never more
+    # often than it is due, and not a fraction of that either
+    due = 1.0 / telemetry.LOOP_LAG_PROBE_S
+    assert 0.3 * due <= rate <= 1.005 * due, rate
+    # the firing the block overran
+    assert blocked_ms >= 40.0, blocked_ms
+    assert stack._stop_lag_probe is None
+    assert asyncio.run(after()) == 0
 
 
 # ------------------------------------------------------ named scopes
