@@ -26,7 +26,8 @@ over real HTTP/SSE:
 then asserts from the program's OWN counters that no answer came from a
 host-side fallback, checks one bf16-vs-f32 numeric anchor, compiles the
 Pallas flash-attention kernels forward and backward at two real shapes
-against the dense reference, and checks that `block_until_ready` is an
+and its packed-rows form (segment mask, RoPE inside) at the looped
+embedder's, against the dense reference, and checks that `block_until_ready` is an
 honest completion barrier.
 
 Encoder: the default EngineConfig (mpnet-base geometry 768x12x12x3072, bf16,
@@ -88,6 +89,8 @@ FULL = dict(
     # (B, q heads, kv heads, S, D, causal, padded): encoder + decoder prefill
     flash_shapes=[(8, 12, 12, 512, 64, False, True),
                   (2, 32, 4, 1024, 64, True, False)],
+    # (B, heads, L, D, a row's chunks): ouro-2.6b-embed's [8, 512] program
+    packed_shapes=[(8, 16, 512, 128, (200, 180, 100))],
     barrier=(2048, 200),  # matmul side, chain length
 )
 TOY = dict(
@@ -105,6 +108,7 @@ TOY = dict(
          "SYMBIONT_LM_NEW_TOKEN_BUCKETS": "[16, 64]"},
     flash_shapes=[(2, 2, 2, 64, 16, False, True),
                   (1, 4, 2, 64, 16, True, False)],
+    packed_shapes=[(2, 2, 128, 128, (50, 40, 20))],
     barrier=(256, 50),
 )
 
@@ -752,6 +756,55 @@ def flash_kernels(shapes: list, platform: str) -> list:
     return out
 
 
+def packed_kernels(shapes: list, platform: str) -> list:
+    """§7, the packed rows' form: `packed_attention` (q, k, v in the
+    projections' layout, causal inside each of a row's chunks, RoPE from the
+    chunk's start inside the kernel) against the dense reference handed the
+    same mask and `layers.rope`'s operands. Compiled on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from symbiont_tpu.models.bert import Segments
+    from symbiont_tpu.models.layers import rope, rope_tables
+    from symbiont_tpu.ops.flash_attention import (
+        _dense_reference,
+        packed_attention,
+    )
+
+    interpret = False if platform == "tpu" else None
+    out = []
+    for (B, NH, L, D, chunks) in shapes:
+        q, k, v = (jax.random.normal(key, (B, L, NH * D), jnp.bfloat16)
+                   for key in jax.random.split(jax.random.key(L + NH), 3))
+        # every row the same chunks, rolled: boundaries fall everywhere
+        lengths = np.stack([np.roll(chunks, r) for r in range(B)])
+        seg = Segments.of_lengths(jnp.asarray(lengths, jnp.int32), L)
+        got = jax.jit(lambda q, k, v: packed_attention(
+            q, k, v, seg.index, NH, rope=rope_tables(seg.position, D, 1e6),
+            interpret=interpret))(q, k, v)
+
+        def heads(t, turn):
+            t = t.reshape(B, L, NH, D)
+            return (rope(t, seg.position, 1e6) if turn else t).transpose(
+                0, 2, 1, 3)
+
+        want = _dense_reference(heads(q, True), heads(k, True),
+                                heads(v, False), jnp.zeros((B, L)), True,
+                                1.0 / float(np.sqrt(D)), seg.index)[0]
+        a = np.asarray(got.astype(jnp.float32))
+        b = np.asarray(want.transpose(0, 2, 1, 3).reshape(B, L, NH * D))
+        row = {"shape": f"B{B} h{NH} L{L} D{D} chunks {list(chunks)} packed",
+               "compiled": interpret is False}
+        check(np.isfinite(a).all(), f"packed out not finite: {row}")
+        err = float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
+        check(err <= FLASH_TOL, f"packed out rel-to-max error {err:.4f} > "
+                                f"{FLASH_TOL} at {row['shape']}")
+        row["out_err"] = round(err, 5)
+        out.append(row)
+    return out
+
+
 def barrier_check(side: int, length: int) -> dict:
     """Is block_until_ready an honest completion barrier? Three walls of
     the same chain of `length` [side, side] matmuls: enqueue only,
@@ -853,7 +906,9 @@ def main(argv=None) -> int:
 
     asyncio.run(drive_stack(sizes, args, out, report))
     phase("flash kernels")
-    report["flash"] = flash_kernels(sizes["flash_shapes"], info.platform)
+    report["flash"] = (flash_kernels(sizes["flash_shapes"], info.platform)
+                       + packed_kernels(sizes["packed_shapes"],
+                                        info.platform))
     from symbiont_tpu.utils.telemetry import metrics
 
     flash_fb = {k: v for k, v in metrics.snapshot()["counters"].items()

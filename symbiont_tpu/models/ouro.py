@@ -66,7 +66,8 @@ import jax.numpy as jnp
 
 from symbiont_tpu.models import quant
 from symbiont_tpu.models.bert import Segments, pool_segments
-from symbiont_tpu.models.layers import rmsnorm, rope, swiglu
+from symbiont_tpu.models.layers import rmsnorm, rope, rope_tables, swiglu
+from symbiont_tpu.utils.telemetry import metrics
 
 Params = Any
 
@@ -144,19 +145,41 @@ class OuroConfig:
 def attention(p: Params, x: jax.Array, segments: Segments,
               cfg: OuroConfig) -> jax.Array:
     """x [B, L, H] (normed) -> [B, L, H]: causal softmax attention inside
-    each chunk of the packed rows, RoPE counted from the chunk's start."""
+    each chunk of the packed rows, RoPE counted from the chunk's start.
+
+    Scores, mask, softmax and context are ONE Pallas kernel
+    (ops/flash_attention.py `packed_attention`: the `[B, heads, L, L]`
+    float32 scores never leave the chip) where the shapes tile, i.e. a head
+    is whole 128-lane column blocks and a row whole 128-token blocks; the
+    einsum form everywhere else (toy widths, an 8-token row). Both compute
+    what the configuration states: operands in `x.dtype`, float32 scores,
+    softmax and accumulation. `attn.packed{path}` says which, once per
+    traced program."""
     B, L, _ = x.shape
     nh, d = cfg.num_heads, cfg.head_dim
     q, k, v = (quant.mm(x, p[n]["kernel"]).reshape(B, L, nh, d)
                for n in "qkv")
-    q = rope(q, segments.position, cfg.rope_theta)
-    k = rope(k, segments.position, cfg.rope_theta)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-    keep = jnp.tril(jnp.ones((L, L), bool))[None, None] & segments.same[:, None]
-    scores = jnp.where(keep, scores / math.sqrt(d), -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    fused = d % 128 == 0 and L % 128 == 0
+    metrics.inc("attn.packed",
+                labels={"path": "flash_segments" if fused else "dense"})
+    if fused:
+        # imported here: pallas costs every process that loads a family
+        # table over a second of its boot
+        from symbiont_tpu.ops.flash_attention import packed_attention
+
+        ctx = packed_attention(
+            *(t.reshape(B, L, nh * d) for t in (q, k, v)), segments.index, nh,
+            rope=rope_tables(segments.position, d, cfg.rope_theta))
+    else:
+        q = rope(q, segments.position, cfg.rope_theta)
+        k = rope(k, segments.position, cfg.rope_theta)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+        keep = (jnp.tril(jnp.ones((L, L), bool))[None, None]
+                & segments.same[:, None])
+        scores = jnp.where(keep, scores / math.sqrt(d), -1e9)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     return quant.mm(ctx.reshape(B, L, nh * d), p["o"]["kernel"])
 
 

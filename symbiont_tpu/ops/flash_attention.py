@@ -33,6 +33,15 @@ Design:
 - fallback: shapes the kernel can't tile (non-divisible or tiny S) route to
   the same dense reference implementation, so callers never need shape
   special-cases.
+- packed rows (`packed_attention`, forward only; the looped embedder's
+  attention, models/ouro.py): a row holds several segments laid end to end,
+  the mask comes from per-token int32 segment ids and causality (keep
+  (i, j) iff `id[i] == id[j]` and `j <= i`), RoPE is applied to q and k
+  inside the kernel, and q, k, v and the context keep the projections' own
+  `[B, L, heads * D]` layout, a head being a 128-lane column block that a
+  BlockSpec picks: nothing is transposed or re-laid on either side. The
+  same arithmetic; its own kernel body (`_packed_kernel`), because what
+  sets its pace on the chip is how a block is walked, not what is computed.
 - every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
   line and one `flash.fallback{path}` bump per traced shape — the Pallas interpreter on a CPU backend, the dense route for
   untileable shapes, the dense-recompute GQA backward. `chip_smoke.py`
@@ -165,6 +174,133 @@ def _kernel(bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l)
 
 
+def _rotate(x, cos, sin):
+    """RoPE on a [rows, D] block (half-split pairing, as `layers.rope`):
+    `x1 cos - x2 sin | x2 cos + x1 sin` in float32, cast back. `cos` and
+    `sin` are [rows, D] float32 as `layers.rope_tables` lays them (cos twice over,
+    sin with its first half negated), so a dimension's partner is one lane
+    rotation by D/2 away."""
+    xf = x.astype(jnp.float32)
+    turned = pltpu.roll(xf, x.shape[-1] // 2, 1)
+    return (xf * cos + turned * sin).astype(x.dtype)
+
+
+# How the packed kernel walks a block (my chip runs, PR 37, one [8, 512]
+# application of 16 heads of 128; PERF.md §6 has the table): a whole
+# [512, 512] float32 score block is 256 vector registers, every elementwise
+# pass over it a round trip through VMEM, and nothing of the next product
+# can start before the last pass ends (320 us); query rows 256 at a time,
+# each tile reading only the keys up to its own last row, 206 us; two heads
+# a grid step, whose products and passes the scheduler can interleave,
+# 171 us (128 rows: 198; four heads: 178; a copy of the operands alone: 66).
+_PACKED_ROWS = 256
+_PACKED_HEADS = 2
+
+
+def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
+    """One (batch, head group, q block, kv block) step over packed rows,
+    forward only: `_kernel`'s arithmetic with the mask from per-token
+    segment ids and causality and, with `rotary`, RoPE on q and k first. Query rows go
+    `_PACKED_ROWS` at a time, and on the diagonal block each tile reads
+    only the keys up to its own last row: the triangle above it is neither
+    multiplied nor exponentiated.
+
+    A row that is ONE block (no scratch refs) is the fast form: each tile's
+    softmax is whole, so its context is written straight out, `heads` heads
+    a step. Longer rows stream kv blocks through `_kernel`'s running max,
+    normaliser and accumulator, one head a step."""
+    qid_ref, kid_ref, *refs = refs
+    if rotary:
+        cosq_ref, sinq_ref, cosk_ref, sink_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, *scratch = refs
+    sub = min(block, _PACKED_ROWS)
+    D = q_ref.shape[2] // heads
+
+    def _q(r0: int, cols):
+        rows = pl.ds(r0, sub)
+        q = q_ref[0, rows, cols]  # [sub, D]
+        return (_rotate(q, cosq_ref[0, rows, :], sinq_ref[0, rows, :])
+                if rotary else q)
+
+    def _k(cols):
+        k = k_ref[0, :, cols]  # [block, D]
+        return _rotate(k, cosk_ref[0], sink_ref[0]) if rotary else k
+
+    def _keys(r0: int) -> int:
+        """How many of a diagonal block's keys the tile at `r0` can see, in
+        whole 128-key tiles (the scores' lane dimension)."""
+        return min(block, -(-(r0 + sub) // 128) * 128)
+
+    def _scores(q, k, r0: int, diagonal: bool):
+        """[sub, keys] float32: scaled, and -1e9 where the key is in another
+        segment or (on the diagonal block) after the query."""
+        keys = k.shape[0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=_dot_prec(q, k)) * scale
+        # a query's id [sub, 1] against a key's [1, keys]: padding carries
+        # an id of its own, so no row of the softmax is empty
+        keep = qid_ref[0, pl.ds(r0, sub), :] == kid_ref[0, :, :keys]
+        if diagonal:  # both blocks start at the same token
+            keep &= (jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+                     <= r0 + jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0))
+        return jnp.where(keep, s, _MASK_NEG)
+
+    def _context(p, v):
+        return jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_dot_prec(v))
+
+    if not scratch:
+        for h in range(heads):
+            cols = pl.ds(h * D, D)
+            k, v = _k(cols), v_ref[0, :, cols]
+            for r0 in range(0, block, sub):
+                s = _scores(_q(r0, cols), k[:_keys(r0)], r0, True)
+                p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                l = jnp.sum(p, axis=-1, keepdims=True)  # >= 1: the max's own
+                o_ref[0, pl.ds(r0, sub), cols] = (
+                    _context(p, v[:_keys(r0)]) * pl.reciprocal(l)
+                ).astype(o_ref.dtype)
+        return
+
+    m_scr, l_scr, acc_scr = scratch
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, _ACC_NEG, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def _step(diagonal: bool):
+        k, v = _k(slice(None)), v_ref[0]
+        for r0 in range(0, block, sub):
+            rows = pl.ds(r0, sub)
+            keys = _keys(r0) if diagonal else block
+            s = _scores(_q(r0, slice(None)), k[:keys], r0, diagonal)
+            m_prev = m_scr[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[rows, :1] + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+            acc_scr[rows, :] = acc_scr[rows, :] * alpha + _context(p, v[:keys])
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (sub, m_scr.shape[1]))
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (sub, l_scr.shape[1]))
+
+    # blocks above the diagonal are skipped, as `_kernel` skips them
+    pl.when(ki < qi)(lambda: _step(False))
+    pl.when(ki == qi)(lambda: _step(True))
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[:] * pl.reciprocal(l_scr[:, :1])
+                    ).astype(o_ref.dtype)
+
+
 def _flash_call(q, k, v, bias, causal, scale, block_q, block_k, interpret):
     B, NH, Sq, D = q.shape
     NKV, Sk = k.shape[1], k.shape[2]
@@ -208,8 +344,61 @@ def _flash_call(q, k, v, bias, causal, scale, block_q, block_k, interpret):
     )(bias.reshape(B, Sk // bk, 1, bk), q, k, v)
 
 
-def _dense_reference(q, k, v, bias, causal, scale):
-    """f32 dense attention — fallback path and backward-pass recompute."""
+def _packed_call(q, k, v, ids, rope, num_heads, block, interpret):
+    """`_packed_kernel` over packed rows in the projections' own layout: q,
+    k, v [B, L, heads * D], head h the column block h (D a multiple of the
+    128 lanes), the context written the same way, so nothing is re-laid on
+    either side. `ids` [B, L] int32 goes in twice: a column [block, 1] of
+    the queries' ids and a row [1, block] of the keys'; `rope` = (cos, sin)
+    [B, L, D] float32 likewise, by query rows and by key rows (a block whose
+    index the step before had is not fetched again: the tables move once a
+    row, not once a head)."""
+    B, L, HD = q.shape
+    D = HD // num_heads
+    single = L == block  # one block a row: no state between steps
+    heads = _PACKED_HEADS if single and num_heads % _PACKED_HEADS == 0 else 1
+    kernel = functools.partial(_packed_kernel, scale=1.0 / math.sqrt(D),
+                               block=block, heads=heads,
+                               rotary=rope is not None)
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    qspec = pl.BlockSpec((1, block, heads * D),
+                         lambda b, h, qi, ki: (b, qi, h))
+    kspec = pl.BlockSpec((1, block, heads * D),
+                         lambda b, h, qi, ki: (b, ki, h))
+    tables, table_specs = (), []
+    if rope is not None:
+        tables = (*rope, *rope)
+        table_specs = 2 * [pl.BlockSpec((1, block, D),
+                                        lambda b, h, qi, ki: (b, qi, 0))]
+        table_specs += 2 * [pl.BlockSpec((1, block, D),
+                                         lambda b, h, qi, ki: (b, ki, 0))]
+    return pl.pallas_call(
+        kernel,
+        grid=(B, num_heads // heads, L // block, L // block),
+        in_specs=[
+            pl.BlockSpec((1, block, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block), lambda b, h, qi, ki: (b, 0, ki)),
+            *table_specs, qspec, kspec, kspec,
+        ],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[] if single else [
+            pltpu.VMEM((block, 128), jnp.float32),  # running max (lane-replicated)
+            pltpu.VMEM((block, 128), jnp.float32),  # running normalizer
+            pltpu.VMEM((block, D), jnp.float32),    # output accumulator
+        ],
+        interpret=interpret,
+        **kwargs,
+    )(ids[:, :, None], ids[:, None, :], *tables, q, k, v)
+
+
+def _dense_reference(q, k, v, bias, causal, scale, segment_ids=None):
+    """f32 dense attention — fallback path and backward-pass recompute.
+    `segment_ids` [B, S] int32 (self-attention): a query sees the keys of
+    its own segment only."""
     NH, NKV = q.shape[1], k.shape[1]
     if NH != NKV:
         k = jnp.repeat(k, NH // NKV, axis=1)
@@ -217,6 +406,9 @@ def _dense_reference(q, k, v, bias, causal, scale):
     qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
     s = s + bias[:, None, None, :]
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]
+        s = jnp.where(same[:, None], s, _MASK_NEG)
     if causal:
         Sq, Sk = q.shape[2], k.shape[2]
         qpos = jnp.arange(Sq)[:, None]
@@ -464,6 +656,25 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _interpret(interpret: bool | None, q, k) -> bool:
+    """`interpret=None` resolved by the backend, and the two errors."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"flash_attention: backend {backend!r} is neither 'tpu' "
+            "(compiled kernel) nor 'cpu' (interpreter)")
+    if interpret is None:
+        interpret = backend == "cpu"
+    if interpret and backend == "tpu":
+        raise ValueError(
+            "flash_attention(interpret=True) on a tpu backend: the Pallas "
+            "interpreter must never stand in for the compiled kernel on "
+            "the chip")
+    if interpret:
+        _announce("interpreter", q, k)
+    return interpret
+
+
 def flash_attention(
     q: jax.Array,  # [B, NH, Sq, D]
     k: jax.Array,  # [B, NKV, Sk, D] — NKV divides NH (GQA)
@@ -489,20 +700,7 @@ def flash_attention(
         raise ValueError(f"q heads {NH} not a multiple of kv heads {NKV}")
     if v.shape != k.shape:
         raise ValueError(f"k/v shape mismatch: {k.shape} vs {v.shape}")
-    backend = jax.default_backend()
-    if backend not in ("tpu", "cpu"):
-        raise RuntimeError(
-            f"flash_attention: backend {backend!r} is neither 'tpu' "
-            "(compiled kernel) nor 'cpu' (interpreter)")
-    if interpret is None:
-        interpret = backend == "cpu"
-    if interpret and backend == "tpu":
-        raise ValueError(
-            "flash_attention(interpret=True) on a tpu backend: the Pallas "
-            "interpreter must never stand in for the compiled kernel on "
-            "the chip")
-    if interpret:
-        _announce("interpreter", q, k)
+    interpret = _interpret(interpret, q, k)
     if kv_bias is None:
         kv_bias = jnp.zeros((B, Sk), jnp.float32)
     kv_bias = kv_bias.astype(jnp.float32)
@@ -510,3 +708,46 @@ def flash_attention(
         scale = 1.0 / math.sqrt(D)
     return _flash(q, k, v, kv_bias, causal, float(scale),
                   _pick_block(Sq, block_q), _pick_block(Sk, block_k), interpret)
+
+
+def packed_attention(
+    q: jax.Array,  # [B, L, num_heads * D], as a projection produces it
+    k: jax.Array,  # [B, L, num_heads * D]
+    v: jax.Array,  # [B, L, num_heads * D]
+    segment_ids: jax.Array,  # [B, L] int32: the token's segment in its row
+    num_heads: int,
+    rope: tuple[jax.Array, jax.Array] | None = None,  # `layers.rope_tables`
+    block: int = 512,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Causal attention inside the segments of packed rows, forward only,
+    -> [B, L, num_heads * D] in q.dtype: token i sees token j iff
+    `segment_ids[b, i] == segment_ids[b, j]` and `j <= i`; scores scaled by
+    1 / sqrt(D). Padding carries an id no segment has, so it keeps itself company and
+    no softmax row is empty. With `rope`, q and k are turned inside the
+    kernel first (float32, cast back to their dtype, as `layers.rope` does):
+    done outside, the turned q and k are two more [B, L, heads * D] arrays
+    written and read per call, in a layout the kernel cannot take.
+
+    `flash_attention`'s streaming softmax with the same arithmetic
+    (operands in the input dtype, float32 scores, softmax statistics and
+    accumulator) and the same `interpret` rule, over square blocks of the
+    largest power of two up to `block` that divides L; it reads and writes
+    the projections' own layout, a head being a D-wide column block, so D
+    and L must be multiples of 128."""
+    B, L, HD = q.shape
+    D, rem = divmod(HD, num_heads)
+    block = _pick_block(L, block)
+    if rem or D % 128 or block < 128:
+        raise ValueError(
+            f"packed_attention: q{tuple(q.shape)} with {num_heads} heads "
+            "does not tile (head_dim and L must be multiples of 128)")
+    shapes = [t.shape for t in (k, v, *(rope or ()))]
+    if (shapes != 2 * [q.shape] + (len(shapes) - 2) * [(B, L, D)]
+            or segment_ids.shape != (B, L)):
+        raise ValueError(
+            f"packed_attention: shapes q{tuple(q.shape)}, k, v and the rope "
+            f"tables {shapes}, ids{tuple(segment_ids.shape)}")
+    return _packed_call(q, k, v, segment_ids.astype(jnp.int32), rope,
+                        num_heads, block, _interpret(interpret, q, k))
+

@@ -2,13 +2,20 @@
 same kernel code path that compiles on TPU)."""
 
 import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from symbiont_tpu.ops.flash_attention import _dense_reference, flash_attention
+from symbiont_tpu.models.bert import Segments
+from symbiont_tpu.models.layers import rope, rope_tables
+from symbiont_tpu.ops.flash_attention import (
+    _dense_reference,
+    flash_attention,
+    packed_attention,
+)
 
 
 def _rand_qkv(key, B, NH, NKV, Sq, Sk, D, dtype=jnp.float32):
@@ -210,3 +217,92 @@ def test_gqa_backward_matches_dense():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------ segments of packed rows
+
+SEGMENT_CASES = {  # chunk lengths of the two rows' first 256 tokens
+    "a_boundary_inside_a_block": [(100, 156), (201, 55)],
+    "a_boundary_on_a_block_boundary": [(128, 128), (128, 100)],
+    "one_chunk_a_row": [(256,), (256,)],
+    "a_padded_tail": [(90, 70), (130,)],
+    "eight_short_chunks": [(24,) * 8, (31, 9, 40, 17, 33, 8, 25, 29)],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rotary, block, L", [
+    (False, 128, 256), (True, 128, 256), (True, 512, 512), (True, 512, 1024)],
+    ids=["plain-2x2_blocks", "rope-2x2_blocks", "rope-one_block",
+         "rope-2x2_blocks_of_512"])
+@pytest.mark.parametrize("case", list(SEGMENT_CASES))
+def test_packed_rows_match_dense_with_the_same_mask(case, rotary, block, L,
+                                                    dtype):
+    """`packed_attention` (q, k, v `[B, L, heads * D]`, a head a column
+    block; the mask from per-token segment ids, causal inside a segment;
+    RoPE from the segment's start inside the kernel) against the dense
+    reference handed the same mask on `[B, heads, L, D]` operands that
+    `layers.rope` turned: float32 to rounding, bfloat16 to its own step.
+    A row of 2 x 2 blocks streams (the block above the diagonal skipped,
+    the one below it whole, two on it); a row of one block takes the direct
+    form, two heads a step. A 512-token block goes 256 query rows at a time
+    and its first tile reads the first 256 keys only. Tokens past the
+    chunks are padding."""
+    B, H, D = 2, 2, 128
+    lengths = np.zeros((B, 8), np.int32)
+    for r, row in enumerate(SEGMENT_CASES[case]):
+        lengths[r, :len(row)] = row
+    seg = Segments.of_lengths(jnp.asarray(lengths), L)
+    q, k, v = (jax.random.normal(key, (B, L, H * D), dtype)
+               for key in jax.random.split(jax.random.key(9), 3))
+    got = packed_attention(
+        q, k, v, seg.index, H, block=block,
+        rope=rope_tables(seg.position, D, 1e4) if rotary else None)
+    assert got.shape == (B, L, H * D) and got.dtype == dtype
+
+    def heads(t, turn):
+        t = t.reshape(B, L, H, D)
+        if turn and rotary:
+            t = rope(t, seg.position, 1e4)
+        return t.transpose(0, 2, 1, 3)
+
+    want, _ = _dense_reference(heads(q, True), heads(k, True),
+                               heads(v, False), jnp.zeros((B, L)), True,
+                               1 / np.sqrt(D), segment_ids=seg.index)
+    want = np.asarray(want.transpose(0, 2, 1, 3).reshape(B, L, H * D))
+    tol = 2e-5 if dtype == jnp.float32 else 0.03
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol,
+                               atol=tol)
+
+
+def test_packed_attention_refuses_what_does_not_tile():
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    ids = jnp.zeros((1, 256), jnp.int32)
+    with pytest.raises(ValueError, match="does not tile"):
+        packed_attention(q, q, q, ids, 2)  # head_dim 64
+    with pytest.raises(ValueError, match="does not tile"):
+        packed_attention(q[:, :200], q[:, :200], q[:, :200], ids[:, :200], 1)
+    with pytest.raises(ValueError, match="shapes"):
+        packed_attention(q, q, q, ids[:, :128], 1)
+
+
+@pytest.mark.parametrize("causal, text_sha256", [
+    (False, "e35e666da375c1ed"), (True, "f1527b70b21f98b0")])
+def test_without_segments_the_call_lowers_to_the_text_it_had(causal,
+                                                             text_sha256):
+    """The segment mask lives in a second kernel (`packed_attention`); a
+    `flash_attention` call traces what it traced before it: the lowered
+    text at a toy shape (GQA, a per-key bias, unequal blocks) hashes to
+    what commit e2bc2c8 lowers to under jax 0.9.0. Another jax writes another text: take the hashes again from that
+    commit (`jax.jit(f).lower(...).as_text()`, as below) before trusting a
+    difference."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"the pinned text is jax 0.9.0's, not {jax.__version__}'s")
+    q = jax.ShapeDtypeStruct((2, 4, 128, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 2, 128, 64), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+    text = jax.jit(lambda q, k, v, b: flash_attention(
+        q, k, v, kv_bias=b, causal=causal, block_q=64, block_k=32)).lower(
+            q, k, k, bias).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == text_sha256

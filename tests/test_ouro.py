@@ -98,10 +98,11 @@ def want(chunks):
     return rows, by_chunk
 
 
-def _packed(chunks, rows):
-    """ids [B, L] and lengths [B, S] with `rows` = lists of chunk indices."""
+def _packed(chunks, rows, length=L):
+    """ids [B, length] and lengths [B, S] with `rows` = lists of chunk
+    indices."""
     S = 16
-    ids = np.zeros((len(rows), L), np.int32)
+    ids = np.zeros((len(rows), length), np.int32)
     lengths = np.zeros((len(rows), S), np.int32)
     for r, row in enumerate(rows):
         toks = np.concatenate([chunks[i] for i in row])
@@ -218,6 +219,82 @@ def test_the_scanned_stack_equals_the_block_unrolled(checkpoint, chunks):
             layer = jax.tree.map(lambda a: a[i], params32["layers"])
             unrolled = ouro.block(layer, unrolled, seg, cfg32)
     assert _rel(scanned, unrolled).max() < 1e-6
+
+
+# ------------------------------------------------ the kernel's route
+
+# a shape that tiles: a head is one 128-lane column block, a row two
+# 128-token blocks (the interpreter runs the kernel on the CPU)
+WIDE = {**MODEL, "hidden_size": 256, "num_attention_heads": 2,
+        "num_key_value_heads": 2, "head_dim": 128, "num_hidden_layers": 2,
+        "layer_types": ["full_attention"] * 2, "total_ut_steps": 2}
+WIDE_ROWS = [[0, 3, 1], [2]]  # 100 + 7 + 20 tokens (padded to the row), 57
+
+
+@pytest.fixture(scope="module")
+def wide(tmp_path_factory, chunks):
+    """(params, cfg) in float32 of the tileable toy model, the reference's
+    rows for the four chunks."""
+    out = tmp_path_factory.mktemp("ouro_wide")
+    ref.write_checkpoint(WIDE, SEED, out)
+    params, cfg = convert.load_ouro_model(out)
+    r = ref.Reference(WIDE, SEED, 512)
+    groups, batches = r.batches_of([list(c) for c in chunks], 1)
+    pooled = r.forward(batches)
+    rows = np.zeros((len(chunks), WIDE["hidden_size"]), np.float32)
+    for b, (idx,) in enumerate(groups):
+        rows[idx] = pooled[b][0]
+    return (jax.tree.map(lambda a: np.asarray(a, np.float32), params),
+            dataclasses.replace(cfg, dtype="float32"), rows)
+
+
+def _routes(fn):
+    """(what `fn` returns, the `attn.packed{path}` bumps it made by path)."""
+    before = _counters("attn.packed")
+    out = fn()
+    return out, {k.split('"')[1]: v - before.get(k, 0)
+                 for k, v in _counters("attn.packed").items()
+                 if v != before.get(k, 0)}
+
+
+def _wide_rows(params, cfg, chunks, length):
+    (got, _), route = _routes(lambda: _embed_packed(
+        params, cfg, *_packed(chunks, WIDE_ROWS, length)))
+    return np.stack([got[0, 0], got[0, 2], got[1, 0], got[0, 1]]), route
+
+
+def test_the_kernel_route_equals_the_einsum_route_and_the_reference(
+        wide, chunks):
+    """At `head_dim` 128 a 256-token row goes through the Pallas kernel
+    (RoPE, chunk-and-causal mask, streaming softmax inside it) and a
+    264-token row of the same chunks through the einsum form: the route
+    depends on the shape alone, `attn.packed{path}` says which once per
+    traced program, and both stand where the float32 program stands from
+    the reference."""
+    params, cfg, want = wide
+    fused, route = _wide_rows(params, cfg, chunks, 256)
+    assert route == {"flash_segments": 1}
+    dense, route = _wide_rows(params, cfg, chunks, 264)
+    assert route == {"dense": 1}
+    assert _rel(fused, want).max() < F32_TOL
+    assert _rel(dense, want).max() < F32_TOL
+    assert _rel(fused, dense).max() < F32_TOL
+
+
+@pytest.mark.parametrize("head_dim, length, path", [
+    (128, 128, "flash_segments"), (128, 512, "flash_segments"),
+    (256, 384, "flash_segments"), (128, 8, "dense"), (128, 192, "dense"),
+    (64, 512, "dense"), (16, 128, "dense")])
+def test_the_route_is_chosen_by_head_dim_and_row_length_alone(
+        head_dim, length, path):
+    cfg = ouro.OuroConfig(vocab_size=50, hidden_size=2 * head_dim,
+                          num_layers=1, num_heads=2, head_dim=head_dim,
+                          intermediate_size=64, total_ut_steps=1)
+    params = jax.eval_shape(lambda: ouro.init_params(jax.random.key(0), cfg))
+    _, route = _routes(lambda: jax.eval_shape(
+        lambda p, i: ouro.embed_sentences(p, i, jnp.ones_like(i), cfg),
+        params, jax.ShapeDtypeStruct((1, length), jnp.int32)))
+    assert route == {path: 1}
 
 
 # ----------------------------------------------------------- precision
@@ -362,15 +439,16 @@ def test_family_table_has_four_rows(tmp_path, model_type, family):
 
 # ------------------------------------------------------ the lowered text
 
-def _dot_generals(layers: int, steps: int, packed: bool) -> int:
-    cfg = ouro.OuroConfig(vocab_size=100, hidden_size=32, num_layers=layers,
-                          num_heads=2, head_dim=16, intermediate_size=64,
-                          total_ut_steps=steps)
+def _dot_generals(layers: int, steps: int, packed: bool, head_dim: int = 16,
+                  length: int = 32) -> int:
+    cfg = ouro.OuroConfig(vocab_size=100, hidden_size=2 * head_dim,
+                          num_layers=layers, num_heads=2, head_dim=head_dim,
+                          intermediate_size=64, total_ut_steps=steps)
     params = jax.eval_shape(lambda: ouro.init_params(jax.random.key(0), cfg))
-    ids = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
     if packed:
         def fn(p, i, lengths):
-            seg = Segments.of_lengths(lengths, 32)
+            seg = Segments.of_lengths(lengths, length)
             return ouro.embed_sentences(p, i, seg.real, cfg, "mean", False,
                                         seg)
         arg = jax.ShapeDtypeStruct((2, 4), jnp.int32)
@@ -381,15 +459,21 @@ def _dot_generals(layers: int, steps: int, packed: bool) -> int:
     return jax.jit(fn).lower(params, ids, arg).as_text().count("dot_general")
 
 
-@pytest.mark.parametrize("packed", [True, False])
-def test_the_lowered_program_holds_one_block_whatever_the_depth(packed):
+@pytest.mark.parametrize("packed, head_dim, length, most", [
+    (True, 16, 32, 12), (False, 16, 32, 12), (True, 128, 128, 14),
+    (False, 128, 128, 14)])
+def test_the_lowered_program_holds_one_block_whatever_the_depth(
+        packed, head_dim, length, most):
     """192 block applications are ONE block in the program text: the count
     of matmuls does not grow with layers or with steps (7 projections, 2
-    attention products, the gate, the pooling)."""
-    counts = {(n, t): _dot_generals(n, t, packed)
+    attention products, the gate, the pooling), on the einsum route and on
+    the kernel's (the interpreted kernel's body stands in the text once,
+    with its two products for a block below the diagonal and two for the
+    block on it)."""
+    counts = {(n, t): _dot_generals(n, t, packed, head_dim, length)
               for n in (2, 6) for t in (1, 3)}
     assert len(set(counts.values())) == 1, counts
-    assert 9 <= counts[2, 1] <= 12, counts
+    assert 9 <= counts[2, 1] <= most, counts
 
 
 def test_a_family_table_import_brings_no_kernels_package():
