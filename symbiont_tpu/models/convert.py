@@ -384,6 +384,88 @@ def convert_ouro(state_dict: Dict[str, Any], cfg) -> Params:
     }
 
 
+def convert_ling(state_dict: Dict[str, Any], cfg) -> Params:
+    """Map a `bailing_hybrid` state_dict to the ling.py pytree. Names (no
+    published checkpoint is in the repository to read them from; the seeded
+    one, benchmark/refs/ling_flash.py, uses them): `word_embeddings`,
+    `norm`; per layer `input_layernorm`, `post_attention_layernorm`;
+    `attention.` + KDA's `{q,k,v}_proj`, `{q,k,v}_conv1d` [C, 1, K],
+    `f_proj`, `dt_bias`, `A_log`, `b_proj`, `g_proj`, `o_norm`, `o_proj`
+    or MLA's `q_proj`, `kv_a_proj_with_mqa`, `kv_a_layernorm`, `kv_b_proj`,
+    `q_norm`, `k_norm`, `g_proj`, `o_proj`; `mlp.` + a dense SwiGLU or
+    `gate` (+ `expert_bias`), `experts.<e>` for the held e, and
+    `shared_experts`. Torch Linear [out, in] -> [in, out], leaf by leaf in
+    the checkpoint's own dtype as `convert_mla_moe` does, and each tensor
+    leaves `state_dict` as it is read (a 10 GB checkpoint is never held
+    twice); rank-1 leaves go to float32. `lm_head` and an MTP layer are not
+    read: the encoder role pools hidden states."""
+    sd = state_dict
+    for k in list(sd):
+        for prefix in _LM_PREFIXES:
+            if k.startswith(prefix):
+                sd[k[len(prefix):]] = sd.pop(k)
+                break
+
+    def take(name: str) -> np.ndarray:
+        if name not in sd:
+            raise KeyError(f"checkpoint missing tensor {name!r}; have e.g. "
+                           f"{sorted(sd)[:5]}")
+        return _to_numpy(sd.pop(name))
+
+    def kernel(name: str) -> dict:
+        return {"kernel": _transposed([take(f"{name}.weight")])[0]}
+
+    def ln(name: str) -> dict:
+        return {"scale": take(f"{name}.weight").astype(np.float32)}
+
+    def mlp(prefix: str) -> dict:
+        return {k: kernel(f"{prefix}.{k}_proj") for k in ("gate", "up", "down")}
+
+    def stacked(prefix: str, proj: str) -> dict:
+        return {"kernel": _transposed(
+            [take(f"{prefix}.experts.{e}.{proj}_proj.weight")
+             for e in range(cfg.held)])}
+
+    params: Params = {"wte": take("word_embeddings.weight"),
+                      "ln_f": ln("norm"), "layers": []}
+    for i in range(cfg.num_layers):
+        p, a = f"layers.{i}", f"layers.{i}.attention"
+        layer = {"ln1": ln(f"{p}.input_layernorm"),
+                 "ln2": ln(f"{p}.post_attention_layernorm")}
+        if cfg.is_mla(i):
+            layer["attn"] = {
+                "q": kernel(f"{a}.q_proj"),
+                "kv_a": kernel(f"{a}.kv_a_proj_with_mqa"),
+                "kv_a_ln": ln(f"{a}.kv_a_layernorm"),
+                "kv_b": kernel(f"{a}.kv_b_proj"), "o": kernel(f"{a}.o_proj"),
+                "q_norm": ln(f"{a}.q_norm"), "k_norm": ln(f"{a}.k_norm"),
+                "gate": kernel(f"{a}.g_proj")}
+        else:
+            layer["kda"] = {
+                **{n: kernel(f"{a}.{n}_proj") for n in "qkv"},
+                "conv": {n: np.ascontiguousarray(
+                    take(f"{a}.{n}_conv1d.weight")[:, 0, :].T) for n in "qkv"},
+                "decay": {**kernel(f"{a}.f_proj"),
+                          "bias": take(f"{a}.dt_bias").astype(np.float32),
+                          "a_log": take(f"{a}.A_log").astype(np.float32)},
+                "beta": kernel(f"{a}.b_proj"), "gate": kernel(f"{a}.g_proj"),
+                "o_norm": ln(f"{a}.o_norm"), "o": kernel(f"{a}.o_proj")}
+        if i < cfg.first_k_dense_replace:
+            layer["mlp"] = mlp(f"{p}.mlp")
+        else:
+            moe = {"router": {
+                       **kernel(f"{p}.mlp.gate"),
+                       "bias": take(f"{p}.mlp.gate.expert_bias"
+                                    ).astype(np.float32)},
+                   "experts": {k: stacked(f"{p}.mlp", k)
+                               for k in ("gate", "up", "down")}}
+            if cfg.num_shared_experts:
+                moe["shared"] = mlp(f"{p}.mlp.shared_experts")
+            layer["moe"] = moe
+        params["layers"].append(layer)
+    return params
+
+
 def export_hf_bert(params: Params, cfg: BertConfig, out_dir: str | Path,
                    tokenizer_file: str | Path | None = None) -> Path:
     """Inverse of convert_bert: write a hub-format model dir
@@ -499,6 +581,14 @@ def load_ouro_model(model_dir: str | Path):
 
     cfg = OuroConfig.from_hf(load_hf_config(model_dir))
     return convert_ouro(load_state_dict(model_dir), cfg), cfg
+
+
+def load_ling_model(model_dir: str | Path):
+    """One-call load: (params, LingConfig) from a local HF model dir."""
+    from symbiont_tpu.models.ling import LingConfig
+
+    cfg = LingConfig.from_hf(load_hf_config(model_dir))
+    return convert_ling(load_state_dict(model_dir), cfg), cfg
 
 
 def load_bert_model(model_dir: str | Path, with_pooler: bool = False):

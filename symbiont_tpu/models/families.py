@@ -6,7 +6,8 @@ segments=None) -> (rows [B, H] float32, aux)` where `aux` is None or what
 the family's forward counts on the device (mla_moe: real tokens per expert
 layer and expert; sala: keys attended, causal keys and dense-path tokens per
 row and sparse layer; ouro: each loop step's exit mass and the token-steps
-run, per row), and `note_aux(aux)`, which books a fetched `aux` under the
+run, per row; ling: real tokens per expert layer and held expert, routed
+choices and passages started), and `note_aux(aux)`, which books a fetched `aux` under the
 family's own series (None where the forward counts nothing). With
 `segments` (models/bert.py `Segments`: the batched
 `embed` program's packed rows) a row holds several sentences and the rows
@@ -22,8 +23,9 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from symbiont_tpu.models import bert, mla_moe, ouro, sala
+from symbiont_tpu.models import bert, ling, mla_moe, ouro, sala
 from symbiont_tpu.models.bert import BertConfig
+from symbiont_tpu.models.ling import LingConfig
 from symbiont_tpu.models.mla_moe import MlaMoeConfig
 from symbiont_tpu.models.ouro import OuroConfig
 from symbiont_tpu.models.sala import SalaConfig
@@ -57,6 +59,12 @@ def _load_ouro(model_dir):
     from symbiont_tpu.models.convert import load_ouro_model
 
     return load_ouro_model(model_dir)
+
+
+def _load_ling(model_dir):
+    from symbiont_tpu.models.convert import load_ling_model
+
+    return load_ling_model(model_dir)
 
 
 _LABELS = {"service": "engine"}
@@ -110,6 +118,19 @@ def _note_loop(aux) -> None:
                         labels=_LABELS)
 
 
+def _note_ling(aux) -> None:
+    """Series of one embed dispatch of the `ling` family: `aux` [expert
+    layers + 1, held] int32 = the held experts' real-token counts by layer
+    (the `mla_moe` series over held experts), then [every real token's
+    routed choices held or not, passages started x KDA layers, ...]
+    (docs/OBSERVABILITY.md)."""
+    aux = np.asarray(aux, np.int64)
+    _note_moe(aux[:-1])
+    metrics.inc("engine.moe.assignments_routed", int(aux[-1, 0]),
+                labels=_LABELS)
+    metrics.inc("engine.kda.state_resets", int(aux[-1, 1]), labels=_LABELS)
+
+
 @dataclass(frozen=True)
 class Family:
     name: str
@@ -129,7 +150,9 @@ SALA = Family("sala", sala.MODEL_TYPES, SalaConfig, _load_sala,
               sala.init_params, sala.embed_sentences, _note_sparse)
 OURO = Family("ouro", ouro.MODEL_TYPES, OuroConfig, _load_ouro,
               ouro.init_params, ouro.embed_sentences, _note_loop)
-FAMILIES = (BERT, MLA_MOE, SALA, OURO)
+LING = Family("ling", ling.MODEL_TYPES, LingConfig, _load_ling,
+              ling.init_params, ling.embed_sentences, _note_ling)
+FAMILIES = (BERT, MLA_MOE, SALA, OURO, LING)
 _BY_TYPE = {t: f for f in FAMILIES for t in f.model_types}
 _BY_CONFIG = {f.config_cls: f for f in FAMILIES}
 

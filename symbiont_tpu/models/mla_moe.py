@@ -31,6 +31,16 @@ rest all run.
   counts of REAL tokens ([expert layers, E] int32): the engine's load
   counters read them at the fetch it already makes.
 
+What models/ling.py (Ling-3.0-flash) asks of the same pieces, each off by
+default, so Kimi-VL's programs lower to the text they lowered to before:
+group-limited routing (`n_group`, `topk_group`); an expert layer told which
+experts this chip holds (`experts_held`: the chip's share of a layer that
+several chips divide, the others' part left out); an RMSNorm over each
+head's q and k before RoPE (`qk_norm`) and a sigmoid gate a head on the
+context (`head_gate`); and two routes chosen by shape: a packed row over
+`LONG_ROW` tokens attends through the segment-masked flash kernel, and a row
+over `MOE_ROWS` tokens takes the expert layer `MOE_ROWS` tokens at a time.
+
 Not here (ROADMAP Reach A4): the absorbed decode form, a latent KV page
 format, experts inside engine/lm.py, an `expert` mesh axis.
 """
@@ -47,6 +57,7 @@ import jax.numpy as jnp
 from symbiont_tpu.models import quant
 from symbiont_tpu.models.bert import POOLERS, Segments, pool_segments
 from symbiont_tpu.models.layers import rmsnorm, rope, swiglu
+from symbiont_tpu.utils.telemetry import metrics
 
 Params = Any
 
@@ -77,6 +88,21 @@ class MlaMoeConfig:
     dtype: str = "bfloat16"
     # the engine sets it on every family's config; only "xla" exists here
     attn_impl: str = "xla"
+    # group-limited routing: experts in `n_group` equal groups, a token's
+    # choices from its best `topk_group` groups (DeepSeek-V3 `noaux_tc`)
+    n_group: int = 1
+    topk_group: int = 1
+    # the experts this chip holds, 0..experts_held-1 (0 = all of them): a
+    # choice of another expert is another chip's part of the layer
+    experts_held: int = 0
+    # MLA as Ling-3.0 has it: an RMSNorm over each head's whole q and k
+    # before RoPE, and the context scaled by sigmoid(W x), one gate a head
+    qk_norm: bool = False
+    head_gate: bool = False
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_routed_experts
 
     @staticmethod
     def from_hf(cfg: dict) -> "MlaMoeConfig":
@@ -128,13 +154,32 @@ def _deinterleave(x: jax.Array) -> jax.Array:
     return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
+LONG_ROW = 512  # packed rows longer than this attend through the kernel
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """[B, S, heads, d] -> [B, S, heads * d'] with d zero-padded to whole
+    128-lane columns (a zero column adds nothing to a dot product)."""
+    d = x.shape[-1]
+    x = jnp.pad(x, ((0, 0),) * 3 + ((0, -d % 128),))
+    return x.reshape(*x.shape[:2], -1)
+
+
 def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
                   cfg: MlaMoeConfig,
                   segments: Optional[Segments] = None) -> jax.Array:
     """x [B, S, H] (normed), mask [B, S] (1 = attended; right-padded, so an
     attended token's position is its index) -> [B, S, H]. Packed rows
     (`segments`): a token's position is its place in its sentence, and it
-    attends causally inside that sentence."""
+    attends causally inside that sentence.
+
+    The route is chosen by shape. A packed row longer than `LONG_ROW`
+    tokens that tiles (a multiple of 128) attends through
+    ops/flash_attention.py `packed_attention`, q.k heads zero-padded to 256
+    lanes and the value heads at 128, so no [S, S] tensor reaches HBM (at
+    32 heads and 32,768 tokens the float32 scores would be 137 GB); every
+    shorter row (Kimi-VL's 3-128-token sentences) takes the einsum form.
+    `attn.packed{path}` says which, once per traced program."""
     B, S, _ = x.shape
     nh, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
                       cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -145,18 +190,49 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
     c, k_rope = kva[..., :cfg.kv_lora_rank], kva[..., cfg.kv_lora_rank:]
     kv = quant.mm(rmsnorm(c, p["kv_a_ln"], cfg.rms_norm_eps),
                   p["kv_b"]["kernel"]).reshape(B, S, nh, dn + dv)
-    q_rope = rope(_deinterleave(q[..., dn:]), positions, cfg.rope_theta)
-    k_rope = rope(_deinterleave(k_rope)[:, :, None, :], positions,
-                  cfg.rope_theta)[:, :, 0, :]  # one key for all heads
-    scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], kv[..., :dn])
-              + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope))
-    keep = (jnp.tril(jnp.ones((S, S), bool))[None, None]
-            & ((mask[:, None, None, :] > 0) if segments is None
-               else segments.same[:, None]))
-    scores = jnp.where(keep, scores.astype(jnp.float32)
-                       / math.sqrt(dn + dr), -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
+    long = segments is not None and S > LONG_ROW and S % 128 == 0
+    metrics.inc("attn.packed",
+                labels={"path": "flash_segments" if long else "dense"})
+    if cfg.qk_norm:
+        # the shared rope key joins each head's key before the norm, so
+        # after it every head has a rope key of its own: k [B, S, nh, dn + dr]
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            k_rope[:, :, None, :], (B, S, nh, dr))], axis=-1)
+        q = rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
+        q_rope = rope(_deinterleave(q[..., dn:]), positions, cfg.rope_theta)
+        k_rope = rope(_deinterleave(k[..., dn:]), positions, cfg.rope_theta)
+    else:
+        k = kv  # the heads' nope keys; one rope key for all heads
+        q_rope = rope(_deinterleave(q[..., dn:]), positions, cfg.rope_theta)
+        k_rope = rope(_deinterleave(k_rope)[:, :, None, :], positions,
+                      cfg.rope_theta)[:, :, 0, :]
+    q_nope, k_nope = q[..., :dn], k[..., :dn]
+    if long:
+        from symbiont_tpu.ops.flash_attention import packed_attention
+
+        if k_rope.ndim == 3:
+            k_rope = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, nh, dr))
+        ctx = packed_attention(
+            _lanes(jnp.concatenate([q_nope, q_rope], axis=-1)),
+            _lanes(jnp.concatenate([k_nope, k_rope], axis=-1)),
+            _lanes(kv[..., dn:]), segments.index, nh,
+            scale=1.0 / math.sqrt(dn + dr))
+        ctx = ctx.reshape(B, S, nh, -1)[..., :dv]
+    else:
+        rope_keys = "bkhd" if k_rope.ndim == 4 else "bkd"  # a head's / shared
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+                  + jnp.einsum(f"bqhd,{rope_keys}->bhqk", q_rope, k_rope))
+        keep = (jnp.tril(jnp.ones((S, S), bool))[None, None]
+                & ((mask[:, None, None, :] > 0) if segments is None
+                   else segments.same[:, None]))
+        scores = jnp.where(keep, scores.astype(jnp.float32)
+                           / math.sqrt(dn + dr), -1e9)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
+    if cfg.head_gate:
+        ctx = ctx * jax.nn.sigmoid(
+            quant.mm(x, p["gate"]["kernel"]))[..., None].astype(ctx.dtype)
     return quant.mm(ctx.reshape(B, S, nh * dv), p["o"]["kernel"])
 
 
@@ -168,14 +244,26 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
 def route(p: Params, x32: jax.Array, cfg: MlaMoeConfig):
     """x32 [T, H] float32 (normed) -> (idx [T, k] int32, weights [T, k]
     float32). Scores in float32 at full matmul precision: top-k is discrete,
-    and a score off in the third digit picks another expert."""
+    and a score off in the third digit picks another expert. With
+    `n_group` > 1 a group scores the sum of its best two choice scores and
+    a token chooses among the experts of its best `topk_group` groups. The
+    weights are normalised over all k choices, wherever their experts
+    live."""
     kernel = p["kernel"]
     if quant.is_quantized(kernel):
         kernel = kernel.dequantize()
     s = jax.nn.sigmoid(jnp.dot(x32, kernel.astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + p["bias"].astype(jnp.float32),
-                           cfg.num_experts_per_tok)
+    choice = s + p["bias"].astype(jnp.float32)
+    if cfg.n_group > 1:
+        T, E = s.shape
+        per = E // cfg.n_group
+        best = jax.lax.top_k(choice.reshape(T, cfg.n_group, per), 2)[0]
+        _, groups = jax.lax.top_k(best.sum(-1), cfg.topk_group)
+        kept = jnp.zeros((T, cfg.n_group), bool).at[
+            jnp.arange(T)[:, None], groups].set(True)
+        choice = jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf)
+    _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.norm_topk_prob and cfg.num_experts_per_tok > 1:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
@@ -190,10 +278,17 @@ def routed_experts(p: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
     int32 = real tokens per expert). The T*k assignments are sorted by
     expert (padding's sort past the last expert, into no group), each
     projection is ONE grouped matmul over the stacked kernels, and the
-    results are gathered back token-major: no capacity, nothing dropped."""
+    results are gathered back token-major: no capacity, nothing dropped.
+
+    Holding `cfg.held` < `n_routed_experts` experts (the stacked kernels
+    are experts 0..held-1), E is the held count: a choice of an expert held
+    elsewhere sorts past the last group as padding does, and the sum is
+    this chip's part of the layer, its weights as the router gave them."""
     T, k = idx.shape
-    E = cfg.n_routed_experts
-    flat = jnp.where(real[:, None], idx, E).reshape(T * k)
+    E = cfg.held
+    here = (real[:, None] if E == cfg.n_routed_experts
+            else real[:, None] & (idx < E))
+    flat = jnp.where(here, idx, E).reshape(T * k)
     order = jnp.argsort(flat, stable=True)
     sorted_e = flat[order]
     counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
@@ -213,13 +308,13 @@ def routed_experts(p: Params, x: jax.Array, idx: jax.Array, w: jax.Array,
     return y.astype(x.dtype), counts
 
 
-def moe_ffn(p: Params, h: jax.Array, mask: jax.Array, ln: Params,
-            cfg: MlaMoeConfig):
-    """h [B, S, H] (the residual, not normed) -> (FFN(RMSNorm(h)), counts)."""
-    B, S, H = h.shape
-    real = (mask > 0).reshape(B * S)
-    x32 = rmsnorm(h.astype(jnp.float32), ln, cfg.rms_norm_eps).reshape(B * S, H)
-    x = x32.astype(h.dtype)
+MOE_ROWS = 8192  # tokens of a longer row the expert layer takes at a time
+
+
+def _moe_rows(p: Params, x32: jax.Array, real: jax.Array, dtype,
+              cfg: MlaMoeConfig):
+    """x32 [T, H] float32 (normed) -> (y [T, H] in `dtype`, counts [E])."""
+    x = x32.astype(dtype)
     with jax.named_scope("router"):
         idx, w = route(p["router"], x32, cfg)
     with jax.named_scope("experts"):
@@ -227,6 +322,33 @@ def moe_ffn(p: Params, h: jax.Array, mask: jax.Array, ln: Params,
     if "shared" in p:
         with jax.named_scope("shared_expert"):
             y = y + swiglu(x, p["shared"])
+    return y, counts
+
+
+def moe_ffn(p: Params, h: jax.Array, mask: jax.Array, ln: Params,
+            cfg: MlaMoeConfig):
+    """h [B, S, H] (the residual, not normed) -> (FFN(RMSNorm(h)), counts).
+    A row longer than `MOE_ROWS` tokens goes `MOE_ROWS` tokens at a time
+    (the tokens padded to whole blocks, the padding not real): over a
+    32,768-token row at top-8 the gathered rows alone are 1.3 GB at a hidden
+    size of 2,560."""
+    B, S, H = h.shape
+    real = (mask > 0).reshape(B * S)
+    if S > MOE_ROWS:
+        def some(xs):
+            rows, real = xs
+            x32 = rmsnorm(rows.astype(jnp.float32), ln, cfg.rms_norm_eps)
+            return _moe_rows(p, x32, real, h.dtype, cfg)
+
+        rows, pad = h.reshape(B * S, H), -(B * S) % MOE_ROWS
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+            real = jnp.pad(real, (0, pad))
+        y, counts = jax.lax.map(some, (rows.reshape(-1, MOE_ROWS, H),
+                                       real.reshape(-1, MOE_ROWS)))
+        return y.reshape(-1, H)[:B * S].reshape(B, S, H), counts.sum(0)
+    x32 = rmsnorm(h.astype(jnp.float32), ln, cfg.rms_norm_eps).reshape(B * S, H)
+    y, counts = _moe_rows(p, x32, real, h.dtype, cfg)
     return y.reshape(B, S, H), counts
 
 

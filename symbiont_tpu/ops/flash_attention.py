@@ -39,7 +39,9 @@ Design:
   (i, j) iff `id[i] == id[j]` and `j <= i`), RoPE is applied to q and k
   inside the kernel, and q, k, v and the context keep the projections' own
   `[B, L, heads * D]` layout, a head being a 128-lane column block that a
-  BlockSpec picks: nothing is transposed or re-laid on either side. The
+  BlockSpec picks: nothing is transposed or re-laid on either side. A value
+  head may be narrower than q.k's (MLA: 192 for q.k padded to 256, 128 for
+  v, models/mla_moe.py) and the caller may give the scale. The
   same arithmetic; its own kernel body (`_packed_kernel`), because what
   sets its pace on the chip is how a block is walked, not what is computed.
 - every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
@@ -215,6 +217,7 @@ def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
     q_ref, k_ref, v_ref, o_ref, *scratch = refs
     sub = min(block, _PACKED_ROWS)
     D = q_ref.shape[2] // heads
+    Dv = v_ref.shape[2] // heads  # a value head may be narrower than q.k's
 
     def _q(r0: int, cols):
         rows = pl.ds(r0, sub)
@@ -253,13 +256,13 @@ def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
 
     if not scratch:
         for h in range(heads):
-            cols = pl.ds(h * D, D)
-            k, v = _k(cols), v_ref[0, :, cols]
+            cols, vcols = pl.ds(h * D, D), pl.ds(h * Dv, Dv)
+            k, v = _k(cols), v_ref[0, :, vcols]
             for r0 in range(0, block, sub):
                 s = _scores(_q(r0, cols), k[:_keys(r0)], r0, True)
                 p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
                 l = jnp.sum(p, axis=-1, keepdims=True)  # >= 1: the max's own
-                o_ref[0, pl.ds(r0, sub), cols] = (
+                o_ref[0, pl.ds(r0, sub), vcols] = (
                     _context(p, v[:_keys(r0)]) * pl.reciprocal(l)
                 ).astype(o_ref.dtype)
         return
@@ -344,30 +347,44 @@ def _flash_call(q, k, v, bias, causal, scale, block_q, block_k, interpret):
     )(bias.reshape(B, Sk // bk, 1, bk), q, k, v)
 
 
-def _packed_call(q, k, v, ids, rope, num_heads, block, interpret):
+def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
+                 scale=None):
     """`_packed_kernel` over packed rows in the projections' own layout: q,
-    k, v [B, L, heads * D], head h the column block h (D a multiple of the
-    128 lanes), the context written the same way, so nothing is re-laid on
-    either side. `ids` [B, L] int32 goes in twice: a column [block, 1] of
-    the queries' ids and a row [1, block] of the keys'; `rope` = (cos, sin)
-    [B, L, D] float32 likewise, by query rows and by key rows (a block whose
-    index the step before had is not fetched again: the tables move once a
-    row, not once a head)."""
+    k [B, L, heads * D] and v [B, L, heads * Dv], head h the column block h
+    (D and Dv multiples of the 128 lanes), the context written as v is, so
+    nothing is re-laid on either side. `ids` [B, L] int32 goes in twice: a
+    column [block, 1] of the queries' ids and a row [1, block] of the keys';
+    `rope` = (cos, sin) [B, L, D] float32 likewise, by query rows and by key
+    rows (a block whose index the step before had is not fetched again: the
+    tables move once a row, not once a head). Streaming, a step above the
+    diagonal asks for the diagonal's key block again, so the blocks it
+    skips are not fetched either."""
     B, L, HD = q.shape
     D = HD // num_heads
+    Dv = v.shape[2] // num_heads
     single = L == block  # one block a row: no state between steps
     heads = _PACKED_HEADS if single and num_heads % _PACKED_HEADS == 0 else 1
-    kernel = functools.partial(_packed_kernel, scale=1.0 / math.sqrt(D),
-                               block=block, heads=heads,
-                               rotary=rope is not None)
+    kernel = functools.partial(
+        _packed_kernel, scale=1.0 / math.sqrt(D) if scale is None else scale,
+        block=block, heads=heads, rotary=rope is not None)
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     qspec = pl.BlockSpec((1, block, heads * D),
                          lambda b, h, qi, ki: (b, qi, h))
-    kspec = pl.BlockSpec((1, block, heads * D),
-                         lambda b, h, qi, ki: (b, ki, h))
+    if single:
+        kspec = pl.BlockSpec((1, block, heads * D),
+                             lambda b, h, qi, ki: (b, ki, h))
+    else:
+        kspec = pl.BlockSpec((1, block, heads * D),
+                             lambda b, h, qi, ki: (b, jnp.minimum(ki, qi), h))
+    vspec, ospec = kspec, qspec
+    if Dv != D:
+        vspec = pl.BlockSpec((1, block, heads * Dv),
+                             lambda b, h, qi, ki: (b, jnp.minimum(ki, qi), h))
+        ospec = pl.BlockSpec((1, block, heads * Dv),
+                             lambda b, h, qi, ki: (b, qi, h))
     tables, table_specs = (), []
     if rope is not None:
         tables = (*rope, *rope)
@@ -381,14 +398,14 @@ def _packed_call(q, k, v, ids, rope, num_heads, block, interpret):
         in_specs=[
             pl.BlockSpec((1, block, 1), lambda b, h, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, 1, block), lambda b, h, qi, ki: (b, 0, ki)),
-            *table_specs, qspec, kspec, kspec,
+            *table_specs, qspec, kspec, vspec,
         ],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=ospec,
+        out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
         scratch_shapes=[] if single else [
             pltpu.VMEM((block, 128), jnp.float32),  # running max (lane-replicated)
             pltpu.VMEM((block, 128), jnp.float32),  # running normalizer
-            pltpu.VMEM((block, D), jnp.float32),    # output accumulator
+            pltpu.VMEM((block, Dv), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
         **kwargs,
@@ -713,17 +730,19 @@ def flash_attention(
 def packed_attention(
     q: jax.Array,  # [B, L, num_heads * D], as a projection produces it
     k: jax.Array,  # [B, L, num_heads * D]
-    v: jax.Array,  # [B, L, num_heads * D]
+    v: jax.Array,  # [B, L, num_heads * Dv]
     segment_ids: jax.Array,  # [B, L] int32: the token's segment in its row
     num_heads: int,
     rope: tuple[jax.Array, jax.Array] | None = None,  # `layers.rope_tables`
     block: int = 512,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Causal attention inside the segments of packed rows, forward only,
-    -> [B, L, num_heads * D] in q.dtype: token i sees token j iff
+    -> [B, L, num_heads * Dv] in q.dtype: token i sees token j iff
     `segment_ids[b, i] == segment_ids[b, j]` and `j <= i`; scores scaled by
-    1 / sqrt(D). Padding carries an id no segment has, so it keeps itself company and
+    `scale`, 1 / sqrt(D) when None (a caller whose heads are zero-padded to
+    the lanes gives the scale of the width before padding). Padding carries an id no segment has, so it keeps itself company and
     no softmax row is empty. With `rope`, q and k are turned inside the
     kernel first (float32, cast back to their dtype, as `layers.rope` does):
     done outside, the turned q and k are two more [B, L, heads * D] arrays
@@ -733,21 +752,24 @@ def packed_attention(
     (operands in the input dtype, float32 scores, softmax statistics and
     accumulator) and the same `interpret` rule, over square blocks of the
     largest power of two up to `block` that divides L; it reads and writes
-    the projections' own layout, a head being a D-wide column block, so D
-    and L must be multiples of 128."""
+    the projections' own layout, a head being a D-wide (v: Dv-wide) column
+    block, so D, Dv and L must be multiples of 128."""
     B, L, HD = q.shape
     D, rem = divmod(HD, num_heads)
+    Dv, vrem = divmod(v.shape[-1], num_heads)
     block = _pick_block(L, block)
-    if rem or D % 128 or block < 128:
+    if rem or vrem or D % 128 or Dv % 128 or block < 128:
         raise ValueError(
-            f"packed_attention: q{tuple(q.shape)} with {num_heads} heads "
-            "does not tile (head_dim and L must be multiples of 128)")
+            f"packed_attention: q{tuple(q.shape)} v{tuple(v.shape)} with "
+            f"{num_heads} heads does not tile (head widths and L must be "
+            "multiples of 128)")
     shapes = [t.shape for t in (k, v, *(rope or ()))]
-    if (shapes != 2 * [q.shape] + (len(shapes) - 2) * [(B, L, D)]
+    if (shapes != [q.shape, (B, L, num_heads * Dv)]
+            + (len(shapes) - 2) * [(B, L, D)]
             or segment_ids.shape != (B, L)):
         raise ValueError(
             f"packed_attention: shapes q{tuple(q.shape)}, k, v and the rope "
             f"tables {shapes}, ids{tuple(segment_ids.shape)}")
     return _packed_call(q, k, v, segment_ids.astype(jnp.int32), rope,
-                        num_heads, block, _interpret(interpret, q, k))
+                        num_heads, block, _interpret(interpret, q, k), scale)
 

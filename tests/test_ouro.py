@@ -427,13 +427,14 @@ def test_from_hf_reads_the_published_sizes():
 
 @pytest.mark.parametrize("model_type,family", [
     ("ouro", "ouro"), ("minicpm_sala", "sala"), ("deepseek_v3", "mla_moe"),
-    ("xlm-roberta", "bert")])
+    ("xlm-roberta", "bert"), ("bailing_hybrid", "ling")])
 def test_family_table_has_four_rows(tmp_path, model_type, family):
     (tmp_path / "config.json").write_text(json.dumps(
         {"model_type": model_type}))
     assert families.family_of_checkpoint(tmp_path).name == family
+    # four rows before the `ling` family, five with it
     assert [f.name for f in families.FAMILIES] == ["bert", "mla_moe", "sala",
-                                                   "ouro"]
+                                                   "ouro", "ling"]
     assert families.family_of_config(ouro.OuroConfig()) is families.OURO
 
 

@@ -219,3 +219,50 @@ def test_the_looped_block_keeps_its_scores_on_the_chip(one_chip, monkeypatch,
     assert f"[{rows},16,512,512]" not in text
     # the parent's program needed 1.38 GB of scratch at 8 rows
     assert compiled.memory_analysis().temp_size_in_bytes < 2e8
+
+
+@pytest.mark.parametrize("kind", ["kda", "mla"])
+def test_ling_mixers_compile_within_the_chip_at_published_widths(
+        one_chip, monkeypatch, kind):
+    """Ling-3.0-flash's two mixers over ONE packed 32,768-token row at
+    published widths (32 heads; KDA 128-wide, MLA q.k 192 and v 128): the
+    chip's compiler takes the chunked delta rule (a scan carrying the float32
+    state) and MLA through ONE Mosaic kernel traced under `mla`, and neither
+    holds anything [L, L]: float32 scores of 32 heads at this length would be
+    137 GB."""
+    from symbiont_tpu.engine.bucketing import segments_per_row
+    from symbiont_tpu.models import ling
+    from symbiont_tpu.models.bert import Segments
+
+    # the route asks nothing of the backend, the kernel's `interpret` does
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L = 32768
+    cfg = ling.LingConfig(num_layers=6, first_k_dense_replace=6)
+    layer = jax.eval_shape(lambda: ling.init_params(
+        jax.random.key(0), cfg))["layers"][5 if kind == "mla" else 0]
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16 if a.ndim > 1 else jnp.float32,
+        sharding=one_chip), layer["attn" if kind == "mla" else "kda"])
+    x = jax.ShapeDtypeStruct((1, L, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, segments_per_row(L)), jnp.int32,
+                               sharding=one_chip)
+
+    def mixer(p, x, seg_lengths):
+        segments = Segments.of_lengths(seg_lengths, L)
+        with jax.named_scope(kind):
+            if kind == "mla":
+                return mla_moe.mla_attention(p, x, segments.real, cfg.mla,
+                                             segments)
+            return ling.kda_mixer(p, x, segments, cfg)
+
+    compiled = jax.jit(mixer).lower(p, x, seg).compile()
+    text = compiled.as_text()
+    assert f"{L},{L}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    if kind == "mla":
+        kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
+                             r'.*op_name="([^"]*)"', text)
+        assert len(kernels) == 1 and "/mla/" in kernels[0], kernels
+    else:
+        assert "while" in text
