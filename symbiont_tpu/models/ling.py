@@ -11,8 +11,9 @@ the vision tower are not instantiated). Layer i's mixer is MLA where
 shared expert.
 
 - **KDA** (Kimi Delta Attention; `ops/delta_rule.py` computes the
-  recurrence): `q, k, v = SiLU(ShortConv(W x))`, the convolution depthwise
-  and causal over `short_conv_kernel_size` tokens of the passage; q and k
+  recurrence, one Pallas kernel where a head is 128 lanes wide):
+  `q, k, v = SiLU(ShortConv(W x))`, the convolution depthwise and causal
+  over `short_conv_kernel_size` tokens of the passage; q and k
   L2-normalised per head, q scaled by 1/sqrt(d); `beta = sigmoid(W_b x)`
   per head; `g = lower_bound * sigmoid(exp(A_h) (W_f x + b))` per key
   channel (the safe gate, `kda_lower_bound`); the gated delta rule over the
@@ -235,11 +236,11 @@ def _l2norm(x: jax.Array) -> jax.Array:
 def kda_mixer(p: Params, x: jax.Array, segments: Segments,
               cfg: LingConfig) -> jax.Array:
     """x [B, L, H] (normed) -> [B, L, H]."""
-    from symbiont_tpu.ops.delta_rule import gated_delta_rule
+    from symbiont_tpu.ops import delta_rule
 
     B, L, _ = x.shape
     nh, d = cfg.num_heads, cfg.head_dim
-    metrics.inc("kda.path", labels={"path": "chunked"})
+    metrics.inc("kda.path", labels={"path": delta_rule.path(d, d)})
 
     def heads(name):
         y = short_conv(quant.mm(x, p[name]["kernel"]), p["conv"][name],
@@ -256,7 +257,7 @@ def kda_mixer(p: Params, x: jax.Array, segments: Segments,
     a = jnp.exp(decay["a_log"].astype(jnp.float32))[:, None]
     g = cfg.kda_lower_bound * jax.nn.sigmoid(a * z)
     with jax.named_scope("delta_rule"):
-        o = gated_delta_rule(q, k, v, g, beta, segments.index)
+        o = delta_rule.gated_delta_rule(q, k, v, g, beta, segments.index)
     o = rmsnorm(o, p["o_norm"], cfg.rms_norm_eps).reshape(B, L, nh * d)
     gate = jax.nn.sigmoid(quant.mm(x, p["gate"]["kernel"]))
     return quant.mm(o * gate, p["o"]["kernel"])

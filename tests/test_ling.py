@@ -22,7 +22,10 @@ Tolerances, each with its reason:
   decays then reach e^+-40 within a sub-chunk, the float32 guard (factored
   from a sub-chunk's start they reached e^-80, and a small q or k entry
   times that fell under float32's normal range and was flushed to zero:
-  2e-4 there).
+  2e-4 there). Both forms: the XLA one at toy width, the Pallas kernel
+  (under the interpreter) at the 128-wide heads that take it.
+- the kernel against the XLA form on the same inputs: 1e-5 relative (read:
+  1e-7 to 2e-6), the same float32 arithmetic in another order.
 """
 
 from __future__ import annotations
@@ -45,6 +48,7 @@ from symbiont_tpu.config import EngineConfig  # noqa: E402
 from symbiont_tpu.engine.engine import TpuEngine  # noqa: E402
 from symbiont_tpu.models import convert, families, ling, mla_moe  # noqa: E402
 from symbiont_tpu.models.bert import Segments  # noqa: E402
+from symbiont_tpu.ops import delta_rule  # noqa: E402
 from symbiont_tpu.ops.delta_rule import gated_delta_rule, unit_lower_inverse  # noqa: E402
 from symbiont_tpu.utils.telemetry import metrics  # noqa: E402
 
@@ -194,16 +198,35 @@ def _inputs(lens, L, floor, seed=0, H=2, d=16, repeat=False):
     return q, k, v, g, beta, index
 
 
-@pytest.mark.parametrize("lens, L, floor, repeat", [
+RULE_CASES = [
     ((128, 64), 192, False, False),  # passages that fill whole chunks
     ((100, 37, 5), 160, False, False),  # that do not, and padding after them
     ((100, 37, 5), 160, True, False),  # the gate at its floor on every token
     ((256,), 256, False, True),  # one key repeated: the solve stays bounded
-])
-def test_chunked_rule_matches_the_token_recurrence(lens, L, floor, repeat):
-    q, k, v, g, beta, index = _inputs(lens, L, floor, repeat=repeat)
+    # a chunk (tokens 64-127) holds the end of one passage and the start of
+    # the next, the first passage's state carried into it
+    ((100, 92), 192, False, False),
+]
+
+
+def _rule_inputs(lens, L, floor, repeat, d):
+    q, k, v, g, beta, index = _inputs(lens, L, floor, d=d, repeat=repeat)
     if repeat:  # slow decay and beta near 1: entries of A near beta
         g, beta = np.full_like(g, -1e-3), np.full_like(beta, 0.95)
+    return q, k, v, g, beta, index
+
+
+@pytest.mark.parametrize("route", ["xla", "pallas"])
+@pytest.mark.parametrize("lens, L, floor, repeat", RULE_CASES)
+def test_chunked_rule_matches_the_token_recurrence(route, lens, L, floor,
+                                                   repeat):
+    """Both forms against the float64 recurrence: the XLA form at toy
+    width, the kernel (under the Pallas interpreter here) at 128-wide heads,
+    the width that takes it."""
+    d = 128 if route == "pallas" else 16
+    assert delta_rule.path(d, d) == ("pallas" if route == "pallas"
+                                     else "chunked")
+    q, k, v, g, beta, index = _rule_inputs(lens, L, floor, repeat, d)
     got = np.asarray(gated_delta_rule(
         *(jnp.asarray(a[None], jnp.float32) for a in (q, k, v, g, beta)),
         index))[0]
@@ -212,8 +235,22 @@ def test_chunked_rule_matches_the_token_recurrence(lens, L, floor, repeat):
     for n in lens:  # over the passage: a token's output may be near zero
         want = _recurrence(*(x[a:a + n] for x in (q, k, v, g, beta)))
         err = np.linalg.norm(got[a:a + n] - want) / np.linalg.norm(want)
-        assert err < TOL, (n, floor, repeat, err)
+        assert err < TOL, (route, n, floor, repeat, err)
         a += n
+
+
+@pytest.mark.parametrize("lens, L, floor, repeat", RULE_CASES)
+def test_the_kernel_is_the_xla_form(lens, L, floor, repeat):
+    """The kernel and the XLA form on the same 128-wide heads: the same
+    float32 arithmetic in another order."""
+    args = _rule_inputs(lens, L, floor, repeat, 128)
+    kernel, xla = (np.asarray(rule(
+        *(jnp.asarray(a[None], jnp.float32) for a in args[:5]), args[5]))[0]
+        for rule in (delta_rule._kernel_rule, delta_rule._chunked_rule))
+    real = sum(lens)
+    err = (np.linalg.norm(kernel[:real] - xla[:real])
+           / np.linalg.norm(xla[:real]))
+    assert err < 1e-5, err
 
 
 def test_a_bfloat16_state_is_outside_the_tolerance():

@@ -226,10 +226,11 @@ def test_ling_mixers_compile_within_the_chip_at_published_widths(
         one_chip, monkeypatch, kind):
     """Ling-3.0-flash's two mixers over ONE packed 32,768-token row at
     published widths (32 heads; KDA 128-wide, MLA q.k 192 and v 128): the
-    chip's compiler takes the chunked delta rule (a scan carrying the float32
-    state) and MLA through ONE Mosaic kernel traced under `mla`, and neither
-    holds anything [L, L]: float32 scores of 32 heads at this length would be
-    137 GB."""
+    chip's compiler takes the delta rule as ONE Mosaic kernel traced under
+    `delta_rule` (the float32 state in VMEM; nothing left of the XLA form's
+    scan, whose operands were [64, 1, 8, 64, 32, 128]) and MLA through ONE
+    Mosaic kernel traced under `mla`, and neither holds anything [L, L]:
+    float32 scores of 32 heads at this length would be 137 GB."""
     from symbiont_tpu.engine.bucketing import segments_per_row
     from symbiont_tpu.models import ling
     from symbiont_tpu.models.bert import Segments
@@ -260,9 +261,8 @@ def test_ling_mixers_compile_within_the_chip_at_published_widths(
     text = compiled.as_text()
     assert f"{L},{L}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
-    if kind == "mla":
-        kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
-                             r'.*op_name="([^"]*)"', text)
-        assert len(kernels) == 1 and "/mla/" in kernels[0], kernels
-    else:
-        assert "while" in text
+    kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
+                         r'.*op_name="([^"]*)"', text)
+    scope = "/mla/" if kind == "mla" else "/kda/delta_rule/"
+    assert len(kernels) == 1 and scope in kernels[0], kernels
+    assert not re.search(r"\[\d+,1,8,64,32,", text)
