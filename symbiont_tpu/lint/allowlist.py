@@ -102,6 +102,11 @@ JAX_JIT_IN_FUNCTION_ALLOWED = {
         "for the one trace of the engine's own jit, so that each kind of "
         "sub-layer (KDA mixer, MLA mixer, expert layer: one shape each) is "
         "traced and lowered once and called per layer",
+    ("symbiont_tpu/models/mimo.py", "encode"):
+        "no executable, as models/mla_moe.py `encode`: the inner jits live "
+        "for the one trace of the engine's own jit, so that each kind of "
+        "sub-layer (window mixer, full mixer, expert layer: one shape each) "
+        "is traced and lowered once and called per layer",
 }
 
 # deliberate device→host sync points on the dispatch hot path: one bulk

@@ -583,6 +583,93 @@ def load_ouro_model(model_dir: str | Path):
     return convert_ouro(load_state_dict(model_dir), cfg), cfg
 
 
+def convert_mimo(state_dict: Dict[str, Any], cfg) -> Params:
+    """Map a `mimo_v2_flash` state_dict to the mimo.py pytree. Names (no
+    published checkpoint is in the repository to read them from; the seeded
+    one, benchmark/refs/mimo_v2_flash.py, uses them): `embed_tokens`,
+    `norm`; per layer `input_layernorm`, `post_attention_layernorm`;
+    `self_attn.` + `{q,k,v,o}_proj` and, in a layer with a sink,
+    `attention_sink_bias` [heads]; `mlp.` + a dense SwiGLU or `gate` (+
+    `e_score_correction_bias`) and `experts.<e>` for the held e. Torch
+    Linear [out, in] -> [in, out], leaf by leaf in the checkpoint's own
+    dtype, each tensor leaving `state_dict` as it is read; W_q's and W_k's
+    columns go to the lanes the attention kernel reads (`mimo.lane_of`,
+    zeros between), W_v's columns and W_o's rows to whole 128-lane value
+    heads; rank-1 leaves go to float32. `lm_head` and the MTP layers are
+    not read: the encoder role pools hidden states."""
+    from symbiont_tpu.models.mimo import lane_of, to_lanes
+
+    sd = state_dict
+    for k in list(sd):
+        for prefix in _LM_PREFIXES:
+            if k.startswith(prefix):
+                sd[k[len(prefix):]] = sd.pop(k)
+                break
+
+    def take(name: str) -> np.ndarray:
+        if name not in sd:
+            raise KeyError(f"checkpoint missing tensor {name!r}; have e.g. "
+                           f"{sorted(sd)[:5]}")
+        return _to_numpy(sd.pop(name))
+
+    def kernel(name: str) -> dict:
+        return {"kernel": _transposed([take(f"{name}.weight")])[0]}
+
+    def ln(name: str) -> dict:
+        return {"scale": take(f"{name}.weight").astype(np.float32)}
+
+    def mlp(prefix: str) -> dict:
+        return {k: kernel(f"{prefix}.{k}_proj") for k in ("gate", "up", "down")}
+
+    def stacked(prefix: str, proj: str) -> dict:
+        return {"kernel": _transposed(
+            [take(f"{prefix}.experts.{e}.{proj}_proj.weight")
+             for e in range(cfg.held)])}
+
+    nh, D, Dv = cfg.num_heads, cfg.head_dim, cfg.v_head_dim
+    where, v_where = lane_of(D, cfg.rotary_dim), np.arange(Dv)
+
+    def heads(name: str, n: int, width: int, lanes: int, at) -> dict:
+        return {"kernel": to_lanes(kernel(name)["kernel"], n, width, lanes,
+                                   at)}
+
+    params: Params = {"wte": take("embed_tokens.weight"), "ln_f": ln("norm"),
+                      "layers": []}
+    for i in range(cfg.num_layers):
+        p, a = f"layers.{i}", f"layers.{i}.self_attn"
+        nkv = cfg.kv_heads(i)
+        o = kernel(f"{a}.o_proj")["kernel"]  # [heads * Dv, H]
+        attn = {"q": heads(f"{a}.q_proj", nh, D, cfg.lanes, where),
+                "k": heads(f"{a}.k_proj", nkv, D, cfg.lanes, where),
+                "v": heads(f"{a}.v_proj", nkv, Dv, cfg.v_lanes, v_where),
+                "o": {"kernel": np.ascontiguousarray(to_lanes(
+                    np.ascontiguousarray(o.T), nh, Dv, cfg.v_lanes,
+                    v_where).T)}}
+        if cfg.sink(i):
+            attn["sink"] = take(f"{a}.attention_sink_bias").astype(np.float32)
+        layer = {"ln1": ln(f"{p}.input_layernorm"),
+                 "ln2": ln(f"{p}.post_attention_layernorm"), "attn": attn}
+        if cfg.is_moe(i):
+            layer["moe"] = {
+                "router": {**kernel(f"{p}.mlp.gate"),
+                           "bias": take(f"{p}.mlp.gate.e_score_correction_bias"
+                                        ).astype(np.float32)},
+                "experts": {k: stacked(f"{p}.mlp", k)
+                            for k in ("gate", "up", "down")}}
+        else:
+            layer["mlp"] = mlp(f"{p}.mlp")
+        params["layers"].append(layer)
+    return params
+
+
+def load_mimo_model(model_dir: str | Path):
+    """One-call load: (params, MimoConfig) from a local HF model dir."""
+    from symbiont_tpu.models.mimo import MimoConfig
+
+    cfg = MimoConfig.from_hf(load_hf_config(model_dir))
+    return convert_mimo(load_state_dict(model_dir), cfg), cfg
+
+
 def load_ling_model(model_dir: str | Path):
     """One-call load: (params, LingConfig) from a local HF model dir."""
     from symbiont_tpu.models.ling import LingConfig

@@ -7,7 +7,8 @@ the family's forward counts on the device (mla_moe: real tokens per expert
 layer and expert; sala: keys attended, causal keys and dense-path tokens per
 row and sparse layer; ouro: each loop step's exit mass and the token-steps
 run, per row; ling: real tokens per expert layer and held expert, routed
-choices and passages started), and `note_aux(aux)`, which books a fetched `aux` under the
+choices and passages started; mimo: the same expert counts and the keys the
+window layers attended beside the causal keys), and `note_aux(aux)`, which books a fetched `aux` under the
 family's own series (None where the forward counts nothing). With
 `segments` (models/bert.py `Segments`: the batched
 `embed` program's packed rows) a row holds several sentences and the rows
@@ -23,9 +24,10 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from symbiont_tpu.models import bert, ling, mla_moe, ouro, sala
+from symbiont_tpu.models import bert, ling, mimo, mla_moe, ouro, sala
 from symbiont_tpu.models.bert import BertConfig
 from symbiont_tpu.models.ling import LingConfig
+from symbiont_tpu.models.mimo import MimoConfig
 from symbiont_tpu.models.mla_moe import MlaMoeConfig
 from symbiont_tpu.models.ouro import OuroConfig
 from symbiont_tpu.models.sala import SalaConfig
@@ -65,6 +67,12 @@ def _load_ling(model_dir):
     from symbiont_tpu.models.convert import load_ling_model
 
     return load_ling_model(model_dir)
+
+
+def _load_mimo(model_dir):
+    from symbiont_tpu.models.convert import load_mimo_model
+
+    return load_mimo_model(model_dir)
 
 
 _LABELS = {"service": "engine"}
@@ -131,6 +139,23 @@ def _note_ling(aux) -> None:
     metrics.inc("engine.kda.state_resets", int(aux[-1, 1]), labels=_LABELS)
 
 
+def _note_mimo(aux) -> None:
+    """Series of one embed dispatch of the `mimo` family: `aux` int32 = a
+    row [every real token's routed choices held or not, window layers,
+    held, expert layers], the held experts' real-token counts by expert
+    layer (the `mla_moe` series over held experts), then a row per batch
+    row [keys the window layers attended, causal keys one layer would see]
+    (docs/OBSERVABILITY.md)."""
+    aux = np.asarray(aux, np.int64)
+    routed, windows, held, layers = (int(v) for v in aux[0, :4])
+    _note_moe(aux[1:1 + layers, :held])
+    metrics.inc("engine.moe.assignments_routed", routed, labels=_LABELS)
+    attended, causal = aux[1 + layers:, :2].sum(0)
+    metrics.inc("engine.attn.window_keys", int(attended), labels=_LABELS)
+    metrics.inc("engine.attn.keys_causal", int(causal) * windows,
+                labels=_LABELS)
+
+
 @dataclass(frozen=True)
 class Family:
     name: str
@@ -152,7 +177,9 @@ OURO = Family("ouro", ouro.MODEL_TYPES, OuroConfig, _load_ouro,
               ouro.init_params, ouro.embed_sentences, _note_loop)
 LING = Family("ling", ling.MODEL_TYPES, LingConfig, _load_ling,
               ling.init_params, ling.embed_sentences, _note_ling)
-FAMILIES = (BERT, MLA_MOE, SALA, OURO, LING)
+MIMO = Family("mimo", mimo.MODEL_TYPES, MimoConfig, _load_mimo,
+              mimo.init_params, mimo.embed_sentences, _note_mimo)
+FAMILIES = (BERT, MLA_MOE, SALA, OURO, LING, MIMO)
 _BY_TYPE = {t: f for f in FAMILIES for t in f.model_types}
 _BY_CONFIG = {f.config_cls: f for f in FAMILIES}
 

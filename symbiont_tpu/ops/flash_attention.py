@@ -44,6 +44,12 @@ Design:
   v, models/mla_moe.py) and the caller may give the scale. The
   same arithmetic; its own kernel body (`_packed_kernel`), because what
   sets its pace on the chip is how a block is walked, not what is computed.
+  Its grouped form (`_grouped_kernel`, models/mimo.py) adds GQA (a step
+  takes every query head of one KV head, so a key block is fetched once
+  for the group), a sliding window whose grid holds only the key blocks
+  the window reaches, and a sink logit a head in the softmax's
+  normaliser; without them a call lowers to the text it lowered to
+  before.
 - every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
   line and one `flash.fallback{path}` bump per traced shape — the Pallas interpreter on a CPU backend, the dense route for
   untileable shapes, the dense-recompute GQA backward. `chip_smoke.py`
@@ -412,6 +418,195 @@ def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
     )(ids[:, :, None], ids[:, None, :], *tables, q, k, v)
 
 
+# How the grouped kernel tiles a row: full attention takes 256 query rows
+# against 512 keys a step (fewer where the row is shorter), every query head
+# of a KV head in the step (at 64 query heads over 4 KV heads, 16 heads of
+# 256 lanes: 2 MB of q); a window takes 128 against 128, so a 128-key
+# window visits two key blocks a query block.
+_GROUPED_BLOCKS = {False: (256, 512), True: (128, 128)}
+_GROUPED_VMEM = 64 * 1024 * 1024  # the step's q, its rotated copy, stats
+
+
+def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
+                    rotary: bool, window: int, sink: bool, steps: int,
+                    count: bool):
+    """One (batch, KV head, q block, kv step) step of `_grouped_call`: the
+    `heads` query heads that read this KV head, against one key block, with
+    `_kernel`'s running max, normaliser and accumulator per head. The key
+    block of step j is j (full attention) or the q block's own less
+    `steps - 1 - j` (a window: the blocks before it that the window
+    reaches); a step whose block lies past the query block's last row or
+    before the row's first is skipped. With `sink`, head h's softmax counts
+    e^sink_h in its normaliser: the running max starts at sink_h and the
+    normaliser at 1, so a key block that is all masked adds nothing. With
+    `count`, each query's unmasked keys over the steps taken are summed
+    and written beside the output (every KV head writes the same count)."""
+    qid_ref, kid_ref, *refs = refs
+    if sink:
+        sink_ref, *refs = refs
+    if rotary:
+        cosq_ref, sinq_ref, cosk_ref, sink_k_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, *refs = refs
+    if count:
+        n_ref, *refs = refs
+    qs_scr, m_scr, l_scr, acc_scr, *refs = refs
+    if count:
+        n_scr, = refs
+    D = k_ref.shape[2]
+    Dv = v_ref.shape[2]
+    qi, j = pl.program_id(2), pl.program_id(3)
+    kb = qi * bq // bk - (steps - 1) + j if window else j
+
+    @pl.when(j == 0)
+    def _init():
+        q = q_ref[0]  # [bq, heads * D]
+        for h in range(heads):
+            cols = pl.ds(h * D, D)
+            qh = q[:, h * D:(h + 1) * D]
+            qs_scr[:, cols] = (_rotate(qh, cosq_ref[0], sinq_ref[0])
+                               if rotary else qh)
+            if sink:
+                m_scr[h] = jnp.broadcast_to(sink_ref[0, pl.ds(h, 1), :],
+                                            m_scr.shape[1:])
+                l_scr[h] = jnp.ones(l_scr.shape[1:], jnp.float32)
+            else:
+                m_scr[h] = jnp.full(m_scr.shape[1:], _ACC_NEG, jnp.float32)
+                l_scr[h] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+            acc_scr[h] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
+        if count:
+            n_scr[...] = jnp.zeros(n_scr.shape, jnp.float32)
+
+    def _step():
+        k = k_ref[0]  # [bk, D], one KV head for every query head here
+        if rotary:
+            k = _rotate(k, cosk_ref[0], sink_k_ref[0])
+        v = v_ref[0]
+        qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        keep = (qid_ref[0] == kid_ref[0]) & (kpos <= qpos)
+        if window:
+            keep &= kpos > qpos - window
+        if count:
+            n_scr[...] += jnp.sum(keep.astype(jnp.float32), axis=-1,
+                                  keepdims=True)
+        for h in range(heads):
+            q = qs_scr[:, pl.ds(h * D, D)]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                    precision=_dot_prec(q, k)) * scale
+            s = jnp.where(keep, s, _MASK_NEG)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[h, :, :1] + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_dot_prec(v))
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    if window:
+        pl.when(kb >= 0)(_step)
+    else:
+        pl.when(kb * bk < (qi + 1) * bq)(_step)
+
+    @pl.when(j == steps - 1)
+    def _finish():
+        for h in range(heads):
+            o_ref[0, :, pl.ds(h * Dv, Dv)] = (
+                acc_scr[h] * pl.reciprocal(l_scr[h, :, :1])
+            ).astype(o_ref.dtype)
+        if count:
+            n_ref[0] = n_scr[:, :1]
+
+
+def _grouped_call(q, k, v, ids, rope, num_heads, kv_heads, window, sinks,
+                  scale, interpret, count):
+    """`_grouped_kernel` over packed rows in the projections' own layout: q
+    [B, L, heads * D], k [B, L, kv_heads * D], v [B, L, kv_heads * Dv]; the
+    grid is (B, KV heads, q blocks, kv steps), so a step takes the whole
+    group of query heads that reads one KV head and each key block is
+    fetched once for the group. A window's grid holds only the key blocks
+    the window reaches; full attention's holds every block, and a block
+    past the diagonal asks for the diagonal's again, so it is not fetched.
+    With `count`, also the keys each query attended, float32 [B, L, 1]."""
+    B, L, _ = q.shape
+    D, Dv = k.shape[2] // kv_heads, v.shape[2] // kv_heads
+    heads = num_heads // kv_heads
+    bq, bk = _GROUPED_BLOCKS[bool(window)]
+    bq, bk = _pick_block(L, bq), _pick_block(L, bk)
+    steps = 1 + -(-(window - 1) // bk) if window else L // bk
+    if window:
+        def kv_block(qi, j):
+            return jnp.maximum(qi * bq // bk - (steps - 1) + j, 0)
+    else:
+        def kv_block(qi, j):
+            return jnp.minimum(j, ((qi + 1) * bq - 1) // bk)
+    kernel = functools.partial(
+        _grouped_kernel, scale=scale, bq=bq, bk=bk, heads=heads,
+        rotary=rope is not None, window=window, sink=sinks is not None,
+        steps=steps, count=count)
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM)
+    extra, extra_specs = [], []
+    if sinks is not None:
+        # lane-replicated, one [heads, 128] block a KV head
+        extra.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(kv_heads, heads, 1),
+            (kv_heads, heads, 128)))
+        extra_specs.append(pl.BlockSpec((1, heads, 128),
+                                        lambda b, g, qi, j: (g, 0, 0)))
+    if rope is not None:
+        extra += [*rope, *rope]
+        extra_specs += 2 * [pl.BlockSpec((1, bq, D),
+                                         lambda b, g, qi, j: (b, qi, 0))]
+        extra_specs += 2 * [pl.BlockSpec(
+            (1, bk, D), lambda b, g, qi, j: (b, kv_block(qi, j), 0))]
+    out_specs = [pl.BlockSpec((1, bq, heads * Dv),
+                              lambda b, g, qi, j: (b, qi, g))]
+    out_shape = [jax.ShapeDtypeStruct((B, L, num_heads * Dv), q.dtype)]
+    scratch = [
+        pltpu.VMEM((bq, heads * D), q.dtype),        # the rotated q
+        pltpu.VMEM((heads, bq, 128), jnp.float32),   # running max
+        pltpu.VMEM((heads, bq, 128), jnp.float32),   # running normaliser
+        pltpu.VMEM((heads, bq, Dv), jnp.float32),    # accumulators
+    ]
+    if count:
+        out_specs.append(pl.BlockSpec((1, bq, 1),
+                                      lambda b, g, qi, j: (b, qi, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, L, 1), jnp.float32))
+        scratch.append(pltpu.VMEM((bq, 128), jnp.float32))  # keys attended
+    out = pl.pallas_call(
+        kernel,
+        grid=(B, kv_heads, L // bq, steps),
+        in_specs=[
+            pl.BlockSpec((1, bq, 1), lambda b, g, qi, j: (b, qi, 0)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda b, g, qi, j: (b, 0, kv_block(qi, j))),
+            *extra_specs,
+            pl.BlockSpec((1, bq, heads * D), lambda b, g, qi, j: (b, qi, g)),
+            pl.BlockSpec((1, bk, D),
+                         lambda b, g, qi, j: (b, kv_block(qi, j), g)),
+            pl.BlockSpec((1, bk, Dv),
+                         lambda b, g, qi, j: (b, kv_block(qi, j), g)),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        # the op's name in a profile: a per-layer metric reads each kernel
+        name="window_attention" if window else "grouped_attention",
+        scratch_shapes=scratch,
+        interpret=interpret,
+        **kwargs,
+    )(ids[:, :, None], ids[:, None, :], *extra, q, k, v)
+    return tuple(out) if count else out[0]
+
+
 def _dense_reference(q, k, v, bias, causal, scale, segment_ids=None):
     """f32 dense attention — fallback path and backward-pass recompute.
     `segment_ids` [B, S] int32 (self-attention): a query sees the keys of
@@ -737,7 +932,11 @@ def packed_attention(
     block: int = 512,
     interpret: bool | None = None,
     scale: float | None = None,
-) -> jax.Array:
+    kv_heads: int | None = None,
+    window: int = 0,
+    sinks: jax.Array | None = None,
+    count_keys: bool = False,
+):
     """Causal attention inside the segments of packed rows, forward only,
     -> [B, L, num_heads * Dv] in q.dtype: token i sees token j iff
     `segment_ids[b, i] == segment_ids[b, j]` and `j <= i`; scores scaled by
@@ -753,23 +952,56 @@ def packed_attention(
     accumulator) and the same `interpret` rule, over square blocks of the
     largest power of two up to `block` that divides L; it reads and writes
     the projections' own layout, a head being a D-wide (v: Dv-wide) column
-    block, so D, Dv and L must be multiples of 128."""
+    block, so D, Dv and L must be multiples of 128.
+
+    Three things more, each off by default (`_grouped_call`, its own
+    kernel; without them the call is the one above): `kv_heads` fewer than
+    `num_heads` (GQA: k and v [B, L, kv_heads * D], query head h reads KV
+    head h // (num_heads / kv_heads), and each key block is fetched once
+    for its group of query heads), a `window` W (token i sees j only where
+    i - W < j; the grid holds only the key blocks the window reaches) and
+    `sinks` [num_heads] float32, a logit per head that joins each query's
+    softmax normaliser and attends to nothing. L must then be a multiple
+    of 128. With `count_keys` (the grouped form only) the call returns
+    (out, keys): keys int32 [B, L], how many keys each query's softmax
+    took, counted in the kernel from the mask it applied."""
     B, L, HD = q.shape
     D, rem = divmod(HD, num_heads)
     Dv, vrem = divmod(v.shape[-1], num_heads)
-    block = _pick_block(L, block)
+    grouped = (kv_heads is not None or window or sinks is not None
+               or count_keys)
+    if grouped:
+        kv_heads = kv_heads or num_heads
+        Dv, vrem = divmod(v.shape[-1], kv_heads)
+        vrem = vrem or num_heads % kv_heads
+        block = 128 if L % 128 == 0 else 0
+    else:
+        kv_heads = num_heads
+        block = _pick_block(L, block)
     if rem or vrem or D % 128 or Dv % 128 or block < 128:
         raise ValueError(
             f"packed_attention: q{tuple(q.shape)} v{tuple(v.shape)} with "
             f"{num_heads} heads does not tile (head widths and L must be "
             "multiples of 128)")
     shapes = [t.shape for t in (k, v, *(rope or ()))]
-    if (shapes != [q.shape, (B, L, num_heads * Dv)]
+    if (shapes != [(B, L, kv_heads * D), (B, L, kv_heads * Dv)]
             + (len(shapes) - 2) * [(B, L, D)]
-            or segment_ids.shape != (B, L)):
+            or segment_ids.shape != (B, L)
+            or (sinks is not None and sinks.shape != (num_heads,))):
         raise ValueError(
             f"packed_attention: shapes q{tuple(q.shape)}, k, v and the rope "
             f"tables {shapes}, ids{tuple(segment_ids.shape)}")
+    interpret = _interpret(interpret, q, k)
+    if grouped:
+        out = _grouped_call(
+            q, k, v, segment_ids.astype(jnp.int32), rope, num_heads,
+            kv_heads, int(window), sinks,
+            1.0 / math.sqrt(D) if scale is None else scale, interpret,
+            count_keys)
+        if count_keys:
+            out, keys = out
+            return out, keys[..., 0].astype(jnp.int32)
+        return out
     return _packed_call(q, k, v, segment_ids.astype(jnp.int32), rope,
-                        num_heads, block, _interpret(interpret, q, k), scale)
+                        num_heads, block, interpret, scale)
 

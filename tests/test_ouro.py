@@ -427,14 +427,15 @@ def test_from_hf_reads_the_published_sizes():
 
 @pytest.mark.parametrize("model_type,family", [
     ("ouro", "ouro"), ("minicpm_sala", "sala"), ("deepseek_v3", "mla_moe"),
-    ("xlm-roberta", "bert"), ("bailing_hybrid", "ling")])
+    ("xlm-roberta", "bert"), ("bailing_hybrid", "ling"),
+    ("mimo_v2_flash", "mimo")])
 def test_family_table_has_four_rows(tmp_path, model_type, family):
     (tmp_path / "config.json").write_text(json.dumps(
         {"model_type": model_type}))
     assert families.family_of_checkpoint(tmp_path).name == family
-    # four rows before the `ling` family, five with it
+    # four rows before the `ling` family, five with it, six with `mimo`
     assert [f.name for f in families.FAMILIES] == ["bert", "mla_moe", "sala",
-                                                   "ouro", "ling"]
+                                                   "ouro", "ling", "mimo"]
     assert families.family_of_config(ouro.OuroConfig()) is families.OURO
 
 

@@ -342,7 +342,7 @@ def test_family_of_config_and_what_each_family_notes():
     assert families.family_of_config(bert.BertConfig()) is families.BERT
     assert families.BERT.note_aux is None
     assert {f.name for f in families.FAMILIES
-            if f.note_aux} == {"mla_moe", "sala", "ouro", "ling"}
+            if f.note_aux} == {"mla_moe", "sala", "ouro", "ling", "mimo"}
 
 
 @pytest.mark.parametrize("key,value", [

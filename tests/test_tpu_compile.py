@@ -266,3 +266,49 @@ def test_ling_mixers_compile_within_the_chip_at_published_widths(
     scope = "/mla/" if kind == "mla" else "/kda/delta_rule/"
     assert len(kernels) == 1 and scope in kernels[0], kernels
     assert not re.search(r"\[\d+,1,8,64,32,", text)
+
+
+@pytest.mark.parametrize("window", [True, False])
+def test_mimo_mixers_compile_within_the_chip_at_published_widths(
+        one_chip, monkeypatch, window):
+    """MiMo-V2-Flash's two attention mixers over ONE packed 32,768-token row
+    at published widths (64 query heads of 192 laid in 256 lanes, v 128; 8
+    KV heads and a sink in a window layer, 4 KV heads in a full one): the
+    chip's compiler takes each as ONE Mosaic kernel (`window_attention` /
+    `grouped_attention`) traced under the mixer's scope, and neither program
+    holds anything [L, L] or a KV head repeated to the query heads."""
+    from symbiont_tpu.engine.bucketing import segments_per_row
+    from symbiont_tpu.models import mimo
+    from symbiont_tpu.models.bert import Segments
+
+    L = 32768
+    cfg = mimo.MimoConfig(num_layers=2, layer_pattern=(0, 1),
+                          moe_layers=(0, 0))
+    layer = jax.eval_shape(lambda: mimo.init_params(
+        jax.random.key(0), cfg))["layers"][int(window)]
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16 if a.ndim > 1 else jnp.float32,
+        sharding=one_chip), layer["attn"])
+    x = jax.ShapeDtypeStruct((1, L, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, segments_per_row(L)), jnp.int32,
+                               sharding=one_chip)
+    scope = "swa" if window else "full_attn"
+
+    def mixer(p, x, seg_lengths):
+        segments = Segments.of_lengths(seg_lengths, L)
+        theta = cfg.swa_rope_theta if window else cfg.rope_theta
+        tables = mimo.rope_lanes(segments.position, cfg, theta)
+        with jax.named_scope(scope):
+            return mimo.attention(p, x, segments, tables, cfg, window)
+
+    # the route asks nothing of the backend, the kernel's `interpret` does
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(mixer).lower(p, x, seg).compile()
+    text = compiled.as_text()
+    assert f"{L},{L}]" not in text
+    assert not re.search(rf"\[1,{L},64,256\]", text)  # no KV head repeated
+    kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
+                         r'.*op_name="([^"]*)"', text)
+    assert len(kernels) == 1 and f"/{scope}/" in kernels[0], kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
