@@ -76,7 +76,8 @@ class Segments(NamedTuple):
     def same(self) -> jax.Array:
         """[B, L, L] bool: query and key in one sentence (padding, which
         nothing reads, keeps itself company: no row of the softmax is
-        empty)."""
+        empty; the grouped attention kernel, told padding's id, writes 0
+        for a block of queries that is all padding instead)."""
         return self.index[:, :, None] == self.index[:, None, :]
 
 

@@ -144,15 +144,20 @@ def _note_mimo(aux) -> None:
     row [every real token's routed choices held or not, window layers,
     held, expert layers], the held experts' real-token counts by expert
     layer (the `mla_moe` series over held experts), then a row per batch
-    row [keys the window layers attended, causal keys one layer would see]
-    (docs/OBSERVABILITY.md)."""
+    row [keys the window layers attended, causal keys one layer would see,
+    the full layers' kernel steps that computed, the steps under their
+    diagonal] (docs/OBSERVABILITY.md)."""
     aux = np.asarray(aux, np.int64)
     routed, windows, held, layers = (int(v) for v in aux[0, :4])
     _note_moe(aux[1:1 + layers, :held])
     metrics.inc("engine.moe.assignments_routed", routed, labels=_LABELS)
-    attended, causal = aux[1 + layers:, :2].sum(0)
+    attended, causal, steps_run, steps_causal = aux[1 + layers:, :4].sum(0)
     metrics.inc("engine.attn.window_keys", int(attended), labels=_LABELS)
     metrics.inc("engine.attn.keys_causal", int(causal) * windows,
+                labels=_LABELS)
+    metrics.inc("engine.attn.block_steps_run", int(steps_run),
+                labels=_LABELS)
+    metrics.inc("engine.attn.block_steps_causal", int(steps_causal),
                 labels=_LABELS)
 
 

@@ -61,10 +61,12 @@ Handed only a mask (the fused query), the row is one passage.
 layers + B, max(held, 4)]: a row [routed choices (real tokens x k x expert
 layers, held or not), window layers, held, expert layers], a row per
 expert layer of the real tokens each held expert took, and a row per batch
-row [keys the window layers attended, causal keys one layer would see]; the
-family's `note_aux` (models/families.py) books it. The keys attended are
-counted where the softmax is taken (the kernel counts the keys its mask
-lets through), so a window the kernel did not apply shows there.
+row [keys the window layers attended, causal keys one layer would see, the
+full layers' kernel steps that computed, the steps under their diagonal
+(`full_steps`)]; the family's `note_aux` (models/families.py) books it. The
+keys attended are counted where the softmax is taken (the kernel counts the
+keys its mask lets through), so a window the kernel did not apply shows
+there.
 
 Not here (ROADMAP Reach A4): pages allocated by layer kind in `kv/paged.py`
 / `kv/pool.py` and a sink in paged attention, the generation path's decode
@@ -344,6 +346,29 @@ def _dense_attention(q, k, v, segments: Segments, tables, window: int,
     return ctx.reshape(B, L, nh, -1).astype(v.dtype), keys
 
 
+def _fits_kernel(L: int) -> bool:
+    """Whether a row of L tokens takes the Pallas kernel (whole 128-token
+    blocks) or the einsum form."""
+    return L % 128 == 0
+
+
+def full_steps(segments: Segments, cfg: MimoConfig) -> jax.Array:
+    """int32 [B, 2]: the full layers' kernel grid steps that compute, and
+    those a walk of every block under the diagonal would take, summed over
+    the full layers and their KV heads (`flash_attention.grouped_steps` on
+    the ids and padding id the layers hand the kernel); 0 where the row
+    takes the einsum form."""
+    B, L = segments.index.shape
+    if not _fits_kernel(L):
+        return jnp.zeros((B, 2), jnp.int32)
+    from symbiont_tpu.ops.flash_attention import grouped_steps
+
+    full = sum(not cfg.is_window(i) for i in range(cfg.num_layers))
+    return grouped_steps(segments.index,
+                         padding_id=segments.lengths.shape[1]) * (
+        full * cfg.num_kv_heads)
+
+
 def attention(p: Params, x: jax.Array, segments: Segments, tables,
               cfg: MimoConfig, window: bool):
     """x [B, L, H] (normed) -> ([B, L, H], keys): one window or full layer;
@@ -358,7 +383,7 @@ def attention(p: Params, x: jax.Array, segments: Segments, tables,
     k = quant.mm(x, p["k"]["kernel"])
     v = quant.mm(x, p["v"]["kernel"])
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    fused = L % 128 == 0
+    fused = _fits_kernel(L)
     metrics.inc("attn.packed", labels={"path": (
         ("flash_window" if window else "flash_grouped") if fused
         else "dense")})
@@ -367,7 +392,8 @@ def attention(p: Params, x: jax.Array, segments: Segments, tables,
 
         out = packed_attention(q, k, v, segments.index, nh, rope=tables,
                                kv_heads=nkv, window=W, sinks=sinks,
-                               scale=scale, count_keys=window)
+                               scale=scale, count_keys=window,
+                               padding_id=segments.lengths.shape[1])
         ctx, keys = out if window else (out, None)
     else:
         ctx, keys = _dense_attention(
@@ -457,7 +483,8 @@ def embed_sentences(params: Params, input_ids: jax.Array,
         routed, jnp.int32(windows), jnp.int32(cfg.held), jnp.int32(layers)]))
     keys = jnp.stack([attended, (real * (segments.position + 1)).sum(
         1, dtype=jnp.int32)], axis=1)  # [B, 2]
-    rows = jnp.zeros((keys.shape[0], width), jnp.int32).at[:, :2].set(keys)
+    keys = jnp.concatenate([keys, full_steps(segments, cfg)], axis=1)
+    rows = jnp.zeros((keys.shape[0], width), jnp.int32).at[:, :4].set(keys)
     counts = jnp.pad(counts, ((0, 0), (0, width - cfg.held)))
     return pooled, jnp.concatenate([last, counts, rows], axis=0)
 

@@ -49,7 +49,11 @@ Design:
   for the group), a sliding window whose grid holds only the key blocks
   the window reaches, and a sink logit a head in the softmax's
   normaliser; without them a call lowers to the text it lowered to
-  before.
+  before. Each of its query blocks walks only the key blocks from the
+  first whose segment ids can meet its own to the diagonal, bounds the
+  wrapper computes on the device and hands over by scalar prefetch, so a
+  packed row's other passage and its padding cost a grid step's overhead,
+  not its work.
 - every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
   line and one `flash.fallback{path}` bump per traced shape — the Pallas interpreter on a CPU backend, the dense route for
   untileable shapes, the dense-recompute GQA backward. `chip_smoke.py`
@@ -427,20 +431,94 @@ _GROUPED_BLOCKS = {False: (256, 512), True: (128, 128)}
 _GROUPED_VMEM = 64 * 1024 * 1024  # the step's q, its rotated copy, stats
 
 
-def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
-                    rotary: bool, window: int, sink: bool, steps: int,
-                    count: bool):
+def _grouped_tiling(L: int, window: int) -> tuple[int, int, int]:
+    """(bq, bk, steps) of `_grouped_call`'s grid over rows of L tokens: the
+    query and key block sizes, and the key steps a query block takes (every
+    key block of the row, or those a window reaches)."""
+    bq, bk = (_pick_block(L, b) for b in _GROUPED_BLOCKS[bool(window)])
+    return bq, bk, 1 + -(-(window - 1) // bk) if window else L // bk
+
+
+def _grouped_reach(L: int, bq: int, bk: int, window: int):
+    """bool [L // bq, L // bk]: the key blocks query block qi can see at all
+    (at or below its diagonal block, the one that holds its last row, and
+    inside the window where there is one); and int32 [L // bq] the
+    diagonal block."""
+    qi = jnp.arange(L // bq, dtype=jnp.int32)[:, None]
+    kb = jnp.arange(L // bk, dtype=jnp.int32)[None, :]
+    diagonal = ((qi + 1) * bq - 1) // bk
+    reach = kb <= diagonal
+    if window:
+        reach &= kb >= (qi * bq - window + 1) // bk
+    return reach, diagonal[:, 0]
+
+
+def _grouped_bounds(ids, bq: int, bk: int, window: int, padding_id):
+    """int32 [B, L // bq, 2] from the ids [B, L]: the first and last key
+    block each query block walks. The last is the diagonal block. The first
+    is the earliest block in reach whose range of ids meets the query
+    block's: a block whose ids all lie above or all below the query block's
+    shares no passage with it, whatever the order of the ids, so every
+    block left out is one the mask would wipe whole. A query block that is
+    all `padding_id` walks nothing (first = last + 1)."""
+    B, L = ids.shape
+    q = ids.reshape(B, L // bq, bq)
+    k = ids.reshape(B, L // bk, bk)
+    reach, last = _grouped_reach(L, bq, bk, window)
+    meets = (reach & (k.min(-1)[:, None, :] <= q.max(-1)[:, :, None])
+             & (k.max(-1)[:, None, :] >= q.min(-1)[:, :, None]))
+    # the diagonal block holds the query block's own rows, so it always meets
+    first = jnp.argmax(meets, axis=-1).astype(jnp.int32)
+    last = jnp.broadcast_to(last, first.shape)
+    if padding_id is not None:
+        padding = (q == padding_id).all(-1)
+        first = jnp.where(padding, last + 1, first)
+    return jnp.stack([first, last], axis=-1)
+
+
+def grouped_steps(segment_ids: jax.Array, window: int = 0,
+                  padding_id: int | None = None) -> jax.Array:
+    """int32 [B, 2] for `packed_attention`'s grouped form over rows with
+    these ids [B, L]: for each KV head, the key steps its kernel computes
+    (`_grouped_bounds`) and the steps of a walk over every block in reach
+    (under the diagonal, or inside the window), which the kernel took
+    before it kept bounds."""
+    B, L = segment_ids.shape
+    bq, bk, _ = _grouped_tiling(L, window)
+    bounds = _grouped_bounds(segment_ids.astype(jnp.int32), bq, bk, window,
+                             padding_id)
+    run = jnp.maximum(bounds[..., 1] - bounds[..., 0] + 1, 0).sum(1)
+    reach = _grouped_reach(L, bq, bk, window)[0].sum(dtype=jnp.int32)
+    return jnp.stack([run, jnp.broadcast_to(reach, run.shape)], axis=1)
+
+
+def _grouped_step(bounds_ref, b, qi, j, *, nq: int, bq: int, bk: int,
+                  steps: int, window: int):
+    """(first, last, kb): query block qi's range of key blocks, read from
+    the scalar-prefetched `_grouped_bounds` of row b, and the key block of
+    step j in it: first + j (full attention) or the q block's own less
+    `steps - 1 - j` (a window: the blocks before it that the window
+    reaches). The kernel and its index maps both walk by it."""
+    at = 2 * (b * nq + qi)
+    first, last = bounds_ref[at], bounds_ref[at + 1]
+    kb = qi * bq // bk - (steps - 1) + j if window else first + j
+    return first, last, kb
+
+
+def _grouped_kernel(bounds_ref, *refs, scale: float, heads: int,
+                    rotary: bool, sink: bool, count: bool, walk, bq: int,
+                    bk: int, window: int, steps: int):
     """One (batch, KV head, q block, kv step) step of `_grouped_call`: the
     `heads` query heads that read this KV head, against one key block, with
-    `_kernel`'s running max, normaliser and accumulator per head. The key
-    block of step j is j (full attention) or the q block's own less
-    `steps - 1 - j` (a window: the blocks before it that the window
-    reaches); a step whose block lies past the query block's last row or
-    before the row's first is skipped. With `sink`, head h's softmax counts
-    e^sink_h in its normaliser: the running max starts at sink_h and the
-    normaliser at 1, so a key block that is all masked adds nothing. With
-    `count`, each query's unmasked keys over the steps taken are summed
-    and written beside the output (every KV head writes the same count)."""
+    `_kernel`'s running max, normaliser and accumulator per head. `walk`
+    (`_grouped_step`) gives the query block's first and last key block and
+    the block of this step; a step whose block lies outside [first, last]
+    is skipped, and a query block with an empty range writes zeros. With
+    `sink`, head h's softmax counts e^sink_h in its
+    normaliser: the running max starts at sink_h and the normaliser at 1,
+    so a key block that is all masked adds nothing. With `count`, each
+    query's unmasked keys over the steps taken are summed and written
+    beside the output (every KV head writes the same count)."""
     qid_ref, kid_ref, *refs = refs
     if sink:
         sink_ref, *refs = refs
@@ -455,9 +533,10 @@ def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
     D = k_ref.shape[2]
     Dv = v_ref.shape[2]
     qi, j = pl.program_id(2), pl.program_id(3)
-    kb = qi * bq // bk - (steps - 1) + j if window else j
+    first, last, kb = walk(bounds_ref, pl.program_id(0), qi, j)
+    walked = first <= last
 
-    @pl.when(j == 0)
+    @pl.when((j == 0) & walked)
     def _init():
         q = q_ref[0]  # [bq, heads * D]
         for h in range(heads):
@@ -476,6 +555,7 @@ def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
         if count:
             n_scr[...] = jnp.zeros(n_scr.shape, jnp.float32)
 
+    @pl.when((kb >= first) & (kb <= last))
     def _step():
         k = k_ref[0]  # [bk, D], one KV head for every query head here
         if rotary:
@@ -507,12 +587,7 @@ def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
             m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
             l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    if window:
-        pl.when(kb >= 0)(_step)
-    else:
-        pl.when(kb * bk < (qi + 1) * bq)(_step)
-
-    @pl.when(j == steps - 1)
+    @pl.when((j == steps - 1) & walked)
     def _finish():
         for h in range(heads):
             o_ref[0, :, pl.ds(h * Dv, Dv)] = (
@@ -521,33 +596,43 @@ def _grouped_kernel(*refs, scale: float, bq: int, bk: int, heads: int,
         if count:
             n_ref[0] = n_scr[:, :1]
 
+    # no step ran: without a sink the normaliser is 0, so write the zeros
+    @pl.when((j == steps - 1) & jnp.logical_not(walked))
+    def _nothing():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        if count:
+            n_ref[...] = jnp.zeros(n_ref.shape, n_ref.dtype)
+
 
 def _grouped_call(q, k, v, ids, rope, num_heads, kv_heads, window, sinks,
-                  scale, interpret, count):
+                  scale, interpret, count, padding_id):
     """`_grouped_kernel` over packed rows in the projections' own layout: q
     [B, L, heads * D], k [B, L, kv_heads * D], v [B, L, kv_heads * Dv]; the
     grid is (B, KV heads, q blocks, kv steps), so a step takes the whole
     group of query heads that reads one KV head and each key block is
     fetched once for the group. A window's grid holds only the key blocks
-    the window reaches; full attention's holds every block, and a block
-    past the diagonal asks for the diagonal's again, so it is not fetched.
-    With `count`, also the keys each query attended, float32 [B, L, 1]."""
+    the window reaches; full attention's holds every block. Each query
+    block walks only its [first, last] (`_grouped_bounds`, computed here
+    on the device and handed over in scalar memory), and a step outside it
+    asks for a block of the range again, so it is not fetched. With
+    `count`, also the keys each query attended, float32 [B, L, 1]."""
     B, L, _ = q.shape
     D, Dv = k.shape[2] // kv_heads, v.shape[2] // kv_heads
     heads = num_heads // kv_heads
-    bq, bk = _GROUPED_BLOCKS[bool(window)]
-    bq, bk = _pick_block(L, bq), _pick_block(L, bk)
-    steps = 1 + -(-(window - 1) // bk) if window else L // bk
-    if window:
-        def kv_block(qi, j):
-            return jnp.maximum(qi * bq // bk - (steps - 1) + j, 0)
-    else:
-        def kv_block(qi, j):
-            return jnp.minimum(j, ((qi + 1) * bq - 1) // bk)
+    bq, bk, steps = _grouped_tiling(L, window)
+    nq = L // bq
+    bounds = _grouped_bounds(ids, bq, bk, window, padding_id)
+    walk = functools.partial(_grouped_step, nq=nq, bq=bq, bk=bk,
+                             steps=steps, window=window)
+
+    def kv_block(b, qi, j, bounds_ref):
+        first, last, kb = walk(bounds_ref, b, qi, j)
+        return jnp.minimum(jnp.maximum(kb, first), last)
+
     kernel = functools.partial(
-        _grouped_kernel, scale=scale, bq=bq, bk=bk, heads=heads,
-        rotary=rope is not None, window=window, sink=sinks is not None,
-        steps=steps, count=count)
+        _grouped_kernel, scale=scale, heads=heads, rotary=rope is not None,
+        sink=sinks is not None, count=count, walk=walk, bq=bq, bk=bk,
+        window=window, steps=steps)
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -561,15 +646,15 @@ def _grouped_call(q, k, v, ids, rope, num_heads, kv_heads, window, sinks,
             sinks.astype(jnp.float32).reshape(kv_heads, heads, 1),
             (kv_heads, heads, 128)))
         extra_specs.append(pl.BlockSpec((1, heads, 128),
-                                        lambda b, g, qi, j: (g, 0, 0)))
+                                        lambda b, g, qi, j, _: (g, 0, 0)))
     if rope is not None:
         extra += [*rope, *rope]
         extra_specs += 2 * [pl.BlockSpec((1, bq, D),
-                                         lambda b, g, qi, j: (b, qi, 0))]
+                                         lambda b, g, qi, j, _: (b, qi, 0))]
         extra_specs += 2 * [pl.BlockSpec(
-            (1, bk, D), lambda b, g, qi, j: (b, kv_block(qi, j), 0))]
+            (1, bk, D), lambda b, g, qi, j, s: (b, kv_block(b, qi, j, s), 0))]
     out_specs = [pl.BlockSpec((1, bq, heads * Dv),
-                              lambda b, g, qi, j: (b, qi, g))]
+                              lambda b, g, qi, j, _: (b, qi, g))]
     out_shape = [jax.ShapeDtypeStruct((B, L, num_heads * Dv), q.dtype)]
     scratch = [
         pltpu.VMEM((bq, heads * D), q.dtype),        # the rotated q
@@ -579,31 +664,35 @@ def _grouped_call(q, k, v, ids, rope, num_heads, kv_heads, window, sinks,
     ]
     if count:
         out_specs.append(pl.BlockSpec((1, bq, 1),
-                                      lambda b, g, qi, j: (b, qi, 0)))
+                                      lambda b, g, qi, j, _: (b, qi, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, L, 1), jnp.float32))
         scratch.append(pltpu.VMEM((bq, 128), jnp.float32))  # keys attended
     out = pl.pallas_call(
         kernel,
-        grid=(B, kv_heads, L // bq, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1), lambda b, g, qi, j: (b, qi, 0)),
-            pl.BlockSpec((1, 1, bk),
-                         lambda b, g, qi, j: (b, 0, kv_block(qi, j))),
-            *extra_specs,
-            pl.BlockSpec((1, bq, heads * D), lambda b, g, qi, j: (b, qi, g)),
-            pl.BlockSpec((1, bk, D),
-                         lambda b, g, qi, j: (b, kv_block(qi, j), g)),
-            pl.BlockSpec((1, bk, Dv),
-                         lambda b, g, qi, j: (b, kv_block(qi, j), g)),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, kv_heads, nq, steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, 1), lambda b, g, qi, j, _: (b, qi, 0)),
+                pl.BlockSpec((1, 1, bk), lambda b, g, qi, j, s: (
+                    b, 0, kv_block(b, qi, j, s))),
+                *extra_specs,
+                pl.BlockSpec((1, bq, heads * D),
+                             lambda b, g, qi, j, _: (b, qi, g)),
+                pl.BlockSpec((1, bk, D), lambda b, g, qi, j, s: (
+                    b, kv_block(b, qi, j, s), g)),
+                pl.BlockSpec((1, bk, Dv), lambda b, g, qi, j, s: (
+                    b, kv_block(b, qi, j, s), g)),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
         out_shape=out_shape,
         # the op's name in a profile: a per-layer metric reads each kernel
         name="window_attention" if window else "grouped_attention",
-        scratch_shapes=scratch,
         interpret=interpret,
         **kwargs,
-    )(ids[:, :, None], ids[:, None, :], *extra, q, k, v)
+    )(bounds.reshape(-1), ids[:, :, None], ids[:, None, :], *extra, q, k, v)
     return tuple(out) if count else out[0]
 
 
@@ -936,13 +1025,15 @@ def packed_attention(
     window: int = 0,
     sinks: jax.Array | None = None,
     count_keys: bool = False,
+    padding_id: int | None = None,
 ):
     """Causal attention inside the segments of packed rows, forward only,
     -> [B, L, num_heads * Dv] in q.dtype: token i sees token j iff
     `segment_ids[b, i] == segment_ids[b, j]` and `j <= i`; scores scaled by
     `scale`, 1 / sqrt(D) when None (a caller whose heads are zero-padded to
-    the lanes gives the scale of the width before padding). Padding carries an id no segment has, so it keeps itself company and
-    no softmax row is empty. With `rope`, q and k are turned inside the
+    the lanes gives the scale of the width before padding). Padding carries
+    an id no segment has, so it keeps itself company and no softmax row is
+    empty. With `rope`, q and k are turned inside the
     kernel first (float32, cast back to their dtype, as `layers.rope` does):
     done outside, the turned q and k are two more [B, L, heads * D] arrays
     written and read per call, in a layout the kernel cannot take.
@@ -964,7 +1055,16 @@ def packed_attention(
     softmax normaliser and attends to nothing. L must then be a multiple
     of 128. With `count_keys` (the grouped form only) the call returns
     (out, keys): keys int32 [B, L], how many keys each query's softmax
-    took, counted in the kernel from the mask it applied."""
+    took, counted in the kernel from the mask it applied.
+
+    The grouped form walks, for each query block, only the key blocks from
+    the first whose ids can meet its own to the diagonal (`grouped_steps`
+    counts them), which leaves every output as a walk of all blocks gives
+    it. Given the padding's id (`padding_id`, the grouped form only; it
+    does not make a call grouped), a query block that is all padding walks
+    nothing: its context and its count are 0, where a walk of its own
+    keys would give padding's mean value. A padding query in a block with
+    real ones keeps itself company as before."""
     B, L, HD = q.shape
     D, rem = divmod(HD, num_heads)
     Dv, vrem = divmod(v.shape[-1], num_heads)
@@ -997,7 +1097,7 @@ def packed_attention(
             q, k, v, segment_ids.astype(jnp.int32), rope, num_heads,
             kv_heads, int(window), sinks,
             1.0 / math.sqrt(D) if scale is None else scale, interpret,
-            count_keys)
+            count_keys, padding_id)
         if count_keys:
             out, keys = out
             return out, keys[..., 0].astype(jnp.int32)

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import importlib
 import sys
 from pathlib import Path
 
@@ -46,6 +47,9 @@ from symbiont_tpu.models.bert import Segments  # noqa: E402
 from symbiont_tpu.models.layers import rmsnorm  # noqa: E402
 from symbiont_tpu.ops.flash_attention import packed_attention  # noqa: E402
 from symbiont_tpu.utils.telemetry import metrics  # noqa: E402
+
+# the module (the package exports a function of its name)
+fa = importlib.import_module("symbiont_tpu.ops.flash_attention")
 
 PATTERN = [0, 1, 1, 1, 1, 0] + [1, 1, 1, 1, 1, 0] * 7
 MODEL = {
@@ -150,7 +154,22 @@ KERNEL_CASES = [
     (8, 8, 16, True, 384, (100, 37, 240)),  # window + sink, 8 KV heads
     (8, 4, 128, True, 512, (300, 190)),  # the published window, 4 KV heads
     (8, 2, 0, True, 256, (256,)),  # one passage filling the row, a sink
+    # whole query blocks of padding: the passages meet mid-block, padding
+    # starts on a block's edge (so every padding row is in such a block)
+    (8, 4, 0, False, 1024, (300, 212)),  # full: two blocks of 256 padding
+    (8, 4, 128, True, 640, (200, 184)),  # window + sink: two of 128
+    # ... or mid-block, whole padding blocks after it
+    (8, 2, 0, True, 1024, (100, 333)),  # full with a sink
+    (8, 8, 16, False, 512, (50, 70)),  # window, no sink: three of 128
 ]
+PAD = 8  # the padding's id `_segments` gives (S)
+
+
+def _padding_blocks(ids, L, window):
+    """bool [L]: the tokens of query blocks (the grouped kernel's) that are
+    all padding."""
+    bq = fa._grouped_tiling(L, window)[0]
+    return np.repeat((ids.reshape(-1, bq) == PAD).all(1), bq)
 
 
 @pytest.mark.parametrize("nh, nkv, window, sink, L, lens", KERNEL_CASES)
@@ -160,7 +179,8 @@ def test_the_grouped_kernel_is_a_dense_softmax(nh, nkv, window, sink, L,
     the program's 256 lanes with 64 dims turned (`mimo.lane_of`,
     `mimo.rope_lanes`), against a dense float32 softmax over the published
     head layout with HF's partial rotary: GQA, the window, the sink and
-    packed passages that start mid-block."""
+    packed passages that start mid-block. A query block that is all
+    padding writes exactly 0."""
     rng = np.random.default_rng(L + nkv + window)
     D, Dv, rot = 192, 128, 64
     cfg = mimo.MimoConfig(head_dim=D, partial_rotary_factor=rot / D)
@@ -188,12 +208,15 @@ def test_the_grouped_kernel_is_a_dense_softmax(nh, nkv, window, sink, L,
         jnp.asarray(v.reshape(1, L, nkv * Dv)), seg.index, nh,
         rope=tables, kv_heads=nkv, window=window,
         sinks=None if sinks is None else jnp.asarray(sinks),
-        scale=1 / np.sqrt(D))
+        scale=1 / np.sqrt(D), padding_id=PAD)
     want = _dense(q, k, v, ids, nh, nkv, window, sinks, turn, 1 / np.sqrt(D))
     got = np.asarray(got)[0].reshape(L, nh, Dv)
     real = ids < len(lens)
     err = np.abs(got[real] - want[real]).max() / np.abs(want[real]).max()
     assert err < 1e-5, err
+    padding = _padding_blocks(ids, L, window)
+    assert padding.any() == (sum(lens) <= L - 128)
+    np.testing.assert_array_equal(got[padding], 0.0)
 
 
 @pytest.mark.parametrize("nh, nkv, window, sink, L, lens", KERNEL_CASES)
@@ -202,7 +225,8 @@ def test_the_kernel_counts_the_keys_its_mask_keeps(nh, nkv, window, sink, L,
     """`count_keys`: each query's keys as the kernel's own mask keeps them
     (passage, causality, window), the output as it is without the count;
     the window a call did not get is seen in the count (the full causal
-    prefix, which `window_keys_kept_pct` reads as 100)."""
+    prefix, which `window_keys_kept_pct` reads as 100). A query block that
+    is all padding counts 0."""
     rng = np.random.default_rng(L + nh)
     D = 128
     q = jnp.asarray(rng.standard_normal((1, L, nh * D)), jnp.float32)
@@ -213,12 +237,16 @@ def test_the_kernel_counts_the_keys_its_mask_keeps(nh, nkv, window, sink, L,
     i, j = np.arange(L)[:, None], np.arange(L)[None]
     causal = (ids[:, None] == ids[None, :]) & (j <= i)
     for w in sorted({window, 0}):
-        kw = dict(kv_heads=nkv, window=w, sinks=sinks)
+        kw = dict(kv_heads=nkv, window=w, sinks=sinks, padding_id=PAD)
         plain = packed_attention(q, k, k, seg.index, nh, **kw)
         out, keys = packed_attention(q, k, k, seg.index, nh, count_keys=True,
                                      **kw)
         keep = causal & (j > i - w) if w else causal
-        np.testing.assert_array_equal(np.asarray(keys)[0], keep.sum(1))
+        padding = _padding_blocks(ids, L, w)
+        keys = np.asarray(keys)[0]
+        real = ~padding
+        np.testing.assert_array_equal(keys[real], keep.sum(1)[real])
+        np.testing.assert_array_equal(keys[padding], 0)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
 
 
@@ -253,9 +281,9 @@ def test_a_window_grid_holds_only_the_blocks_it_reaches(monkeypatch):
     seen = []
     real = pl.pallas_call
 
-    def spy(kernel, *a, grid=None, **kw):
-        seen.append((kw.get("name"), grid))
-        return real(kernel, *a, grid=grid, **kw)
+    def spy(kernel, *a, **kw):
+        seen.append((kw.get("name"), kw["grid_spec"].grid))
+        return real(kernel, *a, **kw)
 
     monkeypatch.setattr(pl, "pallas_call", spy)
     L, D = 4096, 128
@@ -269,15 +297,87 @@ def test_a_window_grid_holds_only_the_blocks_it_reaches(monkeypatch):
                     ("grouped_attention", (1, 1, 16, 8))]
 
 
+def _steps_by_hand(ids, L, window):
+    """(key steps that compute, steps in reach) of the grouped kernel over
+    one row, counted pair by pair: a step computes where its query block
+    is not all padding and some query and key of the two blocks share a
+    passage; it is in reach where some key of the block is one a query of
+    the query block can see (causal, and inside the window)."""
+    bq, bk, _ = fa._grouped_tiling(L, window)
+    run = reach = 0
+    for qi in range(L // bq):
+        rows = np.arange(qi * bq, (qi + 1) * bq)[:, None]
+        for kb in range(L // bk):
+            cols = np.arange(kb * bk, (kb + 1) * bk)[None]
+            seen = cols <= rows
+            if window:
+                seen &= cols > rows - window
+            if not seen.any():
+                continue
+            reach += 1
+            qids, kids = ids[rows[:, 0]], ids[cols[0]]
+            run += bool((qids != PAD).any()
+                        and (qids[:, None] == kids[None]).any())
+    return run, reach
+
+
+@pytest.mark.parametrize("window", [0, 128])
+def test_the_bounds_walk_the_blocks_that_share_a_passage(window):
+    """`grouped_steps` (the bounds the kernel is handed) against a count
+    of the block pairs, at 4,096 tokens of two passages that meet
+    mid-block and 1,596 of padding: full attention walks 24 of the 72
+    steps under its diagonal."""
+    L = 4096
+    seg = _segments((1000, 1500), L)
+    ids = np.asarray(seg.index)[0]
+    got = np.asarray(fa.grouped_steps(seg.index, window, padding_id=PAD))
+    want = _steps_by_hand(ids, L, window)
+    assert tuple(got[0]) == want
+    if not window:
+        assert want == (24, 72)
+    # without the padding's id, a block of padding walks its own keys
+    assert fa.grouped_steps(seg.index, window)[0, 0] > want[0]
+
+
+def test_the_family_books_the_full_kernels_steps(checkpoint):
+    """`mimo.full_steps` over the layout above, through `_note_mimo`: the
+    steps of the two full layers' one KV head, as counted by hand."""
+    _, _, cfg = checkpoint
+    seg = _segments((1000, 1500), 4096)
+    run, reach = _steps_by_hand(np.asarray(seg.index)[0], 4096, 0)
+    full = sum(not cfg.is_window(i) for i in range(cfg.num_layers))
+    assert (full, cfg.num_kv_heads) == (2, 1)
+    width = max(cfg.held, 4)
+    aux = np.zeros((2, width), np.int32)  # no expert layer, one batch row
+    aux[1, 2:4] = np.asarray(mimo.full_steps(seg, cfg))[0]
+    snap = metrics.flat_snapshot()
+    families._note_mimo(aux)
+    after = metrics.flat_snapshot()
+
+    def grew(name):
+        key = "counter." + name + '{service="engine"}'
+        return after.get(key, 0) - snap.get(key, 0)
+
+    assert grew("engine.attn.block_steps_run") == full * run
+    assert grew("engine.attn.block_steps_causal") == full * reach
+    assert grew("engine.attn.window_keys") == 0
+
+
 # ------------------------------------------------------------- the stack
 
 def test_full_forward_matches_reference(checkpoint, passages, want):
     """Through the kernel (a 512-token row) and the einsum form (a row that
     is not whole 128-token blocks) alike."""
     _, params, cfg = checkpoint
+    steps = {}
     for L in (512, 300):
         got, aux = _embed(params, cfg, passages, L)
         assert _rel(got, want).max() < TOL, L
+        steps[L] = tuple(aux[1 + aux[0, 3], 2:4])
+    # the full kernel's steps: two full layers of one KV head, two query
+    # blocks of 256 against one key block, both holding real tokens; the
+    # einsum form takes none
+    assert steps == {512: (4, 4), 300: (0, 0)}
     routed, windows, held, layers = aux[0, :4]
     assert (windows, held, layers) == (5, 2, 6)
     assert routed == sum(LENS) * 4 * 6  # every real token's 4 choices
@@ -305,10 +405,6 @@ def test_the_tolerance_sees_a_wrong_attention(checkpoint, passages, want,
     planted in the kernel's entry: every passage moves far outside the
     tolerance, and with the window off the window layers' keys are the
     causal keys (`window_keys_kept_pct` 100)."""
-    import importlib
-
-    # the package exports a function of the module's name
-    fa = importlib.import_module("symbiont_tpu.ops.flash_attention")
     real = fa.packed_attention
 
     def broken(*a, window=0, sinks=None, **kw):
@@ -451,6 +547,8 @@ def test_engine_takes_the_family_from_the_checkpoint(checkpoint):
         "engine.moe.assignments") > 0
     assert 0 < grew("engine.attn.window_keys") < grew(
         "engine.attn.keys_causal")
+    assert 0 < grew("engine.attn.block_steps_run") <= grew(
+        "engine.attn.block_steps_causal")
 
 
 # -------------------------------------- the programs other cells compile
