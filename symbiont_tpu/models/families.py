@@ -7,8 +7,9 @@ the family's forward counts on the device (mla_moe: real tokens per expert
 layer and expert; sala: keys attended, causal keys and dense-path tokens per
 row and sparse layer; ouro: each loop step's exit mass and the token-steps
 run, per row; ling: real tokens per expert layer and held expert, routed
-choices and passages started; mimo: the same expert counts and the keys the
-window layers attended beside the causal keys), and `note_aux(aux)`, which books a fetched `aux` under the
+choices, passages started and the MLA kernel's block pairs; mimo: the same
+expert counts, the keys the window layers attended beside the causal keys
+and the full kernel's block steps), and `note_aux(aux)`, which books a fetched `aux` under the
 family's own series (None where the forward counts nothing). With
 `segments` (models/bert.py `Segments`: the batched
 `embed` program's packed rows) a row holds several sentences and the rows
@@ -128,15 +129,20 @@ def _note_loop(aux) -> None:
 
 def _note_ling(aux) -> None:
     """Series of one embed dispatch of the `ling` family: `aux` [expert
-    layers + 1, held] int32 = the held experts' real-token counts by layer
+    layers + 2, held] int32 = the held experts' real-token counts by layer
     (the `mla_moe` series over held experts), then [every real token's
-    routed choices held or not, passages started x KDA layers, ...]
-    (docs/OBSERVABILITY.md)."""
+    routed choices held or not, passages started x KDA layers, ...], then
+    [the MLA kernel's block pairs that computed, the pairs under its
+    diagonal, ...] (docs/OBSERVABILITY.md)."""
     aux = np.asarray(aux, np.int64)
-    _note_moe(aux[:-1])
-    metrics.inc("engine.moe.assignments_routed", int(aux[-1, 0]),
+    _note_moe(aux[:-2])
+    metrics.inc("engine.moe.assignments_routed", int(aux[-2, 0]),
                 labels=_LABELS)
-    metrics.inc("engine.kda.state_resets", int(aux[-1, 1]), labels=_LABELS)
+    metrics.inc("engine.kda.state_resets", int(aux[-2, 1]), labels=_LABELS)
+    metrics.inc("engine.attn.block_steps_run", int(aux[-1, 0]),
+                labels=_LABELS)
+    metrics.inc("engine.attn.block_steps_causal", int(aux[-1, 1]),
+                labels=_LABELS)
 
 
 def _note_mimo(aux) -> None:

@@ -33,11 +33,13 @@ rule's state and the convolution's window reset at a passage's first token,
 positions restart there and attention stays inside the passage. Handed only
 a mask (the fused query), the row is one passage.
 
-`embed_sentences` returns, beside the rows, `aux` int32 [expert layers + 1,
-held]: a row per expert layer of the real tokens each held expert took, and
-a last row [routed choices (real tokens x k x expert layers, held or not),
-passages started x KDA layers, 0, ...]; `note_aux` (models/families.py)
-books it.
+`embed_sentences` returns, beside the rows, `aux` int32 [expert layers + 2,
+held]: a row per expert layer of the real tokens each held expert took, a
+row [routed choices (real tokens x k x expert layers, held or not),
+passages started x KDA layers, 0, ...], and a last row [the block pairs the
+MLA layers' flash kernel computed, the pairs under its diagonal, 0, ...]
+(summed over the rows, the MLA layers and their heads:
+`mla_moe.flash_steps`); `note_aux` (models/families.py) books it.
 
 Not here (ROADMAP Reach A4): a [heads, d, d] state and a convolution tail
 per sequence in the page pool, the generation path's decode step.
@@ -55,7 +57,8 @@ import jax.numpy as jnp
 from symbiont_tpu.models import quant
 from symbiont_tpu.models.bert import Segments, pool_segments
 from symbiont_tpu.models.layers import rmsnorm, swiglu
-from symbiont_tpu.models.mla_moe import MlaMoeConfig, mla_attention, moe_ffn
+from symbiont_tpu.models.mla_moe import (MlaMoeConfig, flash_steps,
+                                         mla_attention, moe_ffn)
 from symbiont_tpu.utils.telemetry import metrics
 
 Params = Any
@@ -318,7 +321,7 @@ def embed_sentences(params: Params, input_ids: jax.Array,
                     segments: Optional[Segments] = None):
     """Decoder stack + pooling -> ([B, H] float32 passage embeddings, or
     [B, S, H] for packed rows: `segments`, and `attention_mask` its `real`;
-    aux int32 [expert layers + 1, held], the module's docstring says what)."""
+    aux int32 [expert layers + 2, held], the module's docstring says what)."""
     packed = segments is not None
     if not packed:
         segments = one_passage(attention_mask)
@@ -334,8 +337,10 @@ def embed_sentences(params: Params, input_ids: jax.Array,
     routed = (segments.real.sum(dtype=jnp.int32) * cfg.num_experts_per_tok
               * counts.shape[0])
     started = (segments.lengths > 0).sum(dtype=jnp.int32) * kda_layers
-    last = jnp.zeros((1, cfg.held), jnp.int32).at[0, :2].set(
-        jnp.stack([routed, started]))
+    steps = flash_steps(segments, cfg.num_heads).sum(0) * (
+        cfg.num_layers - kda_layers)
+    last = jnp.zeros((2, cfg.held), jnp.int32).at[:, :2].set(
+        jnp.stack([jnp.stack([routed, started]), steps]))
     return pooled, jnp.concatenate([counts, last], axis=0)
 
 

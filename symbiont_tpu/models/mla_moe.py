@@ -157,6 +157,28 @@ def _deinterleave(x: jax.Array) -> jax.Array:
 LONG_ROW = 512  # packed rows longer than this attend through the kernel
 
 
+def _through_kernel(S: int) -> bool:
+    """Whether a packed row of S tokens attends through the flash kernel
+    (longer than `LONG_ROW`, whole 128-token blocks) or the einsum form."""
+    return S > LONG_ROW and S % 128 == 0
+
+
+def flash_steps(segments: Segments, num_heads: int) -> jax.Array:
+    """int32 [B, 2]: per packed row, the (q block, kv block) pairs one MLA
+    layer's flash kernel computes, summed over its heads, and the pairs
+    under the diagonal it walked before it kept a list
+    (ops/flash_attention.py `segment_steps` on the ids and padding id
+    `mla_attention` hands the kernel); 0 where the row takes the einsum
+    form."""
+    B, S = segments.index.shape
+    if not _through_kernel(S):
+        return jnp.zeros((B, 2), jnp.int32)
+    from symbiont_tpu.ops.flash_attention import segment_steps
+
+    return segment_steps(segments.index,
+                         padding_id=segments.lengths.shape[1]) * num_heads
+
+
 def _lanes(x: jax.Array) -> jax.Array:
     """[B, S, heads, d] -> [B, S, heads * d'] with d zero-padded to whole
     128-lane columns (a zero column adds nothing to a dot product)."""
@@ -177,7 +199,9 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
     tokens that tiles (a multiple of 128) attends through
     ops/flash_attention.py `packed_attention`, q.k heads zero-padded to 256
     lanes and the value heads at 128, so no [S, S] tensor reaches HBM (at
-    32 heads and 32,768 tokens the float32 scores would be 137 GB); every
+    32 heads and 32,768 tokens the float32 scores would be 137 GB), and
+    only the block pairs that can share a passage are walked
+    (`flash_steps` counts them): a block of padding's context is 0; every
     shorter row (Kimi-VL's 3-128-token sentences) takes the einsum form.
     `attn.packed{path}` says which, once per traced program."""
     B, S, _ = x.shape
@@ -190,7 +214,7 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
     c, k_rope = kva[..., :cfg.kv_lora_rank], kva[..., cfg.kv_lora_rank:]
     kv = quant.mm(rmsnorm(c, p["kv_a_ln"], cfg.rms_norm_eps),
                   p["kv_b"]["kernel"]).reshape(B, S, nh, dn + dv)
-    long = segments is not None and S > LONG_ROW and S % 128 == 0
+    long = segments is not None and _through_kernel(S)
     metrics.inc("attn.packed",
                 labels={"path": "flash_segments" if long else "dense"})
     if cfg.qk_norm:
@@ -217,7 +241,8 @@ def mla_attention(p: Params, x: jax.Array, mask: jax.Array,
             _lanes(jnp.concatenate([q_nope, q_rope], axis=-1)),
             _lanes(jnp.concatenate([k_nope, k_rope], axis=-1)),
             _lanes(kv[..., dn:]), segments.index, nh,
-            scale=1.0 / math.sqrt(dn + dr))
+            scale=1.0 / math.sqrt(dn + dr),
+            padding_id=segments.lengths.shape[1])
         ctx = ctx.reshape(B, S, nh, -1)[..., :dv]
     else:
         rope_keys = "bkhd" if k_rope.ndim == 4 else "bkd"  # a head's / shared

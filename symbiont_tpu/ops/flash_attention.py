@@ -53,7 +53,9 @@ Design:
   first whose segment ids can meet its own to the diagonal, bounds the
   wrapper computes on the device and hands over by scalar prefetch, so a
   packed row's other passage and its padding cost a grid step's overhead,
-  not its work.
+  not its work. The streaming form (a row longer than one block, MLA in
+  models/mla_moe.py) uses the same bounds, and its grid walks a list of
+  the block pairs inside them alone, so those pairs cost no step at all.
 - every way off the compiled kernel ANNOUNCES itself (`_announce`): one log
   line and one `flash.fallback{path}` bump per traced shape — the Pallas interpreter on a CPU backend, the dense route for
   untileable shapes, the dense-recompute GQA backward. `chip_smoke.py`
@@ -209,18 +211,24 @@ _PACKED_ROWS = 256
 _PACKED_HEADS = 2
 
 
-def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
-    """One (batch, head group, q block, kv block) step over packed rows,
-    forward only: `_kernel`'s arithmetic with the mask from per-token
-    segment ids and causality and, with `rotary`, RoPE on q and k first. Query rows go
-    `_PACKED_ROWS` at a time, and on the diagonal block each tile reads
-    only the keys up to its own last row: the triangle above it is neither
-    multiplied nor exponentiated.
+def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool,
+                   pairs: int = 0):
+    """One step over packed rows, forward only: `_kernel`'s arithmetic with
+    the mask from per-token segment ids and causality and, with `rotary`,
+    RoPE on q and k first. Query rows go `_PACKED_ROWS` at a time, and on
+    the diagonal block each tile reads only the keys up to its own last
+    row: the triangle above it is neither multiplied nor exponentiated.
 
     A row that is ONE block (no scratch refs) is the fast form: each tile's
     softmax is whole, so its context is written straight out, `heads` heads
     a step. Longer rows stream kv blocks through `_kernel`'s running max,
-    normaliser and accumulator, one head a step."""
+    normaliser and accumulator, one head a step: step t of a row takes
+    entry t of its list (`_segment_pairs`, `pairs` entries a row, the first
+    ref, in scalar memory), which names the (q block, kv block) pair and
+    whether the step starts the q block's state, computes, finishes it,
+    writes the zeros of a block of padding or does nothing."""
+    if pairs:
+        pairs_ref, *refs = refs
     qid_ref, kid_ref, *refs = refs
     if rotary:
         cosq_ref, sinq_ref, cosk_ref, sink_ref, *refs = refs
@@ -278,11 +286,10 @@ def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
         return
 
     m_scr, l_scr, acc_scr = scratch
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    entry = pairs_ref[pl.program_id(0) * pairs + pl.program_id(2)]
+    qi, kb = _pair_blocks(entry)
 
-    @pl.when(ki == 0)
+    @pl.when((entry & _INIT) != 0)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, _ACC_NEG, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -304,14 +311,75 @@ def _packed_kernel(*refs, scale: float, block: int, heads: int, rotary: bool):
             m_scr[rows, :] = jnp.broadcast_to(m_new, (sub, m_scr.shape[1]))
             l_scr[rows, :] = jnp.broadcast_to(l_new, (sub, l_scr.shape[1]))
 
-    # blocks above the diagonal are skipped, as `_kernel` skips them
-    pl.when(ki < qi)(lambda: _step(False))
-    pl.when(ki == qi)(lambda: _step(True))
+    walked = (entry & (_ZERO | _IDLE)) == 0
+    pl.when(walked & (kb < qi))(lambda: _step(False))
+    pl.when(walked & (kb == qi))(lambda: _step(True))
 
-    @pl.when(ki == nk - 1)
+    @pl.when((entry & _FINISH) != 0)
     def _finish():
         o_ref[0] = (acc_scr[:] * pl.reciprocal(l_scr[:, :1])
                     ).astype(o_ref.dtype)
+
+    @pl.when((entry & _ZERO) != 0)
+    def _nothing():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+# An entry of the streaming walk's list (`_segment_pairs`), one int32: the
+# q block in the low bits, the kv block above it, then what the step does.
+_PAIR_BITS = 12  # 4,096 blocks; scalar memory holds the list of ~720
+_PAIR_MASK = (1 << _PAIR_BITS) - 1
+_INIT, _FINISH, _ZERO, _IDLE = (1 << (2 * _PAIR_BITS + i) for i in range(4))
+
+
+def _pair_blocks(entry):
+    """(q block, kv block) of an entry; the kernel and its index maps both
+    read them."""
+    return entry & _PAIR_MASK, (entry >> _PAIR_BITS) & _PAIR_MASK
+
+
+def _segment_pairs(ids, block: int, padding_id):
+    """int32 [B, nb (nb + 1) / 2] from the ids [B, L] (nb = L / block):
+    each row's list of the (q block, kv block) pairs the streaming kernel
+    computes, q block by q block, each from its first kv block
+    (`_grouped_bounds`) up to the diagonal. Its length is the worst case, a
+    row that is one passage. A q block that is all `padding_id` takes one
+    entry that writes zeros, named with the kv block of the entry before
+    it (the last q block walked, whose q and kv blocks are then still
+    held: nothing is fetched for it); past the last entry the list repeats
+    that entry, idle."""
+    B, L = ids.shape
+    nb = L // block
+    bounds = _grouped_bounds(ids, block, block, 0, padding_id)
+    first, last = bounds[..., 0], bounds[..., 1]
+    empty = first > last
+    n = jnp.where(empty, 1, last - first + 1)  # entries of each q block
+    start = jnp.cumsum(n, axis=1) - n
+    t = jnp.arange(nb * (nb + 1) // 2, dtype=jnp.int32)
+    at = jnp.minimum(t, n.sum(1, keepdims=True) - 1)  # [B, P]
+    qi = (start[:, None, :] <= at[..., None]).sum(-1, dtype=jnp.int32) - 1
+    j = at - jnp.take_along_axis(start, qi, 1)
+    q_first, q_n, q_empty = (jnp.take_along_axis(x, qi, 1)
+                             for x in (first, n, empty))
+    held = jax.lax.cummax(jnp.where(empty, 0, jnp.arange(nb)), axis=1)
+    kb = jnp.where(q_empty, jnp.take_along_axis(held, qi, 1), q_first + j)
+    flags = (jnp.where(q_empty, _ZERO, jnp.where(j == 0, _INIT, 0)
+                       | jnp.where(j == q_n - 1, _FINISH, 0)))
+    flags = jnp.where(t > at, _IDLE, flags)
+    return (qi | kb << _PAIR_BITS | flags).astype(jnp.int32)
+
+
+def segment_steps(segment_ids: jax.Array, block: int = 512,
+                  padding_id: int | None = None) -> jax.Array:
+    """int32 [B, 2] for `packed_attention`'s streaming form (rows longer
+    than one block) over rows with these ids [B, L]: for each head, the
+    (q block, kv block) pairs its kernel computes (the entries of
+    `_segment_pairs` that compute) and the pairs under the diagonal, which
+    it walked before it kept a list."""
+    ids = segment_ids.astype(jnp.int32)
+    pairs = _segment_pairs(ids, _pick_block(ids.shape[1], block), padding_id)
+    run = ((pairs & (_ZERO | _IDLE)) == 0).sum(1, dtype=jnp.int32)
+    return jnp.stack([run, jnp.full_like(run, pairs.shape[1])], axis=1)
 
 
 def _flash_call(q, k, v, bias, causal, scale, block_q, block_k, interpret):
@@ -358,7 +426,7 @@ def _flash_call(q, k, v, bias, causal, scale, block_q, block_k, interpret):
 
 
 def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
-                 scale=None):
+                 scale=None, padding_id=None):
     """`_packed_kernel` over packed rows in the projections' own layout: q,
     k [B, L, heads * D] and v [B, L, heads * Dv], head h the column block h
     (D and Dv multiples of the 128 lanes), the context written as v is, so
@@ -366,33 +434,30 @@ def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
     column [block, 1] of the queries' ids and a row [1, block] of the keys';
     `rope` = (cos, sin) [B, L, D] float32 likewise, by query rows and by key
     rows (a block whose index the step before had is not fetched again: the
-    tables move once a row, not once a head). Streaming, a step above the
-    diagonal asks for the diagonal's key block again, so the blocks it
-    skips are not fetched either."""
+    tables move once a row, not once a head). A row longer than one block
+    streams (`_segment_call`)."""
     B, L, HD = q.shape
     D = HD // num_heads
     Dv = v.shape[2] // num_heads
-    single = L == block  # one block a row: no state between steps
-    heads = _PACKED_HEADS if single and num_heads % _PACKED_HEADS == 0 else 1
-    kernel = functools.partial(
-        _packed_kernel, scale=1.0 / math.sqrt(D) if scale is None else scale,
-        block=block, heads=heads, rotary=rope is not None)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
+    if L != block:
+        return _segment_call(q, k, v, ids, rope, num_heads, block, interpret,
+                             scale, padding_id)
+    heads = _PACKED_HEADS if num_heads % _PACKED_HEADS == 0 else 1
+    kernel = functools.partial(_packed_kernel, scale=scale, block=block,
+                               heads=heads, rotary=rope is not None)
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     qspec = pl.BlockSpec((1, block, heads * D),
                          lambda b, h, qi, ki: (b, qi, h))
-    if single:
-        kspec = pl.BlockSpec((1, block, heads * D),
-                             lambda b, h, qi, ki: (b, ki, h))
-    else:
-        kspec = pl.BlockSpec((1, block, heads * D),
-                             lambda b, h, qi, ki: (b, jnp.minimum(ki, qi), h))
+    kspec = pl.BlockSpec((1, block, heads * D),
+                         lambda b, h, qi, ki: (b, ki, h))
     vspec, ospec = kspec, qspec
     if Dv != D:
         vspec = pl.BlockSpec((1, block, heads * Dv),
-                             lambda b, h, qi, ki: (b, jnp.minimum(ki, qi), h))
+                             lambda b, h, qi, ki: (b, ki, h))
         ospec = pl.BlockSpec((1, block, heads * Dv),
                              lambda b, h, qi, ki: (b, qi, h))
     tables, table_specs = (), []
@@ -404,7 +469,7 @@ def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
                                          lambda b, h, qi, ki: (b, ki, 0))]
     return pl.pallas_call(
         kernel,
-        grid=(B, num_heads // heads, L // block, L // block),
+        grid=(B, num_heads // heads, 1, 1),
         in_specs=[
             pl.BlockSpec((1, block, 1), lambda b, h, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, 1, block), lambda b, h, qi, ki: (b, 0, ki)),
@@ -412,14 +477,75 @@ def _packed_call(q, k, v, ids, rope, num_heads, block, interpret,
         ],
         out_specs=ospec,
         out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
-        scratch_shapes=[] if single else [
-            pltpu.VMEM((block, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((block, 128), jnp.float32),  # running normalizer
-            pltpu.VMEM((block, Dv), jnp.float32),   # output accumulator
-        ],
+        scratch_shapes=[],
         interpret=interpret,
         **kwargs,
     )(ids[:, :, None], ids[:, None, :], *tables, q, k, v)
+
+
+def _segment_call(q, k, v, ids, rope, num_heads, block, interpret, scale,
+                  padding_id):
+    """`_packed_call`'s streaming form, one head a step: the grid is (B,
+    heads, P), and step t of a row takes entry t of its list of the
+    (q block, kv block) pairs that can compute (`_segment_pairs`, computed
+    here on the device and handed over in scalar memory, P = nb (nb + 1) / 2
+    entries a row, the worst case). q, its ids and tables, and the context
+    go by the entry's q block, k, v, theirs by its kv block; the context of
+    a q block is written once, after its last pair. An entry of padding
+    asks for the q and kv blocks its predecessor held, and the idle entries
+    at the list's end repeat the last one, so neither fetches anything.
+    The list takes B x P x 4 bytes of the 1 MiB of scalar memory: 8,320 at
+    [1, 32768] in 512-token blocks; the compiler refuses a call past ~126
+    such rows."""
+    B, L, HD = q.shape
+    D, Dv = HD // num_heads, v.shape[2] // num_heads
+    pairs = _segment_pairs(ids, block, padding_id)
+    P = pairs.shape[1]
+
+    def blocks(b, t, pairs_ref):
+        """(q block fetched, kv block, context block) of entry t of row b."""
+        entry = pairs_ref[b * P + t]
+        qi, kb = _pair_blocks(entry)
+        return jnp.where((entry & _ZERO) != 0, kb, qi), kb, qi
+
+    def spec(width, which, head=True):
+        return pl.BlockSpec((1, block, width), lambda b, h, t, s: (
+            b, blocks(b, t, s)[which], h if head else 0))
+
+    tables, table_specs = (), []
+    if rope is not None:
+        tables = (*rope, *rope)
+        table_specs = 2 * [spec(D, 0, False)] + 2 * [spec(D, 1, False)]
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    kernel = functools.partial(_packed_kernel, scale=scale, block=block,
+                               heads=1, rotary=rope is not None, pairs=P)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, num_heads, P),
+            in_specs=[
+                spec(1, 0, False),
+                pl.BlockSpec((1, 1, block), lambda b, h, t, s: (
+                    b, 0, blocks(b, t, s)[1])),
+                *table_specs, spec(D, 0), spec(D, 1), spec(Dv, 1),
+            ],
+            out_specs=spec(Dv, 2),
+            scratch_shapes=[
+                pltpu.VMEM((block, 128), jnp.float32),  # running max (lane-replicated)
+                pltpu.VMEM((block, 128), jnp.float32),  # running normalizer
+                pltpu.VMEM((block, Dv), jnp.float32),   # output accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
+        # the op's name in a profile
+        name="segment_attention",
+        interpret=interpret,
+        **kwargs,
+    )(pairs.reshape(-1), ids[:, :, None], ids[:, None, :], *tables, q, k, v)
 
 
 # How the grouped kernel tiles a row: full attention takes 256 query rows
@@ -1057,14 +1183,16 @@ def packed_attention(
     (out, keys): keys int32 [B, L], how many keys each query's softmax
     took, counted in the kernel from the mask it applied.
 
-    The grouped form walks, for each query block, only the key blocks from
-    the first whose ids can meet its own to the diagonal (`grouped_steps`
-    counts them), which leaves every output as a walk of all blocks gives
-    it. Given the padding's id (`padding_id`, the grouped form only; it
-    does not make a call grouped), a query block that is all padding walks
-    nothing: its context and its count are 0, where a walk of its own
-    keys would give padding's mean value. A padding query in a block with
-    real ones keeps itself company as before."""
+    The grouped form and the streaming one (a row longer than one block)
+    walk, for each query block, only the key blocks from the first whose
+    ids can meet its own to the diagonal (`grouped_steps` and
+    `segment_steps` count them), which leaves every output as a walk of
+    all blocks gives it; the streaming form's grid is a list of those
+    pairs alone. Given the padding's id (`padding_id`; it does not make a
+    call grouped), a query block that is all padding walks nothing: its
+    context and its count are 0, where a walk of its own keys would give
+    padding's mean value. A padding query in a block with real ones keeps
+    itself company as before."""
     B, L, HD = q.shape
     D, rem = divmod(HD, num_heads)
     Dv, vrem = divmod(v.shape[-1], num_heads)
@@ -1103,5 +1231,5 @@ def packed_attention(
             return out, keys[..., 0].astype(jnp.int32)
         return out
     return _packed_call(q, k, v, segment_ids.astype(jnp.int32), rope,
-                        num_heads, block, interpret, scale)
+                        num_heads, block, interpret, scale, padding_id)
 

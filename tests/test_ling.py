@@ -126,12 +126,15 @@ def test_full_forward_matches_reference(checkpoint, passages, want):
     _, params, cfg = checkpoint
     got, aux = _embed(params, cfg, passages)
     assert _rel(got, want).max() < TOL
-    # aux: held experts' counts by expert layer, then [routed, resets]
-    counts, last = aux[:-1], aux[-1]
+    # aux: held experts' counts by expert layer, then [routed, resets],
+    # then the MLA kernel's block pairs (none: a 512-token row takes the
+    # einsum form)
+    counts, last, pairs = aux[:-2], aux[-2], aux[-1]
     assert counts.shape == (6, 8)
     assert last[0] == sum(LENS) * 4 * 6  # every real token's 4 choices
     assert last[1] == len(LENS) * 7  # passages x KDA layers
     assert 0 < counts.sum() < last[0]
+    assert not pairs.any()
 
 
 def test_packed_rows_equal_each_passage_alone(checkpoint, passages):
@@ -390,6 +393,55 @@ def test_long_rows_attend_through_the_kernel(checkpoint):
             a += n
     assert metrics.flat_snapshot().get(
         'counter.attn.packed{path="flash_segments"}', 0) == before + 1
+
+
+def test_the_family_books_the_mla_kernels_pairs(checkpoint, passages):
+    """A row of 1,024 tokens streams MLA through the kernel in two blocks of
+    512: the passages fill the first, the second is padding. `_note_ling`
+    books the pairs the kernel computed (one a head) against the three
+    under the diagonal, over the 4 heads of the one MLA layer."""
+    _, params, cfg = checkpoint
+    _, aux = _embed(params, cfg, passages, L=1024)
+    snap = metrics.flat_snapshot()
+    families._note_ling(aux)
+    after = metrics.flat_snapshot()
+
+    def grew(name):
+        key = "counter." + name + '{service="engine"}'
+        return after.get(key, 0) - snap.get(key, 0)
+
+    run = grew("engine.attn.block_steps_run")
+    causal = grew("engine.attn.block_steps_causal")
+    assert 0 < run <= causal
+    assert (run, causal) == (1 * 4, 3 * 4)
+    assert grew("engine.kda.state_resets") == len(LENS) * 7
+
+
+def _reader(name):
+    import importlib.util
+
+    sys.path.insert(0, str(BENCH / "layer_metrics"))
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_"), BENCH / "layer_metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_the_step_share_reader_reads_the_counters():
+    """`mla_steps_run_pct.ingest_ling` on a made-up window: the page's
+    4,250 pairs that compute of 8,320 under the diagonal, a head, three
+    pages of 32 heads; nothing where the program books no pairs."""
+    def ctx(run, causal):
+        return {"snap0": {"counters": {}}, "snap1": {"counters": {
+            'engine.attn.block_steps_run{service="engine"}': run,
+            'engine.attn.block_steps_causal{service="engine"}': causal}}}
+
+    read = _reader("mla_steps_run_pct.ingest_ling")
+    assert read(ctx(3 * 32 * 4250, 3 * 32 * 8320)) == pytest.approx(
+        51.0817307692)
+    assert read(ctx(0, 0)) is None
+    assert read({"snap0": {"counters": {}}, "snap1": {"counters": {}}}) is None
 
 
 # ------------------------------------------------------- config and seams

@@ -297,13 +297,14 @@ def test_a_window_grid_holds_only_the_blocks_it_reaches(monkeypatch):
                     ("grouped_attention", (1, 1, 16, 8))]
 
 
-def _steps_by_hand(ids, L, window):
+def _steps_by_hand(ids, L, window, blocks=None):
     """(key steps that compute, steps in reach) of the grouped kernel over
-    one row, counted pair by pair: a step computes where its query block
-    is not all padding and some query and key of the two blocks share a
-    passage; it is in reach where some key of the block is one a query of
-    the query block can see (causal, and inside the window)."""
-    bq, bk, _ = fa._grouped_tiling(L, window)
+    one row (or of a kernel with `blocks` = (bq, bk)), counted pair by
+    pair: a step computes where its query block is not all padding and
+    some query and key of the two blocks share a passage; it is in reach
+    where some key of the block is one a query of the query block can see
+    (causal, and inside the window)."""
+    bq, bk = blocks or fa._grouped_tiling(L, window)[:2]
     run = reach = 0
     for qi in range(L // bq):
         rows = np.arange(qi * bq, (qi + 1) * bq)[:, None]
@@ -337,6 +338,53 @@ def test_the_bounds_walk_the_blocks_that_share_a_passage(window):
         assert want == (24, 72)
     # without the padding's id, a block of padding walks its own keys
     assert fa.grouped_steps(seg.index, window)[0, 0] > want[0]
+
+
+@pytest.mark.parametrize("lens, L", [
+    ((1000, 1500), 4096),  # passages meet mid-block, blocks of padding
+    ((29816,), 32768), ((21677, 8590), 32768),  # the ingest_longdocs page
+    ((17592, 14557), 32768), ((11814,), 32768),
+    ((4096,), 4096),  # one passage: every pair, no idle tail
+])
+def test_the_segment_list_walks_the_pairs_that_share_a_passage(lens, L):
+    """The streaming kernel's list (`_segment_pairs`, 512 x 512 blocks) and
+    `segment_steps` against the pairs counted by hand: the pairs that
+    compute, each once, q block by q block, kv blocks ascending to the
+    diagonal; one entry that writes zeros a block of padding, naming the
+    kv block of the entry before it; then idle entries that repeat the
+    last. The page's four rows walk 1,770 / 1,116 / 1,064 / 300 of 2,080
+    pairs (51.08% of its 8,320)."""
+    seg = _segments(lens, L)
+    ids = np.asarray(seg.index)[0]
+    run, reach = _steps_by_hand(ids, L, 0, blocks=(512, 512))
+    got = np.asarray(fa.segment_steps(seg.index, padding_id=PAD))[0]
+    nb = L // 512
+    assert tuple(got) == (run, reach) == (run, nb * (nb + 1) // 2)
+    if L == 32768:
+        assert run == {29816: 1770, 21677: 1116, 17592: 1064,
+                       11814: 300}[lens[0]]
+    entries = np.asarray(fa._segment_pairs(seg.index, 512, PAD))[0]
+    qi, kb = fa._pair_blocks(entries)
+    flags = entries & ~((1 << 2 * fa._PAIR_BITS) - 1)
+    walked = (entries & (fa._ZERO | fa._IDLE)) == 0
+    assert walked.sum() == run
+    pairs = list(zip(qi[walked], kb[walked]))
+    assert pairs == sorted(set(pairs)) and all(k <= q for q, k in pairs)
+    blocks = ids.reshape(-1, 512)
+    for q, k in pairs:
+        assert np.isin(blocks[q], blocks[k]).any()
+    assert ((flags & fa._INIT) != 0).sum() == len(set(qi[walked]))
+    assert ((flags & fa._FINISH) != 0).sum() == len(set(qi[walked]))
+    zero = (flags & fa._ZERO) != 0
+    assert sorted(qi[zero]) == list(np.flatnonzero((blocks == PAD).all(1)))
+    idle = np.flatnonzero((flags & fa._IDLE) != 0)
+    assert list(idle) == list(range(len(entries) - len(idle), len(entries)))
+    # an entry that walks nothing asks for the blocks of the one before it
+    for t in np.flatnonzero(zero)[np.flatnonzero(zero) > 0]:
+        assert kb[t] == kb[t - 1]
+    if len(idle):
+        last = idle[0] - 1
+        assert (qi[idle] == qi[last]).all() and (kb[idle] == kb[last]).all()
 
 
 def test_the_family_books_the_full_kernels_steps(checkpoint):
@@ -557,7 +605,7 @@ def _ling_mla_kernel():
     q = jax.ShapeDtypeStruct((1, 1024, 4 * 256), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, 1024, 4 * 128), jnp.bfloat16)
     return jax.jit(lambda q, k, v, i: packed_attention(
-        q, k, v, i, 4, interpret=True, scale=0.125)).lower(
+        q, k, v, i, 4, interpret=True, scale=0.125, padding_id=128)).lower(
             q, q, v, jax.ShapeDtypeStruct((1, 1024), jnp.int32))
 
 
@@ -594,13 +642,16 @@ def _kimi_experts():
 
 
 # sha256 (first 12 hex digits) of each program's lowered text on the tree
-# before the `mimo` family (Ling's MLA call of the packed kernel, which the
-# grouped form leaves alone; Ling's and Kimi-VL's expert layers, whose
+# before the `mimo` family (Ling's and Kimi-VL's expert layers, whose
 # helpers it calls), read with this repository's jax 0.9.0: another jax
 # lowers other text, and the digests are then taken again from that tree
-# with the same calls. Ouro's kernel calls are held in tests/test_ling.py.
+# with the same calls. Ling's MLA call of the packed kernel is held to the
+# text of the tree where its streaming form took the list of block pairs
+# (`padding_id` given, as models/mla_moe.py gives it; the walk of every
+# pair lowered to 045efc5e38ed). Ouro's kernel calls are held in
+# tests/test_ling.py.
 PARENT = {
-    "ling_mla_kernel_1x1024": ("0.9.0", "045efc5e38ed"),
+    "ling_mla_kernel_1x1024": ("0.9.0", "528575baacf4"),
     "ling_experts_1x16384": ("0.9.0", "9282dffdef12"),
     "kimi_experts_32x128": ("0.9.0", "3478431e2832"),
 }

@@ -2,7 +2,9 @@
 same kernel code path that compiles on TPU)."""
 
 import dataclasses
+import functools
 import hashlib
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +18,9 @@ from symbiont_tpu.ops.flash_attention import (
     flash_attention,
     packed_attention,
 )
+
+# the module itself: the package's `flash_attention` names the function
+fa = importlib.import_module("symbiont_tpu.ops.flash_attention")
 
 
 def _rand_qkv(key, B, NH, NKV, Sq, Sk, D, dtype=jnp.float32):
@@ -274,6 +279,72 @@ def test_packed_rows_match_dense_with_the_same_mask(case, rotary, block, L,
     tol = 2e-5 if dtype == jnp.float32 else 0.03
     np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol,
                                atol=tol)
+
+
+STREAMING_CASES = {  # segment lengths of the two rows of 768 tokens
+    "one_segment_fills_the_row": [(768,), (768,)],
+    "a_boundary_inside_a_block": [(300, 400), (500,)],
+    "three_segments_and_padding": [(100, 200, 250), (128, 256, 128)],
+}
+PAD = 8  # the padding's id: the segments a row may hold
+
+
+def _every_pair(bounds):
+    """`_grouped_bounds` made to walk every block pair under the diagonal,
+    padding too: the walk the streaming kernel took before it kept a
+    list."""
+    def walk(ids, bq, bk, window, padding_id):
+        return bounds(ids, bq, bk, window, None).at[..., 0].set(0)
+    return walk
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("D, Dv", [(128, 128), (256, 128)],
+                         ids=["square", "mla_widths"])
+@pytest.mark.parametrize("case", list(STREAMING_CASES))
+def test_streaming_rows_walk_the_pairs_that_share_a_segment(case, D, Dv,
+                                                            dtype,
+                                                            monkeypatch):
+    """A row of six 128-token blocks streams through the list of the
+    (q block, kv block) pairs that can share a segment (`padding_id`
+    given, as models/mla_moe.py gives it): every real row is the dense
+    reference's within the packed tests' tolerance, and the same to the
+    last bit as a walk of every pair under the diagonal, since a block its
+    rows cannot see adds exactly 0 once a block they can see arrives; a
+    query block that is all padding writes 0. One segment filling the row
+    makes the list whole, with no idle tail."""
+    B, H, L = 2, 2, 768
+    lengths = np.zeros((B, PAD), np.int32)
+    for r, row in enumerate(STREAMING_CASES[case]):
+        lengths[r, :len(row)] = row
+    seg = Segments.of_lengths(jnp.asarray(lengths), L)
+    kq, kk, kv = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(kq, (B, L, H * D), dtype)
+    k = jax.random.normal(kk, (B, L, H * D), dtype)
+    v = jax.random.normal(kv, (B, L, H * Dv), dtype)
+    call = functools.partial(packed_attention, q, k, v, seg.index, H,
+                             block=128, scale=0.1, padding_id=PAD)
+    got = np.asarray(call(), np.float32)
+    with monkeypatch.context() as m:
+        m.setattr(fa, "_grouped_bounds", _every_pair(fa._grouped_bounds))
+        every = np.asarray(call(), np.float32)
+    real = np.asarray(seg.real) > 0
+    np.testing.assert_array_equal(got[real], every[real])
+
+    def heads(t, width):
+        return t.reshape(B, L, H, width).transpose(0, 2, 1, 3)
+
+    want, _ = _dense_reference(heads(q, D), heads(k, D), heads(v, Dv),
+                               jnp.zeros((B, L)), True, 0.1,
+                               segment_ids=seg.index)
+    want = np.asarray(want.transpose(0, 2, 1, 3).reshape(B, L, H * Dv))
+    tol = 2e-5 if dtype == jnp.float32 else 0.03
+    np.testing.assert_allclose(got[real], want[real], rtol=tol, atol=tol)
+    padding = np.repeat((np.asarray(seg.index).reshape(B, -1, 128) == PAD)
+                        .all(-1), 128, axis=1)
+    assert padding.any() == (case != "one_segment_fills_the_row")
+    assert not got[padding].any()
 
 
 def test_packed_attention_refuses_what_does_not_tile():
